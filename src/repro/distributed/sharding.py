@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.models.layers import PAGE_HEAD_AXIS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,17 +259,19 @@ class Rules:
                     path.endswith("pos"):
                 return P(*(None,) * leaf.ndim)
             if "pages_" in path:
-                # paged pool leaves (steps, NP, ps, ...): page ids are
-                # global — the pool axis never shards (a slot's table may
-                # reference any page), and the batch axis isn't there at
-                # all. Only the kv-head axis may take 'model'.
+                # paged pool leaves (steps, NP, ...): page ids are global —
+                # the pool axis never shards (a slot's table may reference
+                # any page), and the batch axis isn't there at all. Only
+                # the kv-head axis of the head-major K/V pages and their
+                # scales (after the leading steps axis) may take 'model'.
+                spec = [None] * leaf.ndim
+                head = 1 + PAGE_HEAD_AXIS
                 if (path.endswith("pages_k") or path.endswith("pages_v")
                         or path.endswith("pages_ks")
                         or path.endswith("pages_vs")) \
-                        and _div(shape[3], self.msize):
-                    return P(*(None, None, None, self.axes.model)
-                             + (None,) * (leaf.ndim - 4))
-                return P(*(None,) * leaf.ndim)
+                        and _div(shape[head], self.msize):
+                    spec[head] = self.axes.model
+                return P(*spec)
             b = dp if shape[1] % bsz == 0 else None
             if path.endswith("/k") or path.endswith("/v"):
                 # (steps, B, W, Hkv, hd)

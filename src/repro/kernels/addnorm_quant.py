@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.kernels.quant_linear import fit_block
 
 
@@ -86,7 +85,7 @@ def addnorm_quant(x: jax.Array, residual: jax.Array, bias: jax.Array,
         out_specs=[row, row],
         out_shape=[jax.ShapeDtypeStruct((M, D), out_dtype),
                    jax.ShapeDtypeStruct((M, D), jnp.int8)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, residual, bias.reshape(1, D).astype(jnp.float32),

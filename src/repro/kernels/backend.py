@@ -29,11 +29,12 @@ flag-driven. The backend's ``name`` is part of the serving runtime's
 executable-cache key, next to the plan fingerprint and (for meshed
 deployments) the mesh topology fingerprint.
 
-Meshed serving binds the backend to the topology via :meth:`with_mesh`:
-the fused backend then declines any GEMM whose per-device output shard is
-narrower than one kernel tile (:data:`MIN_SHARD_TILE`) — tensor-parallel
-splits that starve the MXU fall back to the reference path on that op,
-per-op, exactly like every other decline.
+Mosaic kernels cannot be partitioned by the compiler. On a data-parallel
+mesh (model axis 1) the serving runtime runs each whole step per device
+(``shard_map``), so the backend sees one device's rows and needs no mesh.
+Where the compiler partitions the step (a model axis above 1), the
+runtime binds the backend via :meth:`with_mesh`, and the fused backend
+then declines every op: that serving runs the reference path.
 """
 from __future__ import annotations
 
@@ -52,14 +53,6 @@ from repro.kernels.quant_linear import ACTIVATIONS
 #: kernel's own table, so a new activation is fusable the moment the
 #: kernel (and the reference path, which shares the table) supports it.
 FUSABLE_ACTS = tuple(ACTIVATIONS)
-
-#: the minimum per-device output width a fused GEMM is worth compiling
-#: for: one MXU lane tile. Under tensor parallelism a weight's N axis is
-#: split over the 'model' mesh axis; when the local shard drops below one
-#: lane tile the kernel degenerates (sub-tile blocks, no MXU utilization),
-#: so the fused backend declines that op and the reference XLA path — which
-#: GSPMD partitions natively — runs it instead.
-MIN_SHARD_TILE = 128
 
 
 @dataclasses.dataclass
@@ -168,12 +161,10 @@ class ComputeBackend:
 
     # -- mesh binding --------------------------------------------------------
     def with_mesh(self, mesh) -> "ComputeBackend":
-        """Bind this backend to a serving mesh topology. The reference
-        backend is sharding-oblivious (XLA/GSPMD partitions its ops
-        natively), so the base implementation returns self; the fused
-        backend returns a copy that knows the tensor-parallel degree and
-        declines GEMMs whose local shard is narrower than one kernel
-        tile."""
+        """Bind this backend to a mesh the compiler partitions the step
+        over. The reference backend is sharding-oblivious (XLA/GSPMD
+        partitions its ops natively), so the base implementation returns
+        self; the fused backend returns a copy that declines every op."""
         return self
 
     # -- plan validation -----------------------------------------------------
@@ -220,33 +211,23 @@ class FusedBackend(ComputeBackend):
         # ``enabled=False`` turns every op into a decline — the AutoBackend
         # constructor uses it to resolve to reference off-TPU.
         self._enabled = enabled
-        # tensor-parallel degree of the bound mesh (1 = unmeshed); set via
-        # with_mesh so Runtime(mesh=...) deployments get shard-aware
-        # declines without plumbing a mesh through every op call.
-        self.model_shards = 1
+        # why a mesh turned every op into a decline (None: not declined);
+        # describe() then says so, since no kernel will be compiled
+        self._declined_for: Optional[str] = None
 
     def with_mesh(self, mesh) -> "FusedBackend":
         b = copy.copy(self)
-        b.model_shards = (int(mesh.shape.get("model", 1))
-                          if mesh is not None else 1)
+        if mesh is not None and self._enabled:
+            # Mosaic kernels cannot be partitioned by the compiler
+            b._enabled = False
+            b._declined_for = ("tensor-parallel mesh"
+                               if int(mesh.shape.get("model", 1)) > 1
+                               else "partitioned mesh")
         return b
 
-    def _shard_too_narrow(self, K: int, N: int) -> bool:
-        """Under TP the sharding rules split exactly one GEMM axis over
-        'model': N for column-parallel blocks (qkv / ffn_in), K for
-        row-parallel ones (attn_out / ffn_out). The backend sees only the
-        weight — not which layout the rules chose — so it declines when
-        EITHER divisible axis would leave a per-device shard below one
-        lane tile (declining is always safe: the reference path is
-        GSPMD-partitioned XLA). Non-divisible dims replicate under the
-        rules — params are never padded — so they keep their full width.
-        Production-scale TP dims clear ``MIN_SHARD_TILE * shards`` on both
-        axes, so the conservatism only bites models too small to TP."""
-        if self.model_shards <= 1:
-            return False
-        return any(dim % self.model_shards == 0
-                   and dim // self.model_shards < MIN_SHARD_TILE
-                   for dim in (K, N))
+    def describe(self) -> str:
+        return (f"fused[declined: {self._declined_for}]"
+                if self._declined_for else self.name)
 
     # -- block GEMM ----------------------------------------------------------
     def linear(self, x, p: dict, *, act: Optional[str] = None):
@@ -255,8 +236,7 @@ class FusedBackend(ComputeBackend):
                 or w.values.ndim != 2 or act not in FUSABLE_ACTS):
             return None          # float block / expert stack: reference path
         K, N = w.values.shape
-        if self._shard_too_narrow(K, N):
-            return None          # per-device shard below one kernel tile
+        from repro.kernels import ops
         if isinstance(x, QuantActivation):
             # already int8 — the fused addnorm quantized it with the static
             # scale this GEMM was calibrated on; no runtime quant needed
@@ -272,12 +252,10 @@ class FusedBackend(ComputeBackend):
             if xs is not None:                     # static per-tensor scale
                 x_q, x_scale = quantize(x2, xs), xs
             else:                                  # per-token dynamic scales
-                from repro.kernels import ops
                 x_q, x_scale = ops.dynamic_quant(x2)
         w_scale = w.scale.astype(jnp.float32).reshape(-1)
         if w_scale.shape[0] != N:                  # int8_per_tensor weights
             w_scale = jnp.broadcast_to(w_scale, (N,))
-        from repro.kernels import ops
         # ``out_xs`` — attached by apply_plan under a norm='int8' span — is
         # the next consumer's calibrated activation scale: the epilogue
         # requantizes to int8 and the result stays quantized between GEMMs.
@@ -298,15 +276,10 @@ class FusedBackend(ComputeBackend):
         # through the fused quant_linear kernel with its own per-expert
         # scale operands (weights (E, 1, F); static acts (E, 1, 1) — a
         # scalar xs, the pre-v4 ffn_in fallback, broadcasts to every
-        # expert). Declines float stacks and — mirroring `linear` — any
-        # deployment where the per-expert (D, F) GEMM would shard below
-        # one kernel tile under the bound mesh.
+        # expert). Declines float stacks.
         if (not self._enabled or not isinstance(w, QuantizedTensor)
                 or w.values.ndim != 3):
             return None
-        E, D, F = w.values.shape
-        if self._shard_too_narrow(D, F):
-            return None          # per-device expert shard below one tile
         from repro.kernels import ops
         return ops.quant_expert_gemm(xe, w.values, w.scale, xs,
                                      out_dtype=jnp.float32)
@@ -370,11 +343,9 @@ class FusedBackend(ComputeBackend):
         # Claims the bidirectional (encoder) core when the plan calibrated
         # all four scheme scales — the softmax='uint8' dataflow. Causal /
         # windowed masks keep the reference path (the kernel holds the
-        # whole key axis per tile and masks on validity only), as do
-        # meshed deployments (the grid indexes the full head axis). GQA is
+        # whole key axis per tile and masks on validity only). GQA is
         # supported: the kernel's head grid indexes kv heads by division.
-        if (not self._enabled or self.model_shards > 1 or spec.causal
-                or spec.window is not None
+        if (not self._enabled or spec.causal or spec.window is not None
                 or any(f"{s}_scale" not in p for s in ("q", "k", "p", "v"))):
             return None
         B, Sq, Hq, hd = q.shape
@@ -395,6 +366,8 @@ class FusedBackend(ComputeBackend):
         o_scale = wo.get("xs") if isinstance(wo.get("w"), QuantizedTensor) \
             else None
         from repro.kernels import ops
+        k_pos = jnp.broadcast_to(jnp.asarray(k_pos, jnp.int32).reshape(
+            -1, kq.shape[2]), (B, kq.shape[2]))
         out = ops.quant_flash_attention(
             qq, kq, vq, k_pos, q_scale=p["q_scale"], k_scale=p["k_scale"],
             p_scale=p["p_scale"], v_scale=p["v_scale"], o_scale=o_scale,
@@ -412,11 +385,10 @@ class FusedBackend(ComputeBackend):
                          p_scale=None):
         # The kernel's win is skipping the float-cache materialization, so
         # it claims int8 pages only; float paged caches (and MLA's latent
-        # pages) keep the XLA gather path. Meshed serving declines too: the
-        # grid indexes the full KV-head axis, which GSPMD would split.
+        # pages) keep the XLA gather path.
         k, v = kv_cache.get("pages_k"), kv_cache.get("pages_v")
-        if (not self._enabled or self.model_shards > 1 or k is None
-                or v is None or k.dtype != jnp.int8):
+        if not self._enabled or k is None or v is None \
+                or k.dtype != jnp.int8:
             return None
         per_token = "pages_ks" in kv_cache
         if per_token:
@@ -427,8 +399,10 @@ class FusedBackend(ComputeBackend):
                 return None
             ks = sc["k"].astype(jnp.float32).reshape(-1)
             vs = sc["v"].astype(jnp.float32).reshape(-1)
+        from repro.kernels import ops
+        from repro.models.layers import PAGE_HEAD_AXIS
         B, S, Hq, hd = q.shape
-        Hkv = k.shape[2]
+        Hkv = k.shape[PAGE_HEAD_AXIS]
         if S != 1 or Hq % Hkv != 0:
             return None
         pos = jnp.asarray(positions, jnp.int32)
@@ -437,7 +411,6 @@ class FusedBackend(ComputeBackend):
         lengths = pos + 1                    # incl. the token written above
         if active is not None:
             lengths = jnp.where(active, lengths, 0)
-        from repro.kernels import ops
         out = ops.decode_attention(
             q[:, 0].reshape(B, Hkv, Hq // Hkv, hd), k, v, pages, lengths,
             k_scale=ks, v_scale=vs, per_head=not per_token,

@@ -1,12 +1,20 @@
 """Paged decode attention with fused int8-KV dequantization.
 
 Single-token decode attention over a **paged** KV cache: keys/values live in
-a shared pool of fixed-size pages ``(num_pages, page_size, Hkv, hd)`` and
-each slot owns an ordered list of page ids (its *page table* row, ``-1`` for
+a shared pool of fixed-size, head-major pages ``(num_pages, Hkv, page_size,
+hd)`` and each slot owns an ordered list of page ids (its *page table* row, ``-1`` for
 unallocated entries).  The kernel walks a slot's page table with the page
 axis as the innermost grid dimension, using **scalar prefetch** so the page
 id for grid step ``j`` indexes the pool *in the BlockSpec index map* — the
 DMA engine fetches exactly the pages a slot owns, never the whole pool.
+
+The pool is head-major so one grid step's block ``(1, 1, page_size, hd)``
+spans the array's whole last two dims: Mosaic accepts a block only when its
+last two dims are tile multiples or the full dims, and a token-major
+``(1, page_size, 1, hd)`` block over ``(.., Hkv, hd)`` is neither once
+``Hkv > 1``. For the same reason a page's per-token scales arrive as one
+``(Hkv, page_size)`` block and the kernel picks its head's row, and
+per-head scales sit in SMEM.
 
 K/V pages are int8.  Dequantization is fused into the two matmul epilogues
 rather than materializing a float cache:
@@ -37,7 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -46,8 +53,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                    ps_ref, o_ref, m_ref, l_ref, acc_ref, *,
                    page_size: int, pages_per_slot: int, scale: float,
                    softcap: Optional[float], per_head: bool, quant_p: bool):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     # quant_p doubles the page axis: pass 1 (j < pps) accumulates the exact
     # global softmax max/denominator, pass 2 (j >= pps) revisits every page
     # with the *normalized* probabilities in hand, quantizes them with the
@@ -63,20 +69,26 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def _scale_row(ref):
+        # this head's dequantization scale: an SMEM scalar (per-head), or
+        # row h of the page's (Hkv, ps) per-token scale block
+        if per_head:
+            return ref[h]
+        sc = ref[0]
+        pick = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) == h
+        return jnp.sum(jnp.where(pick, sc, 0.0), axis=0, keepdims=True)
+
     length = len_ref[b]
     page = pt_ref[b * pages_per_slot + jj]
     live = jnp.logical_and(page >= 0, length > jj * page_size)
 
     def _scores():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         # QK^T epilogue: dequantize raw int8 scores by the key scale.
-        if per_head:
-            s = s * ks_ref[0]
-        else:
-            s = s * ks_ref[0, :, 0][None, :]
+        s = s * _scale_row(ks_ref)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         tok = jj * page_size + jax.lax.broadcasted_iota(
@@ -85,12 +97,10 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     def _fold_vs(p):
         # PV epilogue: fold the value scale into p, then one int8-V dot.
-        if per_head:
-            return p * vs_ref[0]
-        return p * vs_ref[0, :, 0][None, :]
+        return p * _scale_row(vs_ref)
 
     def _pv(p):
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
         return jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
@@ -142,11 +152,11 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     Args:
       q: ``(B, Hkv, g, hd)`` float queries, GQA groups pre-folded
         (query head ``h*g + i`` shares KV head ``h``).
-      k_pages / v_pages: ``(num_pages, page_size, Hkv, hd)`` int8 pool.
+      k_pages / v_pages: ``(num_pages, Hkv, page_size, hd)`` int8 pool.
       page_table: ``(B, pages_per_slot)`` int32, ``-1`` = unallocated.
       lengths: ``(B,)`` int32 — valid tokens per slot **including** the
         token written this step; 0 disables a slot (output row is zeros).
-      k_scale / v_scale: per-token ``(num_pages, page_size, Hkv)`` float32
+      k_scale / v_scale: per-token ``(num_pages, Hkv, page_size)`` float32
         scale pages when ``per_head=False``; calibrated ``(Hkv,)`` float32
         vectors when ``per_head=True``.
       scale: query scaling, default ``hd**-0.5``.
@@ -161,7 +171,7 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     Returns ``(B, Hkv, g, hd)`` in ``q.dtype``.
     """
     B, Hkv, g, hd = q.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    page_size = k_pages.shape[2]
     pps = page_table.shape[1]
     if scale is None:
         scale = float(hd) ** -0.5
@@ -178,24 +188,26 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     def jmod(j):
         return jax.lax.rem(j, pps) if quant_p else j
 
-    def page_map(bi, h, j, pt, ln):
-        return (jnp.maximum(pt[bi * pps + jmod(j)], 0), 0, h, 0)
+    def page(bi, j, pt):
+        return jnp.maximum(pt[bi * pps + jmod(j)], 0)
 
     if per_head:
-        scale_spec = pl.BlockSpec((1,), lambda bi, h, j, pt, ln: (h,))
+        scale_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     else:
         scale_spec = pl.BlockSpec(
-            (1, page_size, 1),
-            lambda bi, h, j, pt, ln: (
-                jnp.maximum(pt[bi * pps + jmod(j)], 0), 0, h))
+            (1, Hkv, page_size),
+            lambda bi, h, j, pt, ln: (page(bi, j, pt), 0, 0))
+    page_spec = pl.BlockSpec(
+        (1, 1, page_size, hd),
+        lambda bi, h, j, pt, ln: (page(bi, j, pt), h, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, Hkv, 2 * pps if quant_p else pps),
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda bi, h, j, pt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd), page_map),
-            pl.BlockSpec((1, page_size, 1, hd), page_map),
+            page_spec,
+            page_spec,
             scale_spec,
             scale_spec,
             pl.BlockSpec((1, 1), lambda bi, h, j, pt, ln: (0, 0)),
@@ -215,7 +227,7 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pt_flat, lengths, q, k_pages, v_pages, k_scale, v_scale, ps_op)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.kernels.quant_linear import fit_block
 
 _EPS = 1e-8
@@ -41,7 +40,7 @@ def dynamic_quant(x: jax.Array, *, bm: int = 256,
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((M, D), jnp.int8),
                    jax.ShapeDtypeStruct((M, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

@@ -23,8 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-from repro.kernels.quant_linear import fit_block
+from repro.kernels.quant_linear import INT8_PRECISION, fit_block
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 INT8_MIN = -128
@@ -103,11 +102,12 @@ def _quant_kernel(q_ref, k_ref, v_ref, kpos_ref, qs_ref, ks_ref, ps_ref,
     calibrated activation scale — the whole-layer int8 span's first hop.
     """
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            precision=INT8_PRECISION,
                             preferred_element_type=jnp.int32)
     s = s.astype(jnp.float32) * (qs_ref[...] * ks_ref[...])
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(kpos_ref[...] >= 0, s, NEG_INF)       # validity mask
+    s = jnp.where(kpos_ref[0] >= 0, s, NEG_INF)         # validity mask
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)          # exact softmax
@@ -116,6 +116,7 @@ def _quant_kernel(q_ref, k_ref, v_ref, kpos_ref, qs_ref, ks_ref, ps_ref,
         .astype(jnp.int8)
     v = v_ref[0]
     acc = jax.lax.dot_general(pq, v, (((1,), (0,)), ((), ())),
+                              precision=INT8_PRECISION,
                               preferred_element_type=jnp.int32)
     # zero-point correction: dot(codes - zp, v) = dot(codes, v) - zp*sum(v)
     vsum = jnp.sum(v.astype(jnp.int32), axis=0, keepdims=True)
@@ -154,8 +155,10 @@ def quant_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qf = q.reshape(B * Hq, Sq, D)
     kf = k.reshape(B * Hkv, Sk, D)
     vf = v.reshape(B * Hkv, Sk, D)
-    kpos = jnp.broadcast_to(jnp.asarray(k_pos, jnp.int32).reshape(-1, Sk),
-                            (B, Sk))
+    # (B, 1, Sk): a (1, Sk) block is then the array's whole last two dims,
+    # which Mosaic accepts for any batch (a (1, Sk) block of (B, Sk) is not)
+    kpos = jnp.broadcast_to(jnp.asarray(k_pos, jnp.int32).reshape(-1, 1, Sk),
+                            (B, 1, Sk))
     requant = o_scale is not None
     scalars = [jnp.asarray(x, jnp.float32).reshape(1, 1)
                for x in (q_scale, k_scale, p_scale, v_scale,
@@ -170,13 +173,13 @@ def quant_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, Sk, D), lambda h, i, g=g: (h // g, 0, 0)),
             pl.BlockSpec((1, Sk, D), lambda h, i, g=g: (h // g, 0, 0)),
-            pl.BlockSpec((1, Sk), lambda h, i, H=Hq: (h // H, 0)),
+            pl.BlockSpec((1, 1, Sk), lambda h, i, H=Hq: (h // H, 0, 0)),
             scalar_spec, scalar_spec, scalar_spec, scalar_spec, scalar_spec,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (B * Hq, Sq, D), jnp.int8 if requant else out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(qf, kf, vf, kpos, *scalars)
@@ -224,7 +227,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

@@ -1,9 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU these lower to Mosaic; on this CPU container they run in interpret
-mode (``interpret=True`` executes the kernel body in Python per grid step —
-the correctness path used by the test suite). ``KERNEL_INTERPRET`` flips
-globally so model code can call the same entry points everywhere.
+On TPU these lower to Mosaic; on any other platform they run in interpret
+mode (the kernel body executes grid step by grid step through XLA — the
+correctness path the CPU test suite uses). The choice is made when a
+wrapper is traced, from the platform the computation runs on, so model
+code calls the same entry points everywhere and importing this module
+touches no JAX backend.
 
 Activation scales are **operands** (traced arrays), not static arguments:
 the serving runtime jits the whole forward with params as call arguments,
@@ -29,8 +31,11 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_embed as _fe
 from repro.kernels import quant_linear as _ql
 
-# CPU containers have no Mosaic backend; default to interpret off-TPU.
-KERNEL_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Interpret mode everywhere but a TPU, where Mosaic compiles the
+    kernels. Called at trace time, never at import."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -46,7 +51,7 @@ def quant_linear(x_q, w_q, w_scale, x_scale: Union[float, jax.Array], *,
     return _ql.quant_linear(x_q, w_q, w_scale, x_scale, bias=bias, act=act,
                             out_scale=out_scale, out_dtype=out_dtype,
                             bm=bm, bn=bn, bk=bk,
-                            interpret=KERNEL_INTERPRET)
+                            interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "eps", "bm"))
@@ -60,7 +65,7 @@ def addnorm_quant(x, residual, bias, gamma, beta,
     ``x_in_scale`` operand."""
     return _anq.addnorm_quant(x, residual, bias, gamma, beta, x_scale,
                               x_in_scale=x_in_scale, kind=kind, eps=eps,
-                              bm=bm, interpret=KERNEL_INTERPRET)
+                              bm=bm, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "out_dtype"))
@@ -71,12 +76,12 @@ def fused_embed(tokens, tok_table, pos_table, seg_table=None, segments=None,
     default row-major ``arange(N) mod S`` position stream."""
     return _fe.fused_embed(tokens, tok_table, pos_table, seg_table, segments,
                            positions=positions, scale=scale,
-                           out_dtype=out_dtype, interpret=KERNEL_INTERPRET)
+                           out_dtype=out_dtype, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("bm",))
 def dynamic_quant(x, *, bm: int = 256):
-    return _dq.dynamic_quant(x, bm=bm, interpret=KERNEL_INTERPRET)
+    return _dq.dynamic_quant(x, bm=bm, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "bm", "bn", "bk"))
@@ -147,7 +152,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     explicitly."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, bq=bq, bk=bk,
-                               interpret=KERNEL_INTERPRET)
+                               interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -164,7 +169,7 @@ def quant_flash_attention(q, k, v, k_pos, *, q_scale, k_scale, p_scale,
                                      k_scale=k_scale, p_scale=p_scale,
                                      v_scale=v_scale, o_scale=o_scale,
                                      softcap=softcap, out_dtype=out_dtype,
-                                     bq=bq, interpret=KERNEL_INTERPRET)
+                                     bq=bq, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("per_head", "scale", "softcap"))
@@ -184,4 +189,4 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                                 k_scale=k_scale, v_scale=v_scale,
                                 per_head=per_head, scale=scale,
                                 softcap=softcap, p_scale=p_scale,
-                                interpret=KERNEL_INTERPRET)
+                                interpret=_interpret())

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 # The one activation table shared by the kernel epilogue, the reference
 # dense path (repro.models.layers) and the jnp oracle (kernels/ref.py):
@@ -40,6 +39,13 @@ ACTIVATIONS = {
     "gelu": functools.partial(jax.nn.gelu, approximate=True),
     "relu": jax.nn.relu,
 }
+
+
+#: int8 MXU contractions are exact and take no float precision. Pinning
+#: DEFAULT keeps a process-wide ``jax_default_matmul_precision`` (e.g.
+#: "highest") out of them: Mosaic rejects an fp32 contract precision on
+#: int8 operands.
+INT8_PRECISION = jax.lax.Precision.DEFAULT
 
 
 def fit_block(n: int, b: int) -> int:
@@ -63,7 +69,7 @@ def _kernel(x_ref, w_ref, ws_ref, xs_ref, b_ref, os_ref, o_ref, acc_ref, *,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        precision=INT8_PRECISION, preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -127,7 +133,7 @@ def quant_linear(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct(
             (M, N), jnp.int8 if requant else out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, w_scale.reshape(1, N).astype(jnp.float32), xs,

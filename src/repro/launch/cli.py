@@ -3,20 +3,30 @@
 ``launch/serve.py`` (the synchronous one-shot CLI) and
 ``launch/server.py`` (the HTTP/SSE front-end) serve the same deployments,
 so they must parse the same deployment flags the same way. This module is
-the single definition of that surface — ``--arch / --task / --policy /
---plan / --clusters / --strategy / --max-latency / --backend / --mesh /
---slots / --max-len / --seed`` — so the two entrypoints cannot drift.
-:func:`parse_cluster_model` turns the ``--clusters`` spec string into a
-:class:`~repro.adaptive.clusters.ClusterModel`.
+the single definition of that surface — ``--arch / --reduced / --task /
+--policy / --plan / --clusters / --strategy / --max-latency / --backend /
+--mesh / --slots / --max-len / --seed`` — so the two entrypoints cannot
+drift. :func:`parse_cluster_model` turns the ``--clusters`` spec string
+into a :class:`~repro.adaptive.clusters.ClusterModel`;
+:func:`enable_compilation_cache` is the one place a process that serves
+places JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
+
+#: the checkout this package runs from (``src/repro/launch/cli.py`` -> root)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 
 def add_serving_flags(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:
     """The deployment flags every serving entrypoint shares."""
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's reduced() config (small widths, "
+                         "for CPU runs) instead of its published widths")
     ap.add_argument("--task", default=None,
                     help="lm (decode engine) | tnews|iflytek|afqmc|ner "
                          "(encoder engine); default: lm when the arch "
@@ -42,11 +52,11 @@ def add_serving_flags(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:
     ap.add_argument("--max-latency", type=float, default=None,
                     help="latency ceiling (roofline seconds) for "
                          "--strategy latency_budget")
-    ap.add_argument("--backend", default="reference",
+    ap.add_argument("--backend", default="auto",
                     choices=("reference", "fused", "auto"),
                     help="compute backend for quantized blocks: reference "
-                         "XLA ops, fused Pallas kernels, or auto (fused on "
-                         "TPU, reference elsewhere)")
+                         "XLA ops, fused Pallas kernels, or auto (default: "
+                         "fused on TPU, reference elsewhere)")
     ap.add_argument("--mesh", default="1,1",
                     help="serving mesh as 'dp,tp' (data-parallel x tensor-"
                          "parallel device counts); 1,1 = unmeshed. Needs "
@@ -69,6 +79,35 @@ def add_serving_flags(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:
                          "dynamically at decode time. Default: the plan's "
                          "per-layer kv_cache schemes")
     return ap
+
+
+def resolve_config(args):
+    """The served ArchConfig: published widths unless ``--reduced``."""
+    from repro.configs import get_config
+    cfg = get_config(args.arch)
+    return cfg.reduced() if args.reduced else cfg
+
+
+def compilation_cache_dir(environ=os.environ) -> str:
+    """Where JAX's persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else the fixed,
+    git-ignored ``.jax_cache`` of the checkout. The path is part of every
+    cache key, so it never varies from run to run."""
+    return environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        str(CHECKOUT / ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on the persistent compilation cache at
+    :func:`compilation_cache_dir`; returns the directory. JAX reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself, so only the fallback is set in
+    code. Called by the serving entry points and ``chip_smoke.py``, before
+    their first compile."""
+    import jax
+    path = compilation_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_cluster_model(spec):

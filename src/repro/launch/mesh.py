@@ -1,8 +1,20 @@
 """Production mesh construction (importing this module never touches jax
-device state — the mesh is built lazily inside the function)."""
+device state — the mesh is built lazily inside the function).
+
+Every mesh here uses ``Auto`` axis types: the sharding ``Rules`` place
+params and batches with ``NamedSharding`` and leave propagation to the
+compiler, which is what ``Auto`` axes do. ``jax.make_mesh`` otherwise
+defaults to ``Explicit`` axes, under which ops such as the embedding
+gather must state their output sharding and fail without it.
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,14 +22,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     'pod' axis over DCN: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_serving_mesh(spec: str):
@@ -45,4 +57,4 @@ def make_serving_mesh(spec: str):
             f"--mesh {spec} needs {dp * tp} devices but only {n} visible; "
             f"on CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{dp * tp}")
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return _mesh((dp, tp), ("data", "model"))

@@ -23,8 +23,8 @@
     # (docs/serving.md; needs dp*tp visible devices)
     ... --mesh 2,1
 
-Instantiates the reduced config (this is the CPU-container path; on TPU the
-same flow runs the full config), PTQ-calibrates on synthetic batches,
+Instantiates the arch at its published widths (``--reduced`` selects the
+small ``reduced()`` config for CPU runs), PTQ-calibrates on synthetic batches,
 applies the requested precision — a named mode policy (``--policy``), a
 saved declarative plan (``--plan plan.json``), or the winner of a search
 strategy (``--strategy``, accuracy proxied by closeness to the float
@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
 from repro.core.calibration import synthetic_calibration_batches
 from repro.core.plan import (PlanSet, PrecisionPlan, load_plan_or_planset,
                              plan_from_policy)
@@ -49,7 +48,8 @@ from repro.core.precision import make_policy
 from repro.core.samp import SAMPEngine
 from repro.data.pipeline import make_task
 from repro.distributed.sharding import mesh_fingerprint
-from repro.launch.cli import (add_serving_flags, parse_cluster_model,
+from repro.launch.cli import (add_serving_flags, enable_compilation_cache,
+                              parse_cluster_model, resolve_config,
                               resolve_task)
 from repro.launch.mesh import make_serving_mesh
 from repro.models import transformer as T
@@ -221,7 +221,8 @@ def serve_decode(cfg, args) -> None:
           f"mesh={mesh_fingerprint(server.runtime.mesh)}: "
           f"{s['retired']} requests, {s['tokens']} tokens in "
           f"{s['ticks']} ticks, {dt:.2f}s "
-          f"({s['tokens'] / max(dt, 1e-9):.1f} tok/s CPU); "
+          f"({s['tokens'] / max(dt, 1e-9):.1f} tok/s on "
+          f"{jax.devices()[0].platform}); "
           f"{s['runtime_traces']} compile(s) / "
           f"{s['runtime_executables']} executable(s)")
     if router is not None:
@@ -266,7 +267,8 @@ def serve_encoder(cfg, args) -> None:
           f"backend={server.runtime.backend.describe()} "
           f"mesh={mesh_fingerprint(server.runtime.mesh)}: {s['retired']} "
           f"requests in {s['batches']} micro-batches, {dt:.2f}s "
-          f"({s['retired'] / max(dt, 1e-9):.1f} req/s CPU); "
+          f"({s['retired'] / max(dt, 1e-9):.1f} req/s on "
+          f"{jax.devices()[0].platform}); "
           f"{s['runtime_traces']} compile(s) / "
           f"{s['runtime_executables']} executable(s)")
     if router is not None:
@@ -283,7 +285,8 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
+    enable_compilation_cache()
+    cfg = resolve_config(args)
     args.task = resolve_task(cfg, args.task)
     if args.task == "lm":
         serve_decode(cfg, args)

@@ -1,8 +1,9 @@
 """HTTP/SSE serving entrypoint — the asyncio front-end over the engines.
 
-    # encoder serving (JSON request/response) on the golden plan
+    # encoder serving (JSON request/response) on the golden plan, which
+    # is calibrated for the 4-layer reduced() widths
     PYTHONPATH=src python -m repro.launch.server --arch bert-base \
-        --task tnews --plan tests/data/golden_plan.json --port 8080
+        --reduced --task tnews --plan tests/data/golden_plan.json --port 8080
 
     # a decode-capable arch mounts BOTH endpoints: /v1/encode for the
     # encoder task and /v1/generate for SSE token streaming
@@ -32,9 +33,9 @@ import argparse
 
 import jax.numpy as jnp
 
-from repro.configs import get_config
 from repro.data.pipeline import make_task
-from repro.launch.cli import (add_serving_flags, parse_cluster_model,
+from repro.launch.cli import (add_serving_flags, enable_compilation_cache,
+                              parse_cluster_model, resolve_config,
                               resolve_task)
 from repro.launch.mesh import make_serving_mesh
 from repro.launch.serve import build_model, build_routed_model
@@ -52,7 +53,7 @@ def build_frontend(args, *, log=print) -> HTTPFrontend:
     head rides next to the tied-embedding lm head), so a single server
     answers /v1/encode and /v1/generate.
     """
-    cfg = get_config(args.arch).reduced()
+    cfg = resolve_config(args)
     task_name = resolve_task(cfg, args.task)
     mesh = make_serving_mesh(args.mesh)
     cluster_model = parse_cluster_model(args.clusters)
@@ -124,6 +125,7 @@ def main():
                     help="default per-request deadline when the request "
                          "states no deadline_ms (None = unbounded)")
     args = ap.parse_args()
+    enable_compilation_cache()
     frontend = build_frontend(args)
     frontend.run_forever()
 

@@ -507,17 +507,32 @@ def _cache_write(kv_cache: dict, new: dict, positions: jax.Array,
 # Layout: the per-slot (B, W, ...) ring of `_cache_write` becomes a pooled
 # set of fixed-size token pages shared by every slot:
 #
-#   pages_k / pages_v : (NP, ps, Hkv, hd)   int8 or cache dtype
-#   pages_ks/pages_vs : (NP, ps, Hkv) f32   per-token scales (dynamic only)
+#   pages_k / pages_v : (NP, Hkv, ps, hd)   int8 or cache dtype
+#   pages_ks/pages_vs : (NP, Hkv, ps) f32   per-token scales (dynamic only)
 #   pages_pos         : (NP, ps) int32      absolute position, -1 = invalid
 #   pos               : (B,) int32          per-slot next position
 #
 # plus a page-table *operand* (B, pages_per_slot) int32 owned by the
 # serving scheduler's PagePool (-1 = unallocated). Token t of slot b lives
-# at flat index pages[b, t // ps] * ps + t % ps. Slots stop paying
-# max-length memory: pages are allocated as generation grows and returned
-# to the pool on completion/cancel. MLA caches page the latent instead
-# (pages_ckv / pages_krope).
+# at page pages[b, t // ps], row t % ps. K/V pages are head-major so the
+# Pallas decode kernel reads one head's (ps, hd) page as one whole TPU
+# block. Slots stop paying max-length memory: pages are allocated as
+# generation grows and returned to the pool on completion/cancel. MLA
+# caches page the latent instead (pages_ckv / pages_krope).
+
+
+#: page leaves stored head-major, (NP, Hkv, ps, ...); the latent (MLA)
+#: pages have no head axis and stay token-major, (NP, ps, ...)
+HEAD_MAJOR_PAGES = ("k", "v")
+#: the kv-head axis of a head-major page leaf
+PAGE_HEAD_AXIS = 1
+
+
+def page_leaf_shape(num_pages: int, page_size: int, heads: int,
+                    *tail: int) -> tuple:
+    """Allocation shape of a head-major page leaf: K/V pages with
+    ``tail=(head_dim,)``, their per-token scales with no tail."""
+    return (num_pages, heads, page_size) + tail
 
 
 def _page_flat_index(pages: jax.Array, positions: jax.Array,
@@ -562,6 +577,8 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
     # pool's last row (NumPy negative indexing) and corrupt whichever slot
     # owns it — map the sentinel to a genuinely out-of-bounds index.
     flat = jnp.where(flat < 0, npages * ps, flat)
+    # head-major leaves (K/V pages and their scales) scatter by (page, row)
+    page_of, row_of = flat // ps, flat % ps
     out = dict(kv_cache)
     for key, val in new.items():
         leaf = kv_cache["pages_" + key]
@@ -571,10 +588,8 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
                 amax = jnp.max(jnp.abs(val.astype(jnp.float32)), axis=-1)
                 scl = compute_scale_symmetric(amax)      # (B, S, H)
                 rows = quantize(val, scl[..., None])
-                spages = kv_cache[skey]
-                out[skey] = spages.reshape((npages * ps,) + spages.shape[2:]) \
-                    .at[flat].set(scl.reshape((-1,) + spages.shape[2:]),
-                                  mode="drop").reshape(spages.shape)
+                out[skey] = kv_cache[skey].at[page_of, :, row_of].set(
+                    scl.reshape(-1, scl.shape[-1]), mode="drop")
             else:                                        # per-head static
                 s = (static_scales or {}).get(key)
                 if s is None:
@@ -586,9 +601,14 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
                 rows = quantize(val, s.reshape((1, 1, -1, 1)))
         else:
             rows = val.astype(leaf.dtype)
-        out["pages_" + key] = leaf.reshape((npages * ps,) + leaf.shape[2:]) \
-            .at[flat].set(rows.reshape((-1,) + leaf.shape[2:]),
-                          mode="drop").reshape(leaf.shape)
+        if key in HEAD_MAJOR_PAGES:
+            out["pages_" + key] = leaf.at[page_of, :, row_of].set(
+                rows.reshape((-1,) + rows.shape[2:]), mode="drop")
+        else:
+            out["pages_" + key] = leaf.reshape(
+                (npages * ps,) + leaf.shape[2:]).at[flat].set(
+                rows.reshape((-1,) + leaf.shape[2:]),
+                mode="drop").reshape(leaf.shape)
     out["pages_pos"] = kv_cache["pages_pos"].reshape(-1) \
         .at[flat].set(pos2.reshape(-1), mode="drop") \
         .reshape(kv_cache["pages_pos"].shape)
@@ -616,17 +636,19 @@ def _paged_cache_read(kv_cache: dict, pages: jax.Array, keys, dtype,
     outs = []
     for key in keys:
         leaf = kv_cache["pages_" + key]
-        g = jnp.take(leaf, safe, axis=0)                 # (B, pps, ps, ...)
+        g = jnp.take(leaf, safe, axis=0)                 # (B, pps, ...)
+        if key in HEAD_MAJOR_PAGES:                      # -> (B, pps, ps, H, d)
+            g = g.swapaxes(2, 3)
         if leaf.dtype == jnp.int8:
             skey = "pages_" + key + "s"
             if skey in kv_cache:
-                scl = jnp.take(kv_cache[skey], safe, axis=0)
+                scl = jnp.take(kv_cache[skey], safe, axis=0).swapaxes(2, 3)
                 g = g.astype(jnp.float32) * scl[..., None]
             else:
                 s = (static_scales or {})[key]
                 g = g.astype(jnp.float32) * s.reshape((1, 1, 1, -1, 1))
         g = g.astype(dtype)
-        outs.append(g.reshape((B, pps * ps) + leaf.shape[2:]))
+        outs.append(g.reshape((B, pps * ps) + g.shape[3:]))
     return outs, kpos.reshape(B, pps * ps)
 
 

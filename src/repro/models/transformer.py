@@ -540,17 +540,15 @@ def _layer_cache(cfg: ArchConfig, kind: BlockKind, batch: int, max_len: int,
                         "pages_pos": jnp.full((NP, ps), -1, jnp.int32),
                         "pos": jnp.zeros((batch,), jnp.int32)}
             kv_dtype = jnp.int8 if kv_scheme.startswith("int8") else dtype
-            d = {"pages_k": jnp.zeros(
-                     (NP, ps, cfg.num_kv_heads, cfg.head_dim), kv_dtype),
-                 "pages_v": jnp.zeros(
-                     (NP, ps, cfg.num_kv_heads, cfg.head_dim), kv_dtype),
+            kv = L.page_leaf_shape(NP, ps, cfg.num_kv_heads, cfg.head_dim)
+            d = {"pages_k": jnp.zeros(kv, kv_dtype),
+                 "pages_v": jnp.zeros(kv, kv_dtype),
                  "pages_pos": jnp.full((NP, ps), -1, jnp.int32),
                  "pos": jnp.zeros((batch,), jnp.int32)}
             if kv_scheme == "int8_per_token":
-                d["pages_ks"] = jnp.zeros((NP, ps, cfg.num_kv_heads),
-                                          jnp.float32)
-                d["pages_vs"] = jnp.zeros((NP, ps, cfg.num_kv_heads),
-                                          jnp.float32)
+                scales = L.page_leaf_shape(NP, ps, cfg.num_kv_heads)
+                d["pages_ks"] = jnp.zeros(scales, jnp.float32)
+                d["pages_vs"] = jnp.zeros(scales, jnp.float32)
             return d
         if cfg.mla is not None:
             m = cfg.mla

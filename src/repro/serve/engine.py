@@ -111,6 +111,10 @@ class ServeEngine:
             # the plan itself asks for quantized KV: paging is implied
             page_size = DEFAULT_PAGE_SIZE
         self.page_size = page_size
+        self.runtime = runtime or Runtime(cfg, plan, scheme=scheme,
+                                          precision=precision,
+                                          compute_dtype=compute_dtype,
+                                          backend=backend, mesh=mesh)
         self.pool: Optional[PagePool] = None
         cache_kw = {}
         if page_size is not None:
@@ -123,7 +127,11 @@ class ServeEngine:
             pps = T.pages_per_slot(max_len, page_size)
             num_pages = (pool_pages if pool_pages is not None
                          else batch_slots * pps)
-            self.pool = PagePool(num_pages, page_size, batch_slots, pps)
+            # a runtime that decodes per device holds each device's share
+            # of the pool there, so the pool keeps each slot's pages on the
+            # slot's own device
+            self.pool = PagePool(num_pages, page_size, batch_slots, pps,
+                                 shards=self.runtime.shards)
             cache_kw = dict(page_size=page_size, num_pages=num_pages,
                             kv_schemes=schemes)
         elif kv_cache not in (None, "float"):
@@ -131,10 +139,6 @@ class ServeEngine:
                              "pass page_size= as well")
         self.sched = SlotScheduler(batch_slots, pool=self.pool,
                                    cluster_pure=router is not None)
-        self.runtime = runtime or Runtime(cfg, plan, scheme=scheme,
-                                          precision=precision,
-                                          compute_dtype=compute_dtype,
-                                          backend=backend, mesh=mesh)
         self.router = router
         if router is not None and not router.bound:
             router.bind(self.runtime)
@@ -245,18 +249,24 @@ class ServeEngine:
             stalled = [s for s in live if not self.pool.ensure(s, need(s))]
             if stalled:
                 self._stats["stalls"] += len(stalled)
-                if len(stalled) == len(live):
-                    # deadlock: every live slot needs a page and none can
-                    # retire to free one. Preempt the youngest slot (least
-                    # progress lost): its request goes back to the queue
-                    # head — replayed from its prompt on re-admission —
-                    # and its freed pages unblock the others.
-                    if len(live) == 1:
+                shard = self.pool.shard_of
+                stuck = [s for s in stalled
+                         if all(t in stalled for t in live
+                                if shard(t) == shard(s))]
+                if stuck:
+                    # deadlock: every live slot of a pool shard (the whole
+                    # pool, unless it is split per device) needs a page and
+                    # none can retire to free one. Preempt the shard's
+                    # youngest slot (least progress lost): its request goes
+                    # back to the queue head — replayed from its prompt on
+                    # re-admission — and its freed pages unblock the others.
+                    group = [s for s in stuck if shard(s) == shard(stuck[0])]
+                    if len(group) == 1:
                         raise RuntimeError(
                             "page pool exhausted: a single request needs "
                             "more pages than the pool holds; raise "
                             "pool_pages")
-                    victim = min(stalled,
+                    victim = min(group,
                                  key=lambda s: int(self.sched.cursor[s]))
                     req = self.sched.active[victim]
                     self.sched.release(victim)

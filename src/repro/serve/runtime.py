@@ -51,10 +51,13 @@ Mesh with ``data``/``model`` axes) places every executable over that mesh:
 * batch buckets round up to multiples of the dp axis size (after the
   power-of-two rounding), so every compiled batch splits evenly over
   ``data`` — no padded batch sharding;
-* the fused backend learns the mesh too (:meth:`ComputeBackend.with_mesh`)
-  and declines any GEMM whose per-device shard would be narrower than the
-  minimum Pallas tile on either splittable axis, falling back to
-  reference on that op.
+* on a mesh whose model axis is 1, each executable runs its whole step
+  per device (``shard_map``): a device computes exactly what the unmeshed
+  runtime computes for its rows, Pallas kernels included, and each holds
+  its own share of the decode caches (the paged pool too: the engine's
+  :class:`~repro.serve.scheduler.PagePool` hands a slot only pages of its
+  own device). With a model axis above 1, GSPMD partitions the step and
+  the fused backend (:meth:`ComputeBackend.with_mesh`) declines every op.
 """
 from __future__ import annotations
 
@@ -128,15 +131,26 @@ class Runtime:
         self.chunk = chunk
         # mesh-aware deployments shard params/batches via the training
         # Rules engine with fsdp off (inference: replicate params over
-        # 'data', TP over 'model'); the backend learns the topology so the
-        # fused kernels can decline shards narrower than their tile.
+        # 'data', TP over 'model').
         self.mesh = mesh
         self.rules = Rules(cfg, mesh, fsdp=False) if mesh is not None \
             else None
-        self.backend = get_backend(backend).with_mesh(mesh)
         # MoE expert capacity scales with the token count: padded tokens
         # would consume capacity and change routing for real rows.
         self.bucketed = cfg.moe is None
+        # On a mesh that splits only the batch (model axis 1), every
+        # executable runs its whole step once per device (shard_map): each
+        # device runs the unmeshed program on its rows, so meshed results
+        # equal unmeshed ones at the per-device batch, and the Pallas
+        # kernels need no partitioning. Elsewhere (a model axis above 1;
+        # MoE, whose unbucketed batches need not split) GSPMD partitions
+        # the step, and the fused backend declines every op.
+        self.per_device = (mesh is not None and self.bucketed
+                           and self.rules.msize == 1)
+        self._backend_arg = backend
+        self.backend = get_backend(backend)
+        if mesh is not None and not self.per_device:
+            self.backend = self.backend.with_mesh(mesh)
         # the scheme-identity half of every cache key: the compute backend's
         # name, the PrecisionPlan's stable fingerprint when one is bound
         # (else a structural hash of (execution plan, scheme)), and the
@@ -156,6 +170,9 @@ class Runtime:
                           mesh_fingerprint(mesh),
                           cluster)
         self._exe: dict[tuple, Callable] = {}
+        # argument shapes of the call that built each executable, so
+        # :meth:`executables` can hand back the compiled programs
+        self._arg_shapes: dict[tuple, tuple] = {}
         self._stats = {"calls": 0, "traces": 0,
                        "real_tokens": 0, "padded_tokens": 0}
 
@@ -179,10 +196,11 @@ class Runtime:
                      head=self.head, token_level=self.token_level,
                      min_batch=self.min_batch, min_len=self.min_len,
                      max_len=self.max_len, chunk=self.chunk,
-                     backend=backend or self.backend,
+                     backend=backend or self._backend_arg,
                      mesh=self.mesh if mesh == "inherit" else mesh,
                      cluster=cluster)
         rt._exe = self._exe
+        rt._arg_shapes = self._arg_shapes
         rt._stats = self._stats
         return rt
 
@@ -201,10 +219,32 @@ class Runtime:
             self._exe[key] = fn
         return fn
 
+    def _note_args(self, key: tuple, args: tuple) -> None:
+        if key not in self._arg_shapes:
+            self._arg_shapes[key] = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
+                                               jnp.result_type(a)), args)
+
+    def executables(self):
+        """``(key, jax.stages.Compiled)`` for every executable this cache
+        has run, lowered at the shapes of the call that built it. JAX's
+        trace and compile caches return the program already built, so
+        this neither retraces nor recompiles; the compiled text shows
+        which Pallas kernels (``tpu_custom_call``) an executable holds."""
+        for key, fn in self._exe.items():
+            if key in self._arg_shapes:
+                yield key, fn.lower(*self._arg_shapes[key]).compile()
+
     @property
     def _dp(self) -> int:
         """Batch-sharding factor of the bound mesh (1 when unmeshed)."""
         return self.rules.dp_size if self.rules is not None else 1
+
+    @property
+    def shards(self) -> int:
+        """Devices that each run the step on their own share of the batch
+        and of the decode caches (1 unless ``per_device``)."""
+        return self._dp if self.per_device else 1
 
     def _sharding(self, spec) -> "jax.sharding.NamedSharding":
         from jax.sharding import NamedSharding
@@ -241,7 +281,7 @@ class Runtime:
         cfg, plan, scheme = self.cfg, self.plan, self.scheme
         head, compute_dtype, chunk = self.head, self.compute_dtype, self.chunk
         backend = self.backend
-        constrain_kw = {} if self.rules is None else \
+        constrain_kw = {} if self.rules is None or self.per_device else \
             {"constrain": self.rules}
 
         def fn(params, inputs, lengths):
@@ -266,7 +306,12 @@ class Runtime:
                                 backend=backend, **constrain_kw)
             x = L.norm(x, params["final_norm"], cfg.norm_kind)
             return head(params, x) if head is not None else x
-        return fn
+        if not self.per_device:
+            return fn
+        from jax.sharding import PartitionSpec as P
+        rows = P(self.rules.axes.dp)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=(P(), rows, rows),
+                             out_specs=rows, check_vma=False)
 
     def _encode_shardings(self, params, padded: dict, lengths) -> tuple:
         """(in_shardings, out_shardings) for one encode executable: params
@@ -319,13 +364,16 @@ class Runtime:
         # input structure (which arrays, their dtypes) and the params
         # structure (float vs quantized leaves) are part of the compiled
         # signature: distinct signatures get distinct cache entries
-        fn = self._get(("encode", self._plan_key, Bb, Sb, _tree_sig(padded),
-                        _tree_sig(params)), self._build_encode,
+        fn_key = ("encode", self._plan_key, Bb, Sb, _tree_sig(padded),
+                  _tree_sig(params))
+        fn = self._get(fn_key, self._build_encode,
                        shardings=None if self.rules is None else
                        (lambda: self._encode_shardings(params, padded,
                                                        full_len)))
-        out = fn(params, {k: jnp.asarray(v) for k, v in padded.items()},
-                 jnp.asarray(full_len))
+        args = (params, {k: jnp.asarray(v) for k, v in padded.items()},
+                jnp.asarray(full_len))
+        self._note_args(fn_key, args)
+        out = fn(*args)
         self._stats["calls"] += 1
         self._stats["real_tokens"] += int(lengths.sum())
         self._stats["padded_tokens"] += Bb * Sb - int(lengths.sum())
@@ -342,16 +390,37 @@ class Runtime:
     def _build_decode(self):
         cfg, plan, scheme = self.cfg, self.plan, self.scheme
         compute_dtype, backend = self.compute_dtype, self.backend
-        constrain_kw = {} if self.rules is None else \
+        constrain_kw = {} if self.rules is None or self.per_device else \
             {"constrain": self.rules}
 
-        def fn(params, caches, tokens, pos, active, pages):
-            self._stats["traces"] += 1          # trace-time side effect
+        def step(params, caches, tokens, pos, active, pages):
             logits, caches = T.decode_step(
                 params, tokens, caches, pos, cfg, plan, scheme,
                 active=active, compute_dtype=compute_dtype, pages=pages,
                 backend=backend, **constrain_kw)
             return logits[:, -1, :], caches
+
+        def fn(params, caches, tokens, pos, active, pages):
+            self._stats["traces"] += 1          # trace-time side effect
+            if not self.per_device:
+                return step(params, caches, tokens, pos, active, pages)
+            from jax.sharding import PartitionSpec as P
+            dp = self.rules.axes.dp
+            rows = (tokens, pos, active) + (() if pages is None else (pages,))
+
+            def local(params, caches, tokens, pos, active, pages=None):
+                if pages is not None:
+                    # the table holds pool-wide page ids; this device holds
+                    # pages [i * n, (i + 1) * n) of the pool
+                    n = T.kv_geometry(caches)[2]
+                    base = jax.lax.axis_index(dp) * n
+                    pages = jnp.where(pages >= 0, pages - base, -1)
+                return step(params, caches, tokens, pos, active, pages)
+            return jax.shard_map(
+                local, mesh=self.mesh,
+                in_specs=(P(), P(None, dp)) + (P(dp),) * len(rows),
+                out_specs=(P(dp), P(None, dp)),
+                check_vma=False)(params, caches, *rows)
         return fn
 
     def _decode_shardings(self, params, caches) -> tuple:
@@ -359,9 +428,19 @@ class Runtime:
         from the rule table, caches batch/head-sharded per the cache rules,
         per-tick operands (tokens/pos/active/page table) replicated — they
         are tiny — and the caches come back under the same shardings they
-        went in."""
+        went in. Run per device, every cache leaf (per-slot rows and the
+        page pool alike) and every per-tick operand splits over the data
+        axes instead."""
         from jax.sharding import PartitionSpec
         r = self.rules
+        if self.per_device:
+            rows = self._sharding(PartitionSpec(r.axes.dp))
+            caches_sh = jax.tree_util.tree_map(
+                lambda _: self._sharding(PartitionSpec(None, r.axes.dp)),
+                caches)
+            in_s = (r.params_sharding(params), caches_sh, rows, rows, rows,
+                    rows)
+            return in_s, (rows, caches_sh)
         caches_sh = jax.tree_util.tree_map(
             self._sharding, r.cache_spec(caches),
             is_leaf=lambda x: isinstance(x, PartitionSpec))
@@ -377,6 +456,11 @@ class Runtime:
         the per-tick hot path: no signature hashing per token; its
         ``pages`` operand is the scheduler's page table (None for dense
         caches)."""
+        if self.per_device and any(leaf.shape[1] % self._dp for leaf in
+                                   jax.tree_util.tree_leaves(caches)):
+            raise ValueError(
+                f"per-device decode needs the slots and the page pool to "
+                f"split evenly over the mesh's {self._dp} data shards")
         key = ("decode", self._plan_key, self._decode_batch(caches),
                T.kv_geometry(caches), _tree_sig(caches), _tree_sig(params))
         fn = self._get(key, self._build_decode,
@@ -385,9 +469,12 @@ class Runtime:
 
         def step(params, caches, tokens, pos, active, pages=None):
             self._stats["calls"] += 1
-            return fn(params, caches, jnp.asarray(tokens),
-                      jnp.asarray(pos), jnp.asarray(active),
-                      None if pages is None else jnp.asarray(pages))
+            args = (params, caches, jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(active),
+                    None if pages is None else jnp.asarray(pages))
+            if key not in self._arg_shapes:
+                self._note_args(key, args)
+            return fn(*args)
         return step
 
     @staticmethod

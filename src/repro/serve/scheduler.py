@@ -59,16 +59,31 @@ class PagePool:
     past a page boundary and returned wholesale on :meth:`release` —
     the paging analogue of vLLM's block allocator, sized so the pool can
     oversubscribe max-length worst cases when typical sequences are short.
+
+    With ``shards`` > 1 the slots and the pages are split into that many
+    equal, contiguous parts — one per device when each device of a
+    data-parallel mesh holds its own part of the pool — and a slot only
+    ever gets pages of its own part.
     """
 
     def __init__(self, num_pages: int, page_size: int, slots: int,
-                 pages_per_slot: int):
+                 pages_per_slot: int, *, shards: int = 1):
+        if slots % shards or num_pages % shards:
+            raise ValueError(f"{slots} slots and {num_pages} pages do not "
+                             f"split evenly into {shards} shards")
         self.num_pages = num_pages
         self.page_size = page_size
         self.pages_per_slot = pages_per_slot
         self.table = -np.ones((slots, pages_per_slot), np.int32)
-        self.free: deque = deque(range(num_pages))
+        self._slots_per_shard = slots // shards
+        per = num_pages // shards
+        self.free: list[deque] = [deque(range(k * per, (k + 1) * per))
+                                  for k in range(shards)]
         self.alloc_failures = 0
+
+    def shard_of(self, s: int) -> int:
+        """The pool shard slot ``s`` takes its pages from."""
+        return s // self._slots_per_shard
 
     def ensure(self, s: int, tokens: int) -> bool:
         """Grow slot ``s`` to cover ``tokens`` total tokens. Returns False
@@ -79,11 +94,12 @@ class PagePool:
             raise ValueError(f"slot {s} needs {need} pages > "
                              f"pages_per_slot={self.pages_per_slot}")
         have = int((self.table[s] >= 0).sum())
-        if need - have > len(self.free):
+        free = self.free[self.shard_of(s)]
+        if need - have > len(free):
             self.alloc_failures += 1
             return False
         for j in range(have, need):
-            self.table[s, j] = self.free.popleft()
+            self.table[s, j] = free.popleft()
         return True
 
     def release(self, s: int) -> list[int]:
@@ -91,12 +107,12 @@ class PagePool:
         engine invalidates their ``pages_pos`` rows so a reallocated page
         never leaks another request's positions)."""
         freed = [int(p) for p in self.table[s] if p >= 0]
-        self.free.extend(freed)
+        self.free[self.shard_of(s)].extend(freed)
         self.table[s] = -1
         return freed
 
     def pages_in_use(self) -> int:
-        return self.num_pages - len(self.free)
+        return self.num_pages - sum(len(f) for f in self.free)
 
     def bytes_per_page(self, caches) -> int:
         """Sum of one page's bytes across every paged leaf of ``caches``."""

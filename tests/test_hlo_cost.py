@@ -56,16 +56,8 @@ def test_scan_cache_update_charges_slice_not_buffer():
 
 
 def test_collectives_counted_with_trips():
-    # jax<0.5 has neither sharding.AxisType nor top-level shard_map
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    mesh_kw = {"axis_types": (axis_type.Auto,)} if axis_type else {}
-    mesh = jax.make_mesh((1,), ("d",), **mesh_kw)
-    shard_map = getattr(jax, "shard_map", None)
-    sm_kw = {}
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-        # old-jax replication checker rejects psum-in-scan carries
-        sm_kw = {"check_rep": False}
+    mesh = jax.make_mesh((1,), ("d",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     def fn(xs):
         def body(c, x):
@@ -74,9 +66,9 @@ def test_collectives_counted_with_trips():
         return y
 
     with mesh:
-        sm = shard_map(fn, mesh=mesh,
-                       in_specs=jax.sharding.PartitionSpec(None, None),
-                       out_specs=jax.sharding.PartitionSpec(None), **sm_kw)
+        sm = jax.shard_map(fn, mesh=mesh,
+                           in_specs=jax.sharding.PartitionSpec(None, None),
+                           out_specs=jax.sharding.PartitionSpec(None))
         hlo = jax.jit(sm).lower(
             jax.ShapeDtypeStruct((4, 64), jnp.float32)).compile().as_text()
     r = analyze_hlo(hlo)

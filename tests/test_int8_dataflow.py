@@ -349,8 +349,10 @@ def test_decode_two_pass_uint8_softmax(p_scale):
         for j in range(-(-int(lengths[b]) // ps)):
             pt[b, j] = b * pps + j
     scale = 1.0 / np.sqrt(hd)
+    # the kernel takes head-major (NP, Hkv, ps, hd) pages
     got = ops.decode_attention(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt),
+        jnp.asarray(q), jnp.asarray(k.swapaxes(1, 2)),
+        jnp.asarray(v.swapaxes(1, 2)), jnp.asarray(pt),
         jnp.asarray(lengths), k_scale=jnp.asarray(ks),
         v_scale=jnp.asarray(vs), per_head=True, scale=float(scale),
         p_scale=p_scale)

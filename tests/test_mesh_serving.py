@@ -19,7 +19,7 @@ from repro.configs import get_config
 from repro.core.precision import make_policy
 from repro.core.quantize import QuantizedTensor
 from repro.distributed.sharding import Rules, mesh_fingerprint
-from repro.kernels.backend import MIN_SHARD_TILE, FusedBackend, get_backend
+from repro.kernels.backend import FusedBackend, get_backend
 from repro.models import transformer as T
 from repro.serve import Runtime
 
@@ -144,25 +144,35 @@ def test_meshed_bucket_rounds_to_dp_multiples():
 
 
 def test_fused_backend_declines_sub_tile_shards():
-    """Under TP the fused GEMM must decline when the per-device output
-    shard is narrower than one kernel tile (reference runs that op)."""
+    """Mosaic kernels cannot be partitioned by the compiler: under TP the
+    fused backend declines every op, however wide the per-device shard
+    (reference runs it, GSPMD-partitioned); on a pure data-parallel mesh
+    the runtime runs each step per device and keeps the unmeshed backend,
+    kernels and all."""
     fused = get_backend("fused")
-    assert fused.model_shards == 1
-    bound = fused.with_mesh(FakeMesh({"data": 1, "model": 2}))
-    assert isinstance(bound, FusedBackend) and bound.model_shards == 2
-    assert fused.model_shards == 1              # with_mesh copies
-    T2 = 2 * MIN_SHARD_TILE
-    w_narrow = QuantizedTensor(jnp.zeros((T2, MIN_SHARD_TILE), jnp.int8),
-                               jnp.ones((1, MIN_SHARD_TILE)), None)
-    assert not bound._shard_too_narrow(T2, T2)          # both axes clear
-    # column-parallel case: N splits sub-tile (128/2 = 64 < tile)
-    assert bound._shard_too_narrow(T2, MIN_SHARD_TILE)
-    # row-parallel case: K splits sub-tile — same decline, other axis
-    assert bound._shard_too_narrow(MIN_SHARD_TILE, T2)
+    T2 = 256
+    w = QuantizedTensor(jnp.zeros((T2, T2), jnp.int8),
+                        jnp.ones((1, T2)), None)
     x = jnp.zeros((4, T2), jnp.float32)
-    assert bound.linear(x, {"w": w_narrow}) is None     # declined
-    # non-divisible dims replicate under the rules — full width, no decline
-    assert not bound._shard_too_narrow(T2 + 1, MIN_SHARD_TILE + 1)
+    tp = fused.with_mesh(FakeMesh({"data": 1, "model": 2}))
+    assert isinstance(tp, FusedBackend)
+    assert tp.linear(x, {"w": w}) is None               # declined
+    assert fused.linear(x, {"w": w}) is not None        # with_mesh copies
+    # the label says no kernel will run
+    assert tp.describe() == "fused[declined: tensor-parallel mesh]"
+    cfg = tiny_bert(2)
+    plan = T.build_plan(cfg, make_policy(cfg, "float"))
+    rt_tp = Runtime(cfg, plan, backend="fused",
+                    mesh=FakeMesh({"data": 2, "model": 2}))
+    assert not rt_tp.per_device and rt_tp.shards == 1
+    assert rt_tp.backend.describe() == "fused[declined: tensor-parallel mesh]"
+    rt_dp = Runtime(cfg, plan, backend="fused",
+                    mesh=FakeMesh({"data": 3, "model": 1}))
+    assert rt_dp.per_device and rt_dp.shards == 3
+    assert rt_dp.backend.describe() == "fused"
+    assert rt_dp.backend.linear(x, {"w": w}) is not None
+    # an unmeshed sibling of the TP runtime gets its kernels back
+    assert rt_tp.share(plan, mesh=None).backend.describe() == "fused"
     # the reference backend is sharding-oblivious: with_mesh is identity
     ref = get_backend("reference")
     assert ref.with_mesh(FakeMesh({"data": 8, "model": 8})) is ref
@@ -203,7 +213,11 @@ def test_host_mesh_serve_matches_unmeshed_golden_plan(tmp_path):
         stats = eng.calibrate(params, batches, precision=golden)
 
         # sharded calibration == unsharded: batches placed over the data
-        # axis reduce to identical amax values (observers are global maxes)
+        # axis reduce to the same amax values (observers are global maxes).
+        # The max itself is exact, but the sharded program is compiled
+        # separately and may order the float adds of the activations it
+        # maxes over differently, so allow 16 float32 ulps (the softmax
+        # amax of layer 2 moves by 7).
         mesh_dp = make_serving_mesh("2,1")
         sh = NamedSharding(mesh_dp, P("data"))
         sharded = [{k: jax.device_put(jnp.asarray(v), sh)
@@ -212,7 +226,9 @@ def test_host_mesh_serve_matches_unmeshed_golden_plan(tmp_path):
         for layer, sites in stats.items():
             for site, amax in sites.items():
                 got = stats_sh[layer][site]
-                assert got == amax, (layer, site, got, amax)
+                np.testing.assert_allclose(
+                    got, amax, rtol=16 * np.finfo(np.float32).eps, atol=0,
+                    err_msg=f"{layer}/{site}")
 
         qparams, qplan = eng.apply(params, stats, golden)
         head = lambda p, h: T.apply_head(h, p, "cls")
@@ -224,10 +240,20 @@ def test_host_mesh_serve_matches_unmeshed_golden_plan(tmp_path):
         rt0 = Runtime(cfg, qplan, precision=golden, head=head)
         ref = rt0.encode(qparams, inputs, lengths)
 
-        # Nx1 = pure DP: per-row compute is untouched -> bit-identical
+        # Nx1 = pure DP: per-row compute is untouched, and each device runs
+        # the unmeshed program at its share of the batch (2 of the 4 bucket
+        # rows) -> bit-identical to that program. XLA:CPU compiles float
+        # dots differently for 2 and 4 rows, so the 4-row unmeshed logits
+        # differ from both in the last bits.
         rt_dp = rt0.share(qplan, precision=golden, mesh=mesh_dp)
+        rt_half = Runtime(cfg, qplan, precision=golden, head=head,
+                          min_batch=2)
+        per_device = np.concatenate([
+            rt_half.encode(qparams, {k: v[a:a + 2] for k, v in
+                                     inputs.items()}, lengths[a:a + 2])
+            for a in (0, 2)])
         np.testing.assert_array_equal(
-            rt_dp.encode(qparams, inputs, lengths), ref)
+            rt_dp.encode(qparams, inputs, lengths), per_device)
         # 1xN = TP: row-parallel psums reorder float adds -> allclose
         rt_tp = rt0.share(qplan, precision=golden,
                           mesh=make_serving_mesh("1,2"))
@@ -251,6 +277,73 @@ def test_host_mesh_serve_matches_unmeshed_golden_plan(tmp_path):
         for i in range(3):
             assert int(done[i].prediction) == int(ref[i].argmax()), i
         print("OK")
+    """)
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"))
+    env.pop("XLA_FLAGS", None)          # the script sets its own
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env, cwd=str(repo))
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
+@pytest.mark.parametrize("backend", ["reference", "fused"])
+def test_host_mesh_decode_matches_per_device_decode(backend):
+    """Nx1 (DP) decode runs each step per device, each device holding its
+    own share of the paged int8 KV pool: a 2-slot engine on 2 host devices
+    must give every request the tokens, and the logits behind each, of a
+    1-slot unmeshed engine, bit for bit. Subprocess: the host device count
+    must be forced before jax initializes."""
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        import jax
+        import numpy as np
+        from repro.configs import get_config
+        from repro.core.precision import EncoderPolicy
+        from repro.launch.mesh import make_serving_mesh
+        from repro.models import transformer as T
+        from repro.serve import Request, ServeEngine
+
+        cfg = get_config("qwen2-0.5b").reduced().replace(num_layers=2)
+        policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+        plan = T.build_plan(cfg, policy)
+        params = T.init_params(jax.random.PRNGKey(0), cfg, policy)
+        prompts = [[2, 17, 9], [5, 40, 3, 3, 8], [11, 3, 7, 1], [23, 8]]
+
+        def serve(slots, mesh=None):
+            eng = ServeEngine(cfg, params, plan, batch_slots=slots,
+                              max_len=32, backend="{backend}", mesh=mesh,
+                              page_size=4, kv_cache="int8_per_token")
+            reqs = [Request(uid=i, prompt=p, max_tokens=6)
+                    for i, p in enumerate(prompts)]
+            for r in reqs:
+                eng.submit(r)
+            inner, rows = eng._decode, {{}}
+
+            def record(params, caches, tokens, pos, active, pages=None):
+                held = {{s: (r.uid, int(eng.sched.cursor[s]))
+                        for s, r in enumerate(eng.sched.active)
+                        if r is not None}}
+                logits, caches = inner(params, caches, tokens, pos, active,
+                                       pages)
+                for s, key in held.items():
+                    if bool(active[s]):
+                        rows[key] = np.asarray(logits[s])
+                return logits, caches
+            eng._decode = record
+            eng.run()
+            return rows, {{r.uid: r.output for r in reqs}}, eng
+
+        rows1, toks1, _ = serve(1)
+        rows2, toks2, eng = serve(2, make_serving_mesh("2,1"))
+        assert eng.runtime.per_device and eng.pool.shard_of(1) == 1
+        assert toks2 == toks1, (toks2, toks1)
+        assert rows2.keys() == rows1.keys()
+        for key in rows1:
+            np.testing.assert_array_equal(rows2[key], rows1[key],
+                                          err_msg=str(key))
+        print("OK", len(rows1))
     """)
     repo = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ, PYTHONPATH=str(repo / "src"))

@@ -80,6 +80,12 @@ def _reference_decode_attention(q, k_pages, v_pages, page_table, lengths,
     return out
 
 
+def _head_major(*arrays):
+    """Token-major (NP, ps, Hkv, ...) test pages -> the kernel's head-major
+    (NP, Hkv, ps, ...) layout."""
+    return [jnp.asarray(np.swapaxes(a, 1, 2)) for a in arrays]
+
+
 def _make_paged_case(rng, *, B=3, Hkv=2, g=2, hd=8, ps=4, pps=3):
     NP = B * pps
     q = rng.standard_normal((B, Hkv, g, hd)).astype(np.float32)
@@ -101,10 +107,11 @@ def test_kernel_matches_reference_per_token(softcap):
     rng = np.random.default_rng(0)
     q, k, v, ks, vs, pt, lengths = _make_paged_case(rng)
     scale = 1.0 / np.sqrt(q.shape[-1])
+    kh, vh, ksh, vsh = _head_major(k, v, ks, vs)
     got = ops.decode_attention(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt),
-        jnp.asarray(lengths), k_scale=jnp.asarray(ks),
-        v_scale=jnp.asarray(vs), per_head=False, scale=float(scale),
+        jnp.asarray(q), kh, vh, jnp.asarray(pt),
+        jnp.asarray(lengths), k_scale=ksh,
+        v_scale=vsh, per_head=False, scale=float(scale),
         softcap=softcap)
     want = _reference_decode_attention(q, k, v, pt, lengths, ks, vs,
                                        per_head=False, scale=scale,
@@ -119,8 +126,9 @@ def test_kernel_matches_reference_per_head():
     ks = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
     vs = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
     scale = 1.0 / np.sqrt(q.shape[-1])
+    kh, vh = _head_major(k, v)
     got = ops.decode_attention(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt),
+        jnp.asarray(q), kh, vh, jnp.asarray(pt),
         jnp.asarray(lengths), k_scale=jnp.asarray(ks),
         v_scale=jnp.asarray(vs), per_head=True, scale=float(scale))
     want = _reference_decode_attention(q, k, v, pt, lengths, ks, vs,
@@ -134,10 +142,11 @@ def test_kernel_inactive_slot_outputs_zero():
     q, k, v, ks, vs, pt, lengths = _make_paged_case(rng)
     lengths = lengths.copy()
     lengths[1] = 0                     # masked slot, pages still allocated
+    kh, vh, ksh, vsh = _head_major(k, v, ks, vs)
     got = ops.decode_attention(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt),
-        jnp.asarray(lengths), k_scale=jnp.asarray(ks),
-        v_scale=jnp.asarray(vs), per_head=False, scale=0.25)
+        jnp.asarray(q), kh, vh, jnp.asarray(pt),
+        jnp.asarray(lengths), k_scale=ksh,
+        v_scale=vsh, per_head=False, scale=0.25)
     assert np.all(np.asarray(got)[1] == 0.0)
     assert np.any(np.asarray(got)[0] != 0.0)
 
@@ -350,6 +359,29 @@ def test_preemption_under_pool_pressure_preserves_outputs(qwen_float):
     assert eng.stats["preemptions"] > 0
 
 
+def test_preemption_per_pool_shard_preserves_outputs(qwen_float):
+    """A pool split per device deadlocks one shard at a time: slots 0-1
+    run out of pages 0-3 while slots 2-3 still progress on pages 4-7. The
+    stuck shard preempts its youngest slot at once, and every request
+    still finishes with the outputs of a roomy pool."""
+    cfg, params, plan = qwen_float
+
+    def serve(pool_pages, shards):
+        eng = ServeEngine(cfg, params, plan, batch_slots=4, max_len=64,
+                          page_size=4, pool_pages=pool_pages)
+        eng.pool = eng.sched.pool = PagePool(
+            pool_pages, 4, 4, eng.pool.pages_per_slot, shards=shards)
+        for i, p in enumerate(PROMPTS):     # uids 2-3 fit in 2 pages
+            eng.submit(Request(uid=i, prompt=list(p),
+                               max_tokens=8 if i < 2 else 4))
+        return {r.uid: r.output for r in eng.run()}, eng
+
+    roomy, _ = serve(64, 1)
+    tight, eng = serve(8, 2)
+    assert tight == roomy
+    assert eng.stats["preemptions"] > 0
+
+
 def test_single_oversized_request_raises(qwen_float):
     cfg, params, plan = qwen_float
     eng = ServeEngine(cfg, params, plan, batch_slots=1, max_len=64,
@@ -372,6 +404,26 @@ def test_pagepool_unit():
     assert pool.ensure(0, 5) and pool.pages_in_use() == 3
     with pytest.raises(ValueError, match="pages_per_slot"):
         pool.ensure(0, 7)                     # needs 4 > pages_per_slot
+
+
+def test_pagepool_shards_keep_pages_on_the_slots_device():
+    """Split per device, a slot only takes pages from its own shard: slots
+    0-1 from pages 0-3, slots 2-3 from pages 4-7, and one shard running dry
+    stalls only its own slots."""
+    pool = PagePool(num_pages=8, page_size=2, slots=4, pages_per_slot=4,
+                    shards=2)
+    assert [pool.shard_of(s) for s in range(4)] == [0, 0, 1, 1]
+    assert pool.ensure(0, 6) and pool.ensure(2, 2)
+    assert set(pool.table[0][:3]) <= {0, 1, 2, 3}
+    assert set(pool.table[2][:1]) <= {4, 5, 6, 7}
+    assert not pool.ensure(1, 4)              # shard 0 has one page left
+    assert pool.ensure(3, 6)                  # shard 1 still has three
+    assert pool.pages_in_use() == 7
+    assert sorted(pool.release(0)) == [0, 1, 2]
+    assert pool.ensure(1, 4) and set(pool.table[1][:2]) <= {0, 1, 2, 3}
+    with pytest.raises(ValueError, match="split evenly"):
+        PagePool(num_pages=6, page_size=2, slots=4, pages_per_slot=4,
+                  shards=4)
 
 
 def test_scheduler_stashes_freed_pages():

@@ -60,8 +60,8 @@ def request(port: int, method: str, path: str, payload=None, headers=None):
 def boot(args) -> tuple[subprocess.Popen, int]:
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     cmd = [sys.executable, "-m", "repro.launch.server",
-           "--arch", args.arch, "--task", args.task, "--port", "0",
-           "--slots", "2", "--max-len", "64"]
+           "--arch", args.arch, "--reduced", "--task", args.task,
+           "--port", "0", "--slots", "2", "--max-len", "64"]
     if args.plan:
         cmd += ["--plan", args.plan]
     else:
