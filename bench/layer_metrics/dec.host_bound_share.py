@@ -1,0 +1,8 @@
+"""Share of the traced window, in percent, in which the device sits idle
+while the host is in a decode phase other than ``samp.dec.fetch`` (device
+layer)."""
+import hostphases
+
+
+def read(run):
+    return hostphases.host_bound_share(run, "dec")

@@ -70,8 +70,8 @@ class EncoderServeEngine:
         self.router = router
         if router is not None and not router.bound:
             router.bind(self.runtime)
-        self._stats = {"requests": 0, "batches": 0, "retired": 0,
-                       "batched_rows": 0}
+        self._stats = {"requests": 0, "steps": 0, "batches": 0,
+                       "retired": 0, "batched_rows": 0}
 
     # -- request lifecycle ---------------------------------------------------
     def submit(self, req: EncoderRequest,
@@ -91,43 +91,62 @@ class EncoderServeEngine:
     # -- the serving loop ----------------------------------------------------
     def step(self, now: Optional[float] = None,
              force: bool = False) -> list[EncoderRequest]:
-        """Serve every micro-batch that is due; returns retired requests."""
+        """Serve every micro-batch that is due; returns retired requests.
+        Each phase is a ``samp.enc.<phase>`` span and counter (see
+        :class:`~repro.serve.metrics.Phases`) inside ``samp.enc.step``,
+        which carries the step's ``step`` number."""
+        self._stats["steps"] += 1
+        n = self._stats["steps"]
+        phase = self.runtime.phases
         retired: list[EncoderRequest] = []
-        for blen, reqs in self.batcher.ready(now, force=force):
-            B = len(reqs)
-            tokens = np.zeros((B, blen), np.int32)
-            segments = np.zeros((B, blen), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            for i, req in enumerate(reqs):
-                n = len(req.tokens)
-                tokens[i, :n] = req.tokens
-                if req.segments is not None:
-                    segments[i, :n] = req.segments
-                lengths[i] = n
-            inputs = {"tokens": tokens}
-            if self.cfg.num_segments:
-                inputs["segments"] = segments
-            if self.router is not None:
-                # batches are cluster-pure by construction (the MicroBatcher
-                # keys queues on (bucket, cluster)), so one entry serves all
-                entry = self.router.entry(reqs[0].cluster)
-                logits = entry.runtime.encode(entry.params, inputs, lengths)
-            else:
-                logits = self.runtime.encode(self.params, inputs, lengths)
-            for i, req in enumerate(reqs):
-                row = logits[i]
-                if self.target.token_level:
-                    row = row[:int(lengths[i])]
-                req.logits = row
-                # the registered head's own decision rule (argmax for the
-                # built-ins; custom TargetSpecs may override)
-                req.prediction = np.asarray(self.target.predict(row))
-                req.done = True
-                retired.append(req)
-            self._stats["batches"] += 1
-            self._stats["batched_rows"] += B
-            self._stats["retired"] += B
+        with phase("samp.enc.step", step=n):
+            with phase("samp.enc.flush"):
+                due = self.batcher.ready(now, force=force)
+            for blen, reqs in due:
+                with phase("samp.enc.assemble"):
+                    runtime, params, inputs, lengths = self._assemble(blen,
+                                                                      reqs)
+                logits = runtime.encode(params, inputs, lengths)
+                with phase("samp.enc.predict"):
+                    for i, req in enumerate(reqs):
+                        row = logits[i]
+                        if self.target.token_level:
+                            row = row[:int(lengths[i])]
+                        req.logits = row
+                        # the registered head's own decision rule (argmax
+                        # for the built-ins; custom TargetSpecs may
+                        # override)
+                        req.prediction = np.asarray(self.target.predict(row))
+                        req.step = n
+                        req.done = True
+                        retired.append(req)
+                B = len(reqs)
+                self._stats["batches"] += 1
+                self._stats["batched_rows"] += B
+                self._stats["retired"] += B
         return retired
+
+    def _assemble(self, blen: int, reqs: list[EncoderRequest]):
+        """(runtime, params, inputs, lengths) of one micro-batch."""
+        B = len(reqs)
+        tokens = np.zeros((B, blen), np.int32)
+        segments = np.zeros((B, blen), np.int32)
+        lengths = np.zeros((B,), np.int32)
+        for i, req in enumerate(reqs):
+            n = len(req.tokens)
+            tokens[i, :n] = req.tokens
+            if req.segments is not None:
+                segments[i, :n] = req.segments
+            lengths[i] = n
+        inputs = {"tokens": tokens}
+        if self.cfg.num_segments:
+            inputs["segments"] = segments
+        if self.router is None:
+            return self.runtime, self.params, inputs, lengths
+        # batches are cluster-pure by construction (the MicroBatcher keys
+        # queues on (bucket, cluster)), so one entry serves all
+        entry = self.router.entry(reqs[0].cluster)
+        return entry.runtime, entry.params, inputs, lengths
 
     def run(self, now: Optional[float] = None) -> list[EncoderRequest]:
         """Drain the queues (force-flush partial buckets too)."""
@@ -140,6 +159,6 @@ class EncoderServeEngine:
         from repro.serve.metrics import engine_counters
         s = dict(self._stats)
         s.update({f"runtime_{k}": v for k, v in self.runtime.stats.items()
-                  if k != "buckets"})
+                  if k not in ("buckets", "phase_s", "phase_n")})
         s.update(engine_counters(self))
         return s

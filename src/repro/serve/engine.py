@@ -47,9 +47,13 @@ class Request:
     # id assigned at admission — decode batches stay cluster-pure
     traffic_class: Optional[str] = None
     cluster: int = 0
-    # engine-filled:
+    # engine-filled: queued at ``arrival`` (again after a preemption),
+    # ``queue_wait`` seconds queued in all, last admitted at tick ``step``
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    arrival: Optional[float] = None
+    queue_wait: float = 0.0
+    step: Optional[int] = None
 
     @property
     def text_len(self) -> int:
@@ -154,8 +158,8 @@ class ServeEngine:
                         else self.runtime.decode_fn(params, self.caches))
         self._decode_by_cluster: dict[int, object] = {}
         self.rng = np.random.default_rng(seed)
-        self._stats = {"ticks": 0, "tokens": 0, "retired": 0, "stalls": 0,
-                       "preemptions": 0, "requests": 0}
+        self._stats = {"steps": 0, "ticks": 0, "tokens": 0, "retired": 0,
+                       "stalls": 0, "preemptions": 0, "requests": 0}
         # set when a deadlock preemption proves the pool cannot hold the
         # current working set: admission pauses until pages are freed, so
         # preempted requests don't thrash straight back into a slot
@@ -229,89 +233,107 @@ class ServeEngine:
                     return leaf
                 return jax.tree_util.tree_map_with_path(inval, caches)
             self._inval_fn = jax.jit(inval_tree, donate_argnums=(0,))
-        self.caches = self._inval_fn(self.caches, jnp.asarray(pad))
+        with self.runtime.phases("samp.dec.drain"):
+            self.caches = self._inval_fn(self.caches, jnp.asarray(pad))
 
     # -- the serving loop ---------------------------------------------------------
     def step(self) -> list[Request]:
-        """One engine tick = one compiled decode step for the whole batch."""
-        if not self._admission_hold:
-            for s in self.sched.admit():
-                self._reset_slot(s)
-        self._drain_freed()
-        live = self.sched.live()
-        if not live:
-            return []
-        if self.pool is not None:
-            # grow each live slot's page allocation to cover this tick's
-            # token; slots the pool cannot serve stall (masked inactive,
-            # cursor not advanced) until a retirement frees pages
-            need = lambda s: int(self.sched.cursor[s]) + 1
-            stalled = [s for s in live if not self.pool.ensure(s, need(s))]
-            if stalled:
-                self._stats["stalls"] += len(stalled)
-                shard = self.pool.shard_of
-                stuck = [s for s in stalled
-                         if all(t in stalled for t in live
-                                if shard(t) == shard(s))]
-                if stuck:
-                    # deadlock: every live slot of a pool shard (the whole
-                    # pool, unless it is split per device) needs a page and
-                    # none can retire to free one. Preempt the shard's
-                    # youngest slot (least progress lost): its request goes
-                    # back to the queue head — replayed from its prompt on
-                    # re-admission — and its freed pages unblock the others.
-                    group = [s for s in stuck if shard(s) == shard(stuck[0])]
-                    if len(group) == 1:
-                        raise RuntimeError(
-                            "page pool exhausted: a single request needs "
-                            "more pages than the pool holds; raise "
-                            "pool_pages")
-                    victim = min(group,
-                                 key=lambda s: int(self.sched.cursor[s]))
-                    req = self.sched.active[victim]
-                    self.sched.release(victim)
-                    self.sched.queue.appendleft(req)
-                    self._drain_freed()
-                    self._admission_hold = True
-                    self._stats["preemptions"] += 1
-                    live.remove(victim)
-                    stalled = [s for s in live
-                               if not self.pool.ensure(s, need(s))]
-                live = [s for s in live if s not in stalled]
-                if not live:
-                    return []
-        tokens = np.zeros((self.slots, 1), np.int32)
-        pos = np.zeros(self.slots, np.int32)
-        active = np.zeros(self.slots, bool)
-        for s in live:
-            req = self.sched.active[s]
-            c = int(self.sched.cursor[s])
-            # prompt, then generated tokens: at steady state this is
-            # output[-1]; after a page-pool preemption it replays the
-            # already-generated prefix before sampling resumes
-            tokens[s, 0] = (req.prompt[c] if c < len(req.prompt)
-                            else req.output[c - len(req.prompt)])
-            pos[s] = c
-            active[s] = True
-        pages = (jnp.asarray(self.pool.table) if self.pool is not None
-                 else None)
-        if self.router is not None:
-            # cluster-pure batch: the scheduler guarantees every live slot
-            # shares one cluster — run that cluster's executable + params
-            entry = self.router.entry(self.sched.active_cluster)
-            decode = self._decode_by_cluster.get(entry.cluster)
-            if decode is None:
-                decode = entry.runtime.decode_fn(entry.params, self.caches)
-                self._decode_by_cluster[entry.cluster] = decode
-            step_params = entry.params
-        else:
-            decode, step_params = self._decode, self.params
-        logits, self.caches = decode(
-            step_params, self.caches, tokens, pos, active, pages)
-        logits = np.asarray(jax.device_get(logits), np.float32)
-        self._stats["ticks"] += 1
-        self._stats["tokens"] += len(live)
+        """One engine tick = one compiled decode step for the whole batch.
+        Each phase is a ``samp.dec.<phase>`` span and counter (see
+        :class:`~repro.serve.metrics.Phases`) inside ``samp.dec.tick``,
+        which carries the tick's ``step`` number."""
+        self._stats["steps"] += 1
+        n = self._stats["steps"]
+        phase = self.runtime.phases
+        with phase("samp.dec.tick", step=n):
+            with phase("samp.dec.admit"):
+                if not self._admission_hold:
+                    for s in self.sched.admit():
+                        self.sched.active[s].step = n
+                        self._reset_slot(s)
+            self._drain_freed()
+            live = self.sched.live()
+            if live and self.pool is not None:
+                with phase("samp.dec.pages"):
+                    live = self._ensure_pages(live)
+            if not live:
+                return []
+            with phase("samp.dec.assemble"):
+                tokens = np.zeros((self.slots, 1), np.int32)
+                pos = np.zeros(self.slots, np.int32)
+                active = np.zeros(self.slots, bool)
+                for s in live:
+                    req = self.sched.active[s]
+                    c = int(self.sched.cursor[s])
+                    # prompt, then generated tokens: at steady state this is
+                    # output[-1]; after a page-pool preemption it replays
+                    # the already-generated prefix before sampling resumes
+                    tokens[s, 0] = (req.prompt[c] if c < len(req.prompt)
+                                    else req.output[c - len(req.prompt)])
+                    pos[s] = c
+                    active[s] = True
+                pages = (jnp.asarray(self.pool.table)
+                         if self.pool is not None else None)
+                decode, step_params = self._executable()
+            logits, self.caches = decode(
+                step_params, self.caches, tokens, pos, active, pages)
+            with phase("samp.dec.fetch"):
+                logits = np.asarray(jax.device_get(logits), np.float32)
+            self._stats["ticks"] += 1
+            self._stats["tokens"] += len(live)
+            with phase("samp.dec.sample"):
+                return self._sample(live, logits)
 
+    def _ensure_pages(self, live: list[int]) -> list[int]:
+        """Grow each live slot's page allocation to cover this tick's
+        token; returns the slots that run. Slots the pool cannot serve
+        stall (masked inactive, cursor not advanced) until a retirement
+        frees pages."""
+        need = lambda s: int(self.sched.cursor[s]) + 1
+        stalled = [s for s in live if not self.pool.ensure(s, need(s))]
+        if not stalled:
+            return live
+        self._stats["stalls"] += len(stalled)
+        shard = self.pool.shard_of
+        stuck = [s for s in stalled
+                 if all(t in stalled for t in live if shard(t) == shard(s))]
+        if stuck:
+            # deadlock: every live slot of a pool shard (the whole pool,
+            # unless it is split per device) needs a page and none can
+            # retire to free one. Preempt the shard's youngest slot (least
+            # progress lost): its request goes back to the queue head —
+            # replayed from its prompt on re-admission — and its freed
+            # pages unblock the others.
+            group = [s for s in stuck if shard(s) == shard(stuck[0])]
+            if len(group) == 1:
+                raise RuntimeError(
+                    "page pool exhausted: a single request needs more pages "
+                    "than the pool holds; raise pool_pages")
+            victim = min(group, key=lambda s: int(self.sched.cursor[s]))
+            self.sched.preempt(victim)
+            self._drain_freed()
+            self._admission_hold = True
+            self._stats["preemptions"] += 1
+            live.remove(victim)
+            stalled = [s for s in live if not self.pool.ensure(s, need(s))]
+        return [s for s in live if s not in stalled]
+
+    def _executable(self):
+        """(decode step, params) of this tick."""
+        if self.router is None:
+            return self._decode, self.params
+        # cluster-pure batch: the scheduler guarantees every live slot
+        # shares one cluster — run that cluster's executable + params
+        entry = self.router.entry(self.sched.active_cluster)
+        decode = self._decode_by_cluster.get(entry.cluster)
+        if decode is None:
+            decode = entry.runtime.decode_fn(entry.params, self.caches)
+            self._decode_by_cluster[entry.cluster] = decode
+        return decode, entry.params
+
+    def _sample(self, live: list[int], logits: np.ndarray) -> list[Request]:
+        """Advance the live slots' cursors and pick each next token from
+        this tick's logits; returns the requests that retired."""
         retired: list[Request] = []
         for s in live:
             req = self.sched.active[s]
@@ -367,6 +389,6 @@ class ServeEngine:
         from repro.serve.metrics import engine_counters
         s = dict(self._stats)
         s.update({f"runtime_{k}": v for k, v in self.runtime.stats.items()
-                  if k != "buckets"})
+                  if k not in ("buckets", "phase_s", "phase_n")})
         s.update(engine_counters(self))
         return s

@@ -36,7 +36,7 @@ from typing import Optional
 from repro.serve.frontend import protocol as P
 from repro.serve.frontend.driver import (EngineDriver, FrontendRequest,
                                          RequestError)
-from repro.serve.metrics import MetricsRegistry, engine_counters
+from repro.serve.metrics import PHASES, MetricsRegistry, engine_counters
 from repro.serve.scheduler import EncoderRequest
 
 
@@ -142,6 +142,28 @@ class HTTPFrontend:
             reg.gauge("samp_runtime_executables",
                       "distinct compiled executables in the runtime cache",
                       labels, fn=sample("executables"))
+            reg.counter("samp_queue_wait_seconds_total",
+                        "seconds requests spent queued before their "
+                        "micro-batch flushed or their decode slot was "
+                        "admitted", labels, fn=sample("queue_wait_s"))
+            reg.counter("samp_queue_waited_total",
+                        "flushes and admissions the queue-wait seconds "
+                        "cover", labels, fn=sample("queue_waited"))
+            short = "enc" if name == "encoder" else "dec"
+            for phase in PHASES[short]:
+                span = f"samp.{short}.{phase}"
+
+                def table(key, e=engine, span=span):
+                    return lambda: float(engine_counters(e)[key].get(span,
+                                                                     0))
+
+                reg.counter("samp_phase_seconds_total",
+                            "host seconds inside each serving phase (the "
+                            "samp.<engine>.<phase> profiler spans)",
+                            {**labels, "phase": phase}, fn=table("phase_s"))
+                reg.counter("samp_phase_calls_total",
+                            "calls of each serving phase",
+                            {**labels, "phase": phase}, fn=table("phase_n"))
             # adaptive-routing families — always exported (CORE_METRICS):
             # an unrouted engine books every request under cluster "0" and
             # reports one active plan

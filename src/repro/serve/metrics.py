@@ -4,10 +4,16 @@ text-format registry for the HTTP front-end's ``/metrics`` endpoint.
 Two layers:
 
 * :func:`engine_counters` — the ONE place the scheduler/engine numbers
-  (queue depth, batch occupancy, completed/evicted, runtime retraces) are
-  read. Both ``ServeEngine.stats`` / ``EncoderServeEngine.stats`` and the
+  (queue depth, batch occupancy, completed/evicted, queue wait, runtime
+  retraces, host seconds per serving phase) are read. Both
+  ``ServeEngine.stats`` / ``EncoderServeEngine.stats`` and the
   ``/metrics`` endpoint go through it, so a dashboard and a ``stats()``
   call can never disagree about what the engine is doing.
+
+* :class:`Phases` — the serving hot path's layer boundaries: each phase is
+  a ``jax.profiler`` span named ``samp.<engine>.<phase>`` (on the
+  profiler's clock, beside the device's operations) and a cumulative
+  (seconds, calls) entry that is always on.
 
 * :class:`MetricsRegistry` with :class:`Counter` / :class:`Gauge` /
   :class:`Histogram` — a minimal Prometheus exposition-format (0.0.4)
@@ -16,15 +22,19 @@ Two layers:
   reservoir of recent samples so p50/p95/p99 can be exported next to the
   cumulative buckets.
 
-No external dependency: the exporter is ~100 lines of text formatting,
-which is the point — the serving stack stays stdlib-only.
+The exporter has no external dependency: it is ~100 lines of text
+formatting. The phase spans are ``jax.profiler`` annotations, which cost
+under a microsecond each while no profiler runs.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from collections import deque
 from typing import Callable, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 # Request-latency bucket upper bounds (seconds). Shared by the /metrics
 # histogram and the benchmark artifacts (BENCH_serve.json), so client- and
@@ -50,7 +60,61 @@ CORE_METRICS = (
     "samp_kv_pages_in_use",
     "samp_cluster_requests_total",
     "samp_active_plans",
+    "samp_phase_seconds_total",
+    "samp_phase_calls_total",
+    "samp_queue_wait_seconds_total",
+    "samp_queue_waited_total",
 )
+
+#: the span names of each engine's phases, ``samp.<engine>.<phase>``:
+#: ``step``/``tick`` is the whole engine step, the others lie inside it
+PHASES = {
+    "enc": ("step", "flush", "assemble", "pad", "dispatch", "fetch",
+            "predict"),
+    "dec": ("tick", "admit", "drain", "pages", "assemble", "dispatch",
+            "fetch", "sample"),
+}
+
+
+class Phases:
+    """Cumulative host seconds and calls per serving phase, keyed by span
+    name. ``with phases("samp.dec.fetch"):`` opens a
+    ``jax.profiler.TraceAnnotation`` of that name (``args`` become the
+    span's arguments, e.g. ``step=``) and adds the block's
+    ``time.perf_counter`` seconds and one call to the table. Phases nest:
+    a step's span holds its phases' spans on the same host thread."""
+
+    def __init__(self):
+        self.seconds: dict[str, float] = {}
+        self.calls: dict[str, int] = {}
+
+    def __call__(self, name: str, **args) -> "_Phase":
+        return _Phase(self, name, args)
+
+    def snapshot(self) -> dict:
+        """Copies of the table: ``phase_s`` and ``phase_n`` by span name."""
+        return {"phase_s": dict(self.seconds), "phase_n": dict(self.calls)}
+
+
+class _Phase:
+    # a class rather than a contextlib.contextmanager: about 1 µs less per
+    # span, and every serving step opens several
+    __slots__ = ("table", "name", "span", "t0")
+
+    def __init__(self, table: Phases, name: str, args: dict):
+        self.table, self.name = table, name
+        self.span = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> None:
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self.t0
+        self.span.__exit__(*exc)
+        t, name = self.table, self.name
+        t.seconds[name] = t.seconds.get(name, 0.0) + dt
+        t.calls[name] = t.calls.get(name, 0) + 1
 
 
 def engine_counters(engine) -> dict:
@@ -58,10 +122,15 @@ def engine_counters(engine) -> dict:
     encoder): ``queue_depth`` (requests admitted but not yet running),
     ``occupancy`` (busy decode slots / mean encoder micro-batch fill),
     ``capacity`` (slot count / flush size), ``completed``, ``evicted``
-    (cancelled or deadline-evicted by the scheduler), plus the runtime's
-    ``retraces`` / ``executables`` compile census."""
+    (cancelled or deadline-evicted by the scheduler), ``queue_wait_s`` /
+    ``queue_waited`` (seconds queued before a flush or an admission, and
+    how many requests that covers), plus the runtime's ``retraces`` /
+    ``executables`` compile census and its ``phase_s`` / ``phase_n``
+    table (:class:`Phases`). Every value is a copy, never a live
+    container."""
     rt = engine.runtime.stats
-    base = {"retraces": rt["traces"], "executables": rt["executables"]}
+    base = {"retraces": rt["traces"], "executables": rt["executables"],
+            "phase_s": rt["phase_s"], "phase_n": rt["phase_n"]}
     sched = getattr(engine, "sched", None)
     if sched is not None:                               # decode engine
         return {"queue_depth": len(sched.queue),
@@ -70,14 +139,18 @@ def engine_counters(engine) -> dict:
                 "completed": engine._stats["retired"],
                 "evicted": sched.evicted,
                 "kv_cache_bytes": engine.kv_cache_bytes,
-                "kv_pages_in_use": engine.kv_pages_in_use, **base}
+                "kv_pages_in_use": engine.kv_pages_in_use,
+                "queue_wait_s": sched.queue_wait_s,
+                "queue_waited": sched.queue_waited, **base}
     batcher = engine.batcher                            # encoder engine
     return {"queue_depth": len(batcher),
             "occupancy": (engine._stats["batched_rows"]
                           / max(engine._stats["batches"], 1)),
             "capacity": batcher.max_batch,
             "completed": engine._stats["retired"],
-            "evicted": batcher.evicted, **base}
+            "evicted": batcher.evicted,
+            "queue_wait_s": batcher.queue_wait_s,
+            "queue_waited": batcher.queue_waited, **base}
 
 
 def latency_summary(latencies: Sequence[float], *,

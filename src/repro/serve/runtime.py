@@ -29,7 +29,10 @@ quantized weights of the same structure reuses the compiled executables.
 The ``stats`` counters make the caching auditable: ``traces`` increments
 inside the traced function body (a Python side effect that only runs when
 XLA actually re-traces), so a serving log can *prove* "≤ 1 compile per
-(plan, scheme, bucket)" rather than assume it.
+(plan, scheme, bucket)" rather than assume it. The runtime also holds the
+serving path's :class:`~repro.serve.metrics.Phases` table: its own
+``samp.enc.pad`` / ``dispatch`` / ``fetch`` and ``samp.dec.dispatch``
+spans, and the engines' phases around them.
 
 MoE configs are the one exception to bucketing: expert capacity is derived
 from the token count, so padding would change routing for real rows. They
@@ -70,6 +73,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import transformer as T
+from repro.serve.metrics import Phases
 
 HeadFn = Callable[[dict, jax.Array], jax.Array]     # (params, hidden)->logits
 
@@ -173,8 +177,8 @@ class Runtime:
         # argument shapes of the call that built each executable, so
         # :meth:`executables` can hand back the compiled programs
         self._arg_shapes: dict[tuple, tuple] = {}
-        self._stats = {"calls": 0, "traces": 0,
-                       "real_tokens": 0, "padded_tokens": 0}
+        self._stats = {"traces": 0, "real_tokens": 0, "padded_tokens": 0}
+        self.phases = Phases()
 
     def share(self, plan, *, scheme: Optional[T.QuantScheme] = None,
               precision=None, backend=None, mesh="inherit",
@@ -202,6 +206,7 @@ class Runtime:
         rt._exe = self._exe
         rt._arg_shapes = self._arg_shapes
         rt._stats = self._stats
+        rt.phases = self.phases
         return rt
 
     # -- cache plumbing ------------------------------------------------------
@@ -268,13 +273,16 @@ class Runtime:
     @property
     def stats(self) -> dict:
         """Counters + executable census. ``traces`` counts actual XLA traces
-        (incremented inside the traced body); ``executables`` the distinct
-        (plan, kind, bucket) entries. Keys are
-        ("encode", plan_key, Bb, Sb, ...) / ("decode", plan_key, B, ...)."""
+        (incremented inside the traced body); ``real_tokens`` /
+        ``padded_tokens`` the encode calls' token slots; ``executables``
+        the distinct (plan, kind, bucket) entries. Keys are
+        ("encode", plan_key, Bb, Sb, ...) / ("decode", plan_key, B, ...).
+        ``phase_s`` / ``phase_n`` copy the :class:`Phases` table."""
         return dict(self._stats, executables=len(self._exe),
                     buckets=sorted({(k[0],) + (k[2:4] if k[0] == "encode"
                                                else k[2:3])
-                                    for k in self._exe}))
+                                    for k in self._exe}),
+                    **self.phases.snapshot())
 
     # -- encoder / full-sequence path ---------------------------------------
     def _build_encode(self):
@@ -338,46 +346,49 @@ class Runtime:
         runs the cached executable, and slices the result back to the true
         batch (and true length for token-level heads).
         """
-        arrs = {k: np.asarray(v) for k, v in inputs.items()}
-        lead = arrs.get("tokens", arrs.get("frames"))
-        B, S = lead.shape[0], lead.shape[1]
-        if lengths is None:
-            lengths = np.full((B,), S, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        seq_bucketed = self.bucketed and "tokens" in arrs
-        Bb = bucket_size(B, self.min_batch) if self.bucketed else B
-        if self.bucketed and Bb % self._dp:
-            # meshed serving: the compiled batch must split evenly over the
-            # data axis, so buckets round up to dp multiples (a non-power-
-            # of-two dp size yields non-power-of-two buckets, still cached)
-            Bb = -(-Bb // self._dp) * self._dp
-        Sb = (bucket_size(S, self.min_len, self.max_len) if seq_bucketed
-              else S)
-        padded = {}
-        for k, v in arrs.items():
-            pad = [(0, Bb - B)] + [(0, 0)] * (v.ndim - 1)
-            if k in ("tokens", "segments"):
-                pad[1] = (0, Sb - v.shape[1])
-            padded[k] = np.pad(v, pad)
-        full_len = np.zeros((Bb,), np.int32)
-        full_len[:B] = lengths
-        # input structure (which arrays, their dtypes) and the params
-        # structure (float vs quantized leaves) are part of the compiled
-        # signature: distinct signatures get distinct cache entries
-        fn_key = ("encode", self._plan_key, Bb, Sb, _tree_sig(padded),
-                  _tree_sig(params))
-        fn = self._get(fn_key, self._build_encode,
-                       shardings=None if self.rules is None else
-                       (lambda: self._encode_shardings(params, padded,
-                                                       full_len)))
-        args = (params, {k: jnp.asarray(v) for k, v in padded.items()},
-                jnp.asarray(full_len))
-        self._note_args(fn_key, args)
-        out = fn(*args)
-        self._stats["calls"] += 1
+        with self.phases("samp.enc.pad"):
+            arrs = {k: np.asarray(v) for k, v in inputs.items()}
+            lead = arrs.get("tokens", arrs.get("frames"))
+            B, S = lead.shape[0], lead.shape[1]
+            if lengths is None:
+                lengths = np.full((B,), S, np.int32)
+            lengths = np.asarray(lengths, np.int32)
+            seq_bucketed = self.bucketed and "tokens" in arrs
+            Bb = bucket_size(B, self.min_batch) if self.bucketed else B
+            if self.bucketed and Bb % self._dp:
+                # meshed serving: the compiled batch must split evenly over
+                # the data axis, so buckets round up to dp multiples (a non-
+                # power-of-two dp size yields non-power-of-two buckets,
+                # still cached)
+                Bb = -(-Bb // self._dp) * self._dp
+            Sb = (bucket_size(S, self.min_len, self.max_len) if seq_bucketed
+                  else S)
+            padded = {}
+            for k, v in arrs.items():
+                pad = [(0, Bb - B)] + [(0, 0)] * (v.ndim - 1)
+                if k in ("tokens", "segments"):
+                    pad[1] = (0, Sb - v.shape[1])
+                padded[k] = np.pad(v, pad)
+            full_len = np.zeros((Bb,), np.int32)
+            full_len[:B] = lengths
+            # input structure (which arrays, their dtypes) and the params
+            # structure (float vs quantized leaves) are part of the compiled
+            # signature: distinct signatures get distinct cache entries
+            fn_key = ("encode", self._plan_key, Bb, Sb, _tree_sig(padded),
+                      _tree_sig(params))
+            fn = self._get(fn_key, self._build_encode,
+                           shardings=None if self.rules is None else
+                           (lambda: self._encode_shardings(params, padded,
+                                                           full_len)))
+            args = (params, {k: jnp.asarray(v) for k, v in padded.items()},
+                    jnp.asarray(full_len))
+            self._note_args(fn_key, args)
+        with self.phases("samp.enc.dispatch"):
+            out = fn(*args)
         self._stats["real_tokens"] += int(lengths.sum())
         self._stats["padded_tokens"] += Bb * Sb - int(lengths.sum())
-        out = np.asarray(jax.device_get(out))
+        with self.phases("samp.enc.fetch"):
+            out = np.asarray(jax.device_get(out))
         out = out[:B]
         if self.token_level and out.ndim >= 2:
             P = (arrs["prefix_embeds"].shape[1]
@@ -468,13 +479,13 @@ class Runtime:
                        (lambda: self._decode_shardings(params, caches)))
 
         def step(params, caches, tokens, pos, active, pages=None):
-            self._stats["calls"] += 1
-            args = (params, caches, jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(active),
-                    None if pages is None else jnp.asarray(pages))
-            if key not in self._arg_shapes:
-                self._note_args(key, args)
-            return fn(*args)
+            with self.phases("samp.dec.dispatch"):
+                args = (params, caches, jnp.asarray(tokens),
+                        jnp.asarray(pos), jnp.asarray(active),
+                        None if pages is None else jnp.asarray(pages))
+                if key not in self._arg_shapes:
+                    self._note_args(key, args)
+                return fn(*args)
         return step
 
     @staticmethod

@@ -13,6 +13,12 @@ Two admission disciplines over one queue abstraction:
   ``max_batch`` or its oldest request has waited ``max_wait`` seconds
   (latency bound), or on demand (drain). Requests of similar length batch
   together so padding waste stays bounded by the bucket geometry.
+
+Both count the time requests spend queued: ``queue_wait_s`` sums, over
+every flush or admission, the seconds since the request was queued
+(``arrival``, on ``time.monotonic`` unless the caller passes ``now``), and
+``queue_waited`` counts them; each request also keeps its own sum in
+``queue_wait``.
 """
 from __future__ import annotations
 
@@ -42,8 +48,11 @@ class EncoderRequest:
     # with their own cluster, and the engine picks the cluster's plan
     traffic_class: Optional[str] = None
     cluster: int = 0
-    # engine-filled:
+    # engine-filled: queued at ``arrival``, flushed after ``queue_wait``
+    # seconds by engine step ``step``
     arrival: Optional[float] = None
+    queue_wait: float = 0.0
+    step: Optional[int] = None
     logits: Optional[np.ndarray] = None
     prediction: Optional[np.ndarray] = None
     done: bool = False
@@ -153,9 +162,29 @@ class SlotScheduler:
         # slots must share one precision plan. Requests of other clusters
         # wait (FIFO among themselves) until the batch drains.
         self.cluster_pure = cluster_pure
+        self.queue_wait_s = 0.0
+        self.queue_waited = 0
 
-    def submit(self, req) -> None:
+    def submit(self, req, now: Optional[float] = None) -> None:
+        req.arrival = time.monotonic() if now is None else now
         self.queue.append(req)
+
+    def preempt(self, s: int, now: Optional[float] = None):
+        """Free slot ``s`` and put its request back at the queue's head,
+        queued anew from ``now``; returns the request."""
+        req = self.active[s]
+        self.release(s)
+        req.arrival = time.monotonic() if now is None else now
+        self.queue.appendleft(req)
+        return req
+
+    def _occupy(self, s: int, req, now: float) -> None:
+        self.active[s] = req
+        self.cursor[s] = 0
+        wait = now - req.arrival
+        req.queue_wait += wait
+        self.queue_wait_s += wait
+        self.queue_waited += 1
 
     @property
     def active_cluster(self) -> Optional[int]:
@@ -165,18 +194,20 @@ class SlotScheduler:
                 return getattr(a, "cluster", 0)
         return None
 
-    def admit(self) -> list[int]:
+    def admit(self, now: Optional[float] = None) -> list[int]:
         """Fill free slots FIFO; returns the newly-occupied slot ids (their
         per-slot state must be reset by the caller). In ``cluster_pure``
         mode only requests matching the live batch's cluster (or, on an
         empty batch, the queue head's cluster) are admitted; skipped
         requests keep their queue order."""
         newly = []
+        if not self.queue:
+            return newly
+        now = time.monotonic() if now is None else now
         if not self.cluster_pure:
             for s in range(self.slots):
                 if self.active[s] is None and self.queue:
-                    self.active[s] = self.queue.popleft()
-                    self.cursor[s] = 0
+                    self._occupy(s, self.queue.popleft(), now)
                     newly.append(s)
             return newly
         free = [s for s in range(self.slots) if self.active[s] is None]
@@ -190,8 +221,7 @@ class SlotScheduler:
             req = self.queue.popleft()
             if getattr(req, "cluster", 0) == current:
                 s = free.pop(0)
-                self.active[s] = req
-                self.cursor[s] = 0
+                self._occupy(s, req, now)
                 newly.append(s)
             else:
                 skipped.append(req)
@@ -257,6 +287,8 @@ class MicroBatcher:
         self.max_len = max_len
         self._queues: dict[tuple[int, int], deque] = {}
         self.evicted = 0        # cancellations + deadline evictions
+        self.queue_wait_s = 0.0
+        self.queue_waited = 0
 
     def bucket(self, length: int) -> int:
         return bucket_size(length, self.min_len, self.max_len)
@@ -282,9 +314,13 @@ class MicroBatcher:
             q = self._queues[key]
             while q and (force or len(q) >= self.max_batch
                          or now - q[0].arrival >= self.max_wait):
-                out.append((key[0], [q.popleft()
-                                     for _ in range(min(self.max_batch,
-                                                        len(q)))]))
+                batch = [q.popleft() for _ in range(min(self.max_batch,
+                                                        len(q)))]
+                for req in batch:
+                    req.queue_wait = now - req.arrival
+                    self.queue_wait_s += req.queue_wait
+                self.queue_waited += len(batch)
+                out.append((key[0], batch))
         return out
 
     def depth_by_cluster(self) -> dict[int, int]:
