@@ -101,6 +101,18 @@ def kernel_census(name: str, runtime, *, need_kernels: bool) -> None:
                          f"no Pallas kernel")
 
 
+def pool_census(name: str, runtime, *, in_place: bool) -> None:
+    """Print the relayout copies of a page-pool leaf in every decode
+    executable ``runtime`` built; ``in_place`` (the fused backend on int8
+    pages) fails the phase on any."""
+    for key, n in runtime.pool_copies().items():
+        log(f"[{name}] executable decode{tuple(key[2:3])}: {n} copies of a "
+            f"page-pool leaf")
+        if in_place:
+            check(n == 0, f"{name}: the decode step relayouts its page "
+                          f"pool {n} times")
+
+
 def logit_gap(name: str, got, want, bound=None) -> float:
     """max |got - want| over max |want|; fails above ``bound`` (None:
     report only)."""
@@ -383,6 +395,8 @@ def decode_phase(cfg, seed: int) -> None:
     log(f"[decode] {fused[0].stats['ticks']} ticks each, {secs:.1f}s with "
         f"compiles")
     kernel_census("decode", fused[0].runtime, need_kernels=True)
+    pool_census("decode", fused[0].runtime, in_place=True)
+    pool_census("decode reference", ref[0].runtime, in_place=False)
     logit_gap("decode first-step fused vs reference", f_first, r_first,
               bound)
     log(f"[decode] float model vs int8 reference, {frows} slot rows with "

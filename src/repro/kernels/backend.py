@@ -159,6 +159,30 @@ class ComputeBackend:
         None to use the reference gather-dequant + attention_core path."""
         return None
 
+    def pages_in_place(self, kv_cache) -> bool:
+        """Whether this backend writes a paged cache's pool in place
+        (:meth:`write_pages`) on a decode step. Its pool leaves
+        (``layers.POOL_KEYS``) then reach the layer whole, stacked over
+        the scan group's layers, with the layer index under
+        ``layers.POOL_LAYER``. The reference backend scatters in XLA:
+        False."""
+        return False
+
+    def page_lanes(self) -> int:
+        """The lane width the minor dim of int8 page leaves is padded to
+        (``layers.page_leaf_shape``), so that their compact device layout
+        is the row-major one this backend's kernels read: 1, unpadded,
+        where no kernel reads them."""
+        return 1
+
+    def write_pages(self, kv_cache, rows: dict, page, row) -> dict:
+        """Write one new row per slot into the pool leaves ``rows`` names
+        (``pages_k`` -> (B, Hkv, hd) int8, ``pages_ks`` -> (B, Hkv) float
+        scales, ...) at ``(page[b], row[b])``, ``page`` -1 writing nothing;
+        returns the updated leaves. Called only where
+        :meth:`pages_in_place` holds."""
+        raise NotImplementedError
+
     # -- mesh binding --------------------------------------------------------
     def with_mesh(self, mesh) -> "ComputeBackend":
         """Bind this backend to a mesh the compiler partitions the step
@@ -400,9 +424,9 @@ class FusedBackend(ComputeBackend):
             ks = sc["k"].astype(jnp.float32).reshape(-1)
             vs = sc["v"].astype(jnp.float32).reshape(-1)
         from repro.kernels import ops
-        from repro.models.layers import PAGE_HEAD_AXIS
+        from repro.models.layers import POOL_LAYER
         B, S, Hq, hd = q.shape
-        Hkv = k.shape[PAGE_HEAD_AXIS]
+        Hkv = k.shape[-3]                    # (..., Hkv, ps, hd)
         if S != 1 or Hq % Hkv != 0:
             return None
         pos = jnp.asarray(positions, jnp.int32)
@@ -416,8 +440,31 @@ class FusedBackend(ComputeBackend):
             k_scale=ks, v_scale=vs, per_head=not per_token,
             scale=float(scale),
             softcap=float(softcap) if softcap is not None else None,
-            p_scale=p_scale)
+            p_scale=p_scale, layer=kv_cache.get(POOL_LAYER))
         return out.reshape(B, 1, Hq, hd)
+
+    # -- in-place page writes ------------------------------------------------
+    def pages_in_place(self, kv_cache) -> bool:
+        k = kv_cache.get("pages_k") if isinstance(kv_cache, dict) else None
+        return self._enabled and k is not None and k.dtype == jnp.int8
+
+    def page_lanes(self) -> int:
+        from repro.kernels import ops
+        return ops.page_lanes() if self._enabled else 1
+
+    def write_pages(self, kv_cache, rows: dict, page, row) -> dict:
+        from repro.kernels import ops
+        from repro.models.layers import POOL_LAYER
+        names = sorted(rows)
+        layer = kv_cache.get(POOL_LAYER)
+        pools = tuple(kv_cache[n] for n in names)
+        if layer is None:                    # one layer's pool: a stack of 1
+            pools = tuple(p[None] for p in pools)
+        out = ops.page_write(pools, tuple(rows[n] for n in names),
+                             0 if layer is None else layer, page, row)
+        if layer is None:
+            out = tuple(o[0] for o in out)
+        return dict(zip(names, out))
 
 
 class AutoBackend(FusedBackend):

@@ -29,6 +29,7 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import dynamic_quant as _dq
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_embed as _fe
+from repro.kernels import page_write as _pw
 from repro.kernels import quant_linear as _ql
 
 
@@ -36,6 +37,18 @@ def _interpret() -> bool:
     """Interpret mode everywhere but a TPU, where Mosaic compiles the
     kernels. Called at trace time, never at import."""
     return jax.default_backend() != "tpu"
+
+
+#: a TPU vector register's lanes: the minor dim of a row-major tile
+LANES = 128
+
+
+def page_lanes() -> int:
+    """The lane width a KV page leaf's minor dim is padded to for the
+    kernels that read it: whole TPU lanes where Mosaic compiles them (so
+    XLA's compact layout of the pool is the row-major one they read), 1 in
+    interpret mode."""
+    return 1 if _interpret() else LANES
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -177,16 +190,29 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                      k_scale, v_scale, per_head: bool,
                      scale: Optional[float] = None,
                      softcap: Optional[float] = None,
-                     p_scale=None):
+                     p_scale=None, layer=None):
     """Paged int8-KV decode attention (single query token per slot).
 
     ``page_table``/``lengths`` are operands — slots churn every step and
     must not retrace; the kv scheme (``per_head``) and page geometry are
     static and baked into the executable key by the serving runtime.
     ``p_scale`` (the plan's ``softmax='uint8'`` scheme) is a scalar
-    operand; its presence selects the two-pass quantized-softmax grid."""
+    operand; its presence selects the two-pass quantized-softmax grid.
+    ``layer`` (an operand) picks the layer of a pool stacked over a scan
+    group's layers."""
     return _da.decode_attention(q, k_pages, v_pages, page_table, lengths,
                                 k_scale=k_scale, v_scale=v_scale,
                                 per_head=per_head, scale=scale,
                                 softcap=softcap, p_scale=p_scale,
-                                interpret=_interpret())
+                                layer=layer, interpret=_interpret())
+
+
+@jax.jit
+def page_write(pools, rows, layer, page, row):
+    """Write each slot's new row into its page of layer ``layer`` of the
+    stacked int8 pools (K/V pages and their per-token scale pages), in
+    place; ``page`` -1 drops a slot's write. All operands are arrays: the
+    slots, their pages and the layer change every call without
+    retracing."""
+    return _pw.page_write(tuple(pools), tuple(rows), layer, page, row,
+                          interpret=_interpret())

@@ -519,6 +519,23 @@ def _cache_write(kv_cache: dict, new: dict, positions: jax.Array,
 # block. Slots stop paying max-length memory: pages are allocated as
 # generation grows and returned to the pool on completion/cancel. MLA
 # caches page the latent instead (pages_ckv / pages_krope).
+#
+# The int8 pool has one layout: the row-major tiled layout the Pallas
+# kernels read. XLA gives an array the compact device layout of its shape,
+# and for a page with a minor dim narrower than the TPU's 128 lanes (a
+# 64-wide head, 16 per-token scales) that puts the page axis minor-most,
+# which no kernel reads; so where the kernels compile for a TPU, the int8
+# pages' minor dim (head_dim for K/V, the page's tokens for the scales) is
+# padded to whole lanes (``lanes``, ``page_leaf_shape``), and the compact
+# layout is the row-major one. Where a backend writes the pool in place
+# (``pages_in_place``: the fused backend on int8 K/V pages), a decode step
+# writes each tick's rows with the Pallas page-write kernel and reads them
+# with the decode kernel; both take the pool leaves (POOL_KEYS) whole,
+# stacked over the scan group's layers, (L, NP, Hkv, ps, ...), with the
+# layer index (POOL_LAYER in the cache dict a layer sees) as an operand,
+# and the stacks ride the layer scan as a carry, so no pool is sliced per
+# layer, scattered or relaid out. Elsewhere (the reference backend, float
+# and latent pages, prefill) the XLA scatter below writes per-layer leaves.
 
 
 #: page leaves stored head-major, (NP, Hkv, ps, ...); the latent (MLA)
@@ -526,13 +543,28 @@ def _cache_write(kv_cache: dict, new: dict, positions: jax.Array,
 HEAD_MAJOR_PAGES = ("k", "v")
 #: the kv-head axis of a head-major page leaf
 PAGE_HEAD_AXIS = 1
+#: the pool leaves a decode step takes whole, stacked over the scan
+#: group's layers, where a backend writes the pool in place
+POOL_KEYS = ("pages_k", "pages_v", "pages_ks", "pages_vs", "pages_pos")
+#: in a layer's paged cache dict: this layer's index into POOL_KEYS leaves
+#: that arrive stacked over the scan group's layers
+POOL_LAYER = "pool_layer"
 
 
 def page_leaf_shape(num_pages: int, page_size: int, heads: int,
-                    *tail: int) -> tuple:
+                    *tail: int, lanes: int = 1) -> tuple:
     """Allocation shape of a head-major page leaf: K/V pages with
-    ``tail=(head_dim,)``, their per-token scales with no tail."""
-    return (num_pages, heads, page_size) + tail
+    ``tail=(head_dim,)``, their per-token scales with no tail, the minor
+    dim padded to a multiple of ``lanes`` (see the paged-KV section)."""
+    shape = (num_pages, heads, page_size) + tail
+    return shape[:-1] + (-(-shape[-1] // lanes) * lanes,)
+
+
+def _pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """``x`` with its minor dim zero-padded to ``width``."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                                      + [(0, pad)])
 
 
 def _page_flat_index(pages: jax.Array, positions: jax.Array,
@@ -551,9 +583,18 @@ def _page_flat_index(pages: jax.Array, positions: jax.Array,
     return jnp.where(ok, pt * page_size + within, -1)
 
 
+def _pool_leaf(kv_cache: dict, name: str) -> jax.Array:
+    """This layer's (NP, ...) view of a pool leaf that may arrive stacked
+    over the scan group's layers (POOL_LAYER)."""
+    leaf = kv_cache[name]
+    layer = kv_cache.get(POOL_LAYER)
+    return leaf if layer is None or name not in POOL_KEYS else leaf[layer]
+
+
 def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
                        active: Optional[jax.Array], pages: jax.Array,
-                       static_scales: Optional[dict] = None) -> dict:
+                       static_scales: Optional[dict] = None,
+                       backend=None) -> dict:
     """Scatter new K/V(-like) tokens into their slots' pages.
 
     ``new`` maps short key ("k"/"v"/"ckv"/...) -> (B, S, ...) tensor; the
@@ -562,9 +603,13 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
     per-token dynamic scales computed here; int8 without the sibling uses
     the calibrated per-head scale from ``static_scales``; float pages store
     the cast value. Out-of-range / inactive / unallocated writes are
-    dropped (`mode='drop'` keeps -1 indices from wrapping)."""
-    ps = kv_cache["pages_pos"].shape[1]
-    npages = kv_cache["pages_pos"].shape[0]
+    dropped (`mode='drop'` keeps -1 indices from wrapping).
+
+    A decode step (one token per slot) on a ``backend`` that updates the
+    pool in place hands the quantized K/V rows and their scales to
+    ``backend.write_pages`` (the Pallas page-write kernel) instead of the
+    scatter; the quantization is the same either way."""
+    npages, ps = kv_cache["pages_pos"].shape[-2:]
     B = kv_cache["pos"].shape[0]
     if positions.ndim == 1:                              # uniform prefill
         pos2 = jnp.broadcast_to(positions[None, :].astype(jnp.int32),
@@ -579,6 +624,9 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
     flat = jnp.where(flat < 0, npages * ps, flat)
     # head-major leaves (K/V pages and their scales) scatter by (page, row)
     page_of, row_of = flat // ps, flat % ps
+    in_place = (S == 1 and backend is not None
+                and backend.pages_in_place(kv_cache))
+    writes = {}                          # pool leaf -> (B, H, ...) new rows
     out = dict(kv_cache)
     for key, val in new.items():
         leaf = kv_cache["pages_" + key]
@@ -588,8 +636,11 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
                 amax = jnp.max(jnp.abs(val.astype(jnp.float32)), axis=-1)
                 scl = compute_scale_symmetric(amax)      # (B, S, H)
                 rows = quantize(val, scl[..., None])
-                out[skey] = kv_cache[skey].at[page_of, :, row_of].set(
-                    scl.reshape(-1, scl.shape[-1]), mode="drop")
+                if in_place:
+                    writes[skey] = scl[:, 0]
+                else:
+                    out[skey] = kv_cache[skey].at[page_of, :, row_of].set(
+                        scl.reshape(-1, scl.shape[-1]), mode="drop")
             else:                                        # per-head static
                 s = (static_scales or {}).get(key)
                 if s is None:
@@ -602,6 +653,10 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
         else:
             rows = val.astype(leaf.dtype)
         if key in HEAD_MAJOR_PAGES:
+            rows = _pad_lanes(rows, leaf.shape[-1])
+        if in_place and key in HEAD_MAJOR_PAGES:
+            writes["pages_" + key] = rows[:, 0]
+        elif key in HEAD_MAJOR_PAGES:
             out["pages_" + key] = leaf.at[page_of, :, row_of].set(
                 rows.reshape((-1,) + rows.shape[2:]), mode="drop")
         else:
@@ -609,9 +664,17 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
                 (npages * ps,) + leaf.shape[2:]).at[flat].set(
                 rows.reshape((-1,) + leaf.shape[2:]),
                 mode="drop").reshape(leaf.shape)
-    out["pages_pos"] = kv_cache["pages_pos"].reshape(-1) \
-        .at[flat].set(pos2.reshape(-1), mode="drop") \
-        .reshape(kv_cache["pages_pos"].shape)
+    if writes:
+        out.update(backend.write_pages(
+            kv_cache, writes, jnp.where(page_of < npages, page_of, -1),
+            row_of))
+    # by (page, row), not into a flattened pool: XLA keeps the pages_pos
+    # layout it chose instead of relayouting it for a flat reshape
+    at = (page_of, row_of)
+    if POOL_LAYER in kv_cache:
+        at = (kv_cache[POOL_LAYER],) + at
+    out["pages_pos"] = kv_cache["pages_pos"].at[at].set(pos2.reshape(-1),
+                                                        mode="drop")
     if positions.ndim == 1:
         out["pos"] = kv_cache["pos"] + S
     else:
@@ -621,28 +684,34 @@ def _paged_cache_write(kv_cache: dict, new: dict, positions: jax.Array,
 
 
 def _paged_cache_read(kv_cache: dict, pages: jax.Array, keys, dtype,
-                      static_scales: Optional[dict] = None):
+                      static_scales: Optional[dict] = None,
+                      head_dim: Optional[int] = None):
     """Gather + dequantize a slot-major view of the paged cache: each
-    requested key comes back (B, pages_per_slot * ps, ...), with k_pos
+    requested key comes back (B, pages_per_slot * ps, ...), cut to
+    ``head_dim`` where its pages are lane-padded, with k_pos
     (B, pages_per_slot * ps) carrying -1 for unallocated pages / unwritten
     entries (the reference XLA decode path; the fused backend's Pallas
     kernel consumes the pages + scales directly instead)."""
     pt = pages.astype(jnp.int32)
     safe = jnp.maximum(pt, 0)                            # gatherable
     B, pps = pt.shape
-    ps = kv_cache["pages_pos"].shape[1]
-    kpos = jnp.take(kv_cache["pages_pos"], safe, axis=0)  # (B, pps, ps)
+    ps = kv_cache["pages_pos"].shape[-1]
+    kpos = jnp.take(_pool_leaf(kv_cache, "pages_pos"), safe,
+                    axis=0)                              # (B, pps, ps)
     kpos = jnp.where(pt[:, :, None] >= 0, kpos, -1)
     outs = []
     for key in keys:
-        leaf = kv_cache["pages_" + key]
+        leaf = _pool_leaf(kv_cache, "pages_" + key)
         g = jnp.take(leaf, safe, axis=0)                 # (B, pps, ...)
         if key in HEAD_MAJOR_PAGES:                      # -> (B, pps, ps, H, d)
             g = g.swapaxes(2, 3)
+            if head_dim is not None:
+                g = g[..., :head_dim]
         if leaf.dtype == jnp.int8:
             skey = "pages_" + key + "s"
             if skey in kv_cache:
-                scl = jnp.take(kv_cache[skey], safe, axis=0).swapaxes(2, 3)
+                scl = jnp.take(_pool_leaf(kv_cache, skey)[..., :ps], safe,
+                               axis=0).swapaxes(2, 3)
                 g = g.astype(jnp.float32) * scl[..., None]
             else:
                 s = (static_scales or {})[key]
@@ -725,7 +794,8 @@ def attention_block(x: jax.Array, p: dict, cfg, *, positions: jax.Array,
             raise ValueError("paged kv_cache requires the page-table "
                              "operand (pages=)")
         new_cache = _paged_cache_write(kv_cache, {"k": k, "v": v},
-                                       positions, active, pages, static_sc)
+                                       positions, active, pages, static_sc,
+                                       backend=backend)
         if S == 1:
             if backend is not None and not quant.enabled:
                 o = backend.decode_attention(
@@ -736,7 +806,8 @@ def attention_block(x: jax.Array, p: dict, cfg, *, positions: jax.Array,
                              if quant.plan_scheme == "uint8" else None))
             if o is None:
                 (k, v), k_pos = _paged_cache_read(
-                    new_cache, pages, ("k", "v"), x.dtype, static_sc)
+                    new_cache, pages, ("k", "v"), x.dtype, static_sc,
+                    head_dim=cfg.head_dim)
         # prefill (S > 1): attend over in-sequence K/V, as in the dense path
     elif kv_cache is not None:
         new_cache = _cache_write(kv_cache, {"k": k, "v": v}, positions,

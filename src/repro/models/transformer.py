@@ -363,25 +363,52 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
                     _stack([ncs[s][j] for s in range(g.steps)])
                     for j in range(len(g.kinds))))
         else:
-            def body(carry, xs, g=g):
-                xc = carry
-                lps, lcs = xs
-                outs = []
-                for j, kind in enumerate(g.kinds):
-                    xc, nc = make_lf(kind, g.mode, None)(
-                        xc, lps[j], None if lcs is None else lcs[j])
-                    outs.append(nc)
-                return xc, (tuple(outs) if lcs is not None else None)
+            # a decode step's int8 pools that the backend writes in place
+            # ride the scan whole, as a carry, with the layer index (see
+            # layers.POOL_KEYS): sliced per layer as xs/ys, every layer's
+            # pool would be copied out and back on every step
+            pools = tuple(
+                {k: c[k] for k in L.POOL_KEYS if k in c}
+                if (c is not None and backend is not None
+                    and x.shape[1] == 1 and backend.pages_in_place(c))
+                else None
+                for c in (gcache or (None,) * len(g.kinds)))
+            carried = any(pl is not None for pl in pools)
+            rest = None if gcache is None else tuple(
+                c if pl is None else
+                {k: v for k, v in c.items() if k not in pl}
+                for c, pl in zip(gcache, pools))
 
-            if gcache is None:
-                # scan requires xs leaves with a leading dim; close over the
-                # absent cache.
-                x, _ = jax.lax.scan(
-                    lambda c, lps, g=g: body(c, (lps, None), g),
-                    x, gp["layers"])
+            def body(carry, xs, g=g, carried=carried, pools=pools):
+                xc, pls = carry if carried else (carry, pools)
+                lps, lcs, layer = xs if carried else xs + (None,)
+                outs, new_pls = [], []
+                for j, kind in enumerate(g.kinds):
+                    lc = None if lcs is None else lcs[j]
+                    if pls[j] is not None:
+                        lc = {**lc, **pls[j], L.POOL_LAYER: layer}
+                    xc, nc = make_lf(kind, g.mode, None)(xc, lps[j], lc)
+                    if pls[j] is not None:
+                        nc = {k: v for k, v in nc.items()
+                              if k != L.POOL_LAYER}
+                        new_pls.append({k: nc.pop(k) for k in pls[j]})
+                    else:
+                        new_pls.append(None)
+                    outs.append(nc)
+                ys = tuple(outs) if lcs is not None else None
+                return ((xc, tuple(new_pls)) if carried else xc), ys
+
+            if carried:
+                layer_ids = jnp.arange(g.steps, dtype=jnp.int32)
+                (x, pools), nc_stack = jax.lax.scan(
+                    body, (x, pools), (gp["layers"], rest, layer_ids))
             else:
-                x, nc_stack = jax.lax.scan(body, x, (gp["layers"], gcache))
-                new_caches.append(nc_stack)
+                # per-layer caches (or none: an empty xs subtree) as xs/ys
+                x, nc_stack = jax.lax.scan(body, x, (gp["layers"], rest))
+            if gcache is not None:
+                new_caches.append(tuple(
+                    c if pl is None else {**c, **pl}
+                    for c, pl in zip(nc_stack, pools)))
     return x, new_caches
 
 
@@ -522,7 +549,8 @@ def lm_loss(params, batch: dict, cfg: ArchConfig, plan, scheme=QuantScheme(),
 
 def _layer_cache(cfg: ArchConfig, kind: BlockKind, batch: int, max_len: int,
                  dtype, *, page_size: Optional[int] = None,
-                 num_pages: int = 0, kv_scheme: str = "float"):
+                 num_pages: int = 0, kv_scheme: str = "float",
+                 lanes: int = 1):
     if kind.body == "attn":
         W = min(cfg.sliding_window, max_len) if kind.local else max_len
         paged = page_size is not None and not kind.local
@@ -539,14 +567,18 @@ def _layer_cache(cfg: ArchConfig, kind: BlockKind, batch: int, max_len: int,
                                                  dtype),
                         "pages_pos": jnp.full((NP, ps), -1, jnp.int32),
                         "pos": jnp.zeros((batch,), jnp.int32)}
-            kv_dtype = jnp.int8 if kv_scheme.startswith("int8") else dtype
-            kv = L.page_leaf_shape(NP, ps, cfg.num_kv_heads, cfg.head_dim)
+            int8 = kv_scheme.startswith("int8")
+            kv_dtype = jnp.int8 if int8 else dtype
+            lanes = lanes if int8 else 1
+            kv = L.page_leaf_shape(NP, ps, cfg.num_kv_heads, cfg.head_dim,
+                                   lanes=lanes)
             d = {"pages_k": jnp.zeros(kv, kv_dtype),
                  "pages_v": jnp.zeros(kv, kv_dtype),
                  "pages_pos": jnp.full((NP, ps), -1, jnp.int32),
                  "pos": jnp.zeros((batch,), jnp.int32)}
             if kv_scheme == "int8_per_token":
-                scales = L.page_leaf_shape(NP, ps, cfg.num_kv_heads)
+                scales = L.page_leaf_shape(NP, ps, cfg.num_kv_heads,
+                                           lanes=lanes)
                 d["pages_ks"] = jnp.zeros(scales, jnp.float32)
                 d["pages_vs"] = jnp.zeros(scales, jnp.float32)
             return d
@@ -577,7 +609,8 @@ def init_caches(cfg: ArchConfig, plan: tuple[Group, ...],
                 batch: int, max_len: int, dtype=jnp.bfloat16, *,
                 page_size: Optional[int] = None,
                 num_pages: Optional[int] = None,
-                kv_schemes: Optional[Sequence[str]] = None):
+                kv_schemes: Optional[Sequence[str]] = None,
+                lanes: int = 1):
     """Decode-cache pytree mirroring the plan's group structure. Cache
     geometry is fully determined by (cfg, plan, batch, max_len) plus the
     paged-KV knobs — no parameters needed.
@@ -588,7 +621,9 @@ def init_caches(cfg: ArchConfig, plan: tuple[Group, ...],
     ``kv_schemes`` gives each layer's KV-cache scheme from the
     PrecisionPlan (``plan_obj.kv_schemes``), default all-float. Scan groups
     are homogeneous by construction (group_boundaries splits on full
-    LayerPlan equality, which includes ``kv_cache``)."""
+    LayerPlan equality, which includes ``kv_cache``). ``lanes`` pads the
+    minor dim of int8 pages to the lane width of the kernels that read
+    them (``ComputeBackend.page_lanes``)."""
     if page_size is not None and num_pages is None:
         num_pages = batch * pages_per_slot(max_len, page_size)
     caches = []
@@ -605,7 +640,7 @@ def init_caches(cfg: ArchConfig, plan: tuple[Group, ...],
         for kind in g.kinds:
             one = _layer_cache(cfg, kind, batch, max_len, dtype,
                                page_size=page_size, num_pages=num_pages or 0,
-                               kv_scheme=scheme)
+                               kv_scheme=scheme, lanes=lanes)
             period.append(jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(a, (g.steps,) + a.shape), one))
         caches.append(tuple(period))

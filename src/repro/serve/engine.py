@@ -136,8 +136,10 @@ class ServeEngine:
             # slot's own device
             self.pool = PagePool(num_pages, page_size, batch_slots, pps,
                                  shards=self.runtime.shards)
+            # int8 pools the backend's kernels read take its lane width
             cache_kw = dict(page_size=page_size, num_pages=num_pages,
-                            kv_schemes=schemes)
+                            kv_schemes=schemes,
+                            lanes=self.runtime.backend.page_lanes())
         elif kv_cache not in (None, "float"):
             raise ValueError("kv_cache quantization needs the paged layout; "
                              "pass page_size= as well")
@@ -205,7 +207,7 @@ class ServeEngine:
                 return jax.tree_util.tree_map_with_path(reset, caches, fresh)
             # donation: the old cache buffers are dead after the update,
             # so XLA updates in place instead of copying the whole tree
-            self._reset_fn = jax.jit(reset_tree, donate_argnums=(0,))
+            self._reset_fn = self.runtime.cache_update(reset_tree)
         self.caches = self._reset_fn(self.caches, self._fresh1,
                                      jnp.int32(s))
 
@@ -232,7 +234,7 @@ class ServeEngine:
                         return leaf.at[:, idx].set(-1, mode="drop")
                     return leaf
                 return jax.tree_util.tree_map_with_path(inval, caches)
-            self._inval_fn = jax.jit(inval_tree, donate_argnums=(0,))
+            self._inval_fn = self.runtime.cache_update(inval_tree)
         with self.runtime.phases("samp.dec.drain"):
             self.caches = self._inval_fn(self.caches, jnp.asarray(pad))
 

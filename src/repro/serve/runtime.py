@@ -61,9 +61,19 @@ Mesh with ``data``/``model`` axes) places every executable over that mesh:
   :class:`~repro.serve.scheduler.PagePool` hands a slot only pages of its
   own device). With a model axis above 1, GSPMD partitions the step and
   the fused backend (:meth:`ComputeBackend.with_mesh`) declines every op.
+
+**Decode caches in place.** The decode step donates its cache tree, so
+XLA updates the pools in place instead of writing a new tree every tick.
+It donates only a tree this runtime produced (a step's output, or a
+:meth:`cache_update` of one); any other tree — fresh from ``init_caches``,
+or one a caller of :meth:`decode` still holds — is first copied, so no
+caller is left holding donated buffers. :meth:`pool_copies` counts the
+relayout copies of a page pool left in each compiled decode step.
 """
 from __future__ import annotations
 
+import re
+import weakref
 from typing import Callable, Optional
 
 import jax
@@ -99,6 +109,29 @@ def bucket_size(n: int, floor: int = 1, cap: Optional[int] = None) -> int:
     if cap is not None and cap >= n:
         b = min(b, cap)
     return b
+
+
+#: a ``copy`` instruction and its result shape: ``= s8[24,1536,2,16,64]``
+_COPY = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(")
+
+
+def pool_copies(hlo_text: str, caches, shards: int = 1) -> int:
+    """The ``copy`` instructions of compiled HLO text whose result has the
+    shape of a page-pool leaf of ``caches`` (a ``pages_*`` leaf): one
+    layer's pool, or any stack of them, or one device's share of it when
+    the pool is split ``shards`` ways. A ``copy`` changes an array's
+    layout; the asynchronous ``copy-start`` that moves an array between
+    memory spaces unchanged is not one."""
+    pools = {(leaf.shape[1] // n,) + tuple(leaf.shape[2:])
+             for path, leaf in jax.tree_util.tree_leaves_with_path(caches)
+             if str(getattr(path[-1], "key", "")).startswith("pages_")
+             for n in {1, shards}}
+    n = 0
+    for m in _COPY.finditer(hlo_text):
+        dims = tuple(int(d) for d in m.group(1).split(",") if d)
+        n += any(dims[len(dims) - len(p):] == p
+                 for p in pools if len(dims) >= len(p))
+    return n
 
 
 class Runtime:
@@ -179,6 +212,9 @@ class Runtime:
         self._arg_shapes: dict[tuple, tuple] = {}
         self._stats = {"traces": 0, "real_tokens": 0, "padded_tokens": 0}
         self.phases = Phases()
+        # cache trees this runtime may donate: id of the tree's first leaf
+        # -> a weak reference to that leaf
+        self._owned: dict[int, weakref.ref] = {}
 
     def share(self, plan, *, scheme: Optional[T.QuantScheme] = None,
               precision=None, backend=None, mesh="inherit",
@@ -207,20 +243,23 @@ class Runtime:
         rt._arg_shapes = self._arg_shapes
         rt._stats = self._stats
         rt.phases = self.phases
+        rt._owned = self._owned
         return rt
 
     # -- cache plumbing ------------------------------------------------------
     def _get(self, key: tuple, build: Callable[[], Callable],
-             shardings: Optional[Callable[[], tuple]] = None) -> Callable:
+             shardings: Optional[Callable[[], tuple]] = None,
+             donate: tuple = ()) -> Callable:
         # ``shardings`` is a thunk so cache hits never pay the spec-tree
         # walk — it only runs when an executable is actually created
         fn = self._exe.get(key)
         if fn is None:
             if shardings is None:
-                fn = jax.jit(build())
+                fn = jax.jit(build(), donate_argnums=donate)
             else:
                 in_s, out_s = shardings()
-                fn = jax.jit(build(), in_shardings=in_s, out_shardings=out_s)
+                fn = jax.jit(build(), in_shardings=in_s, out_shardings=out_s,
+                             donate_argnums=donate)
             self._exe[key] = fn
         return fn
 
@@ -239,6 +278,16 @@ class Runtime:
         for key, fn in self._exe.items():
             if key in self._arg_shapes:
                 yield key, fn.lower(*self._arg_shapes[key]).compile()
+
+    def pool_copies(self) -> dict:
+        """Census of each compiled decode executable: ``{key: n}``, ``n``
+        the ``copy`` instructions whose result has the shape of a page
+        pool leaf (one layer's or the whole stack's). Each is a relayout
+        of a pool around a kernel or a scatter; in place there are none."""
+        return {key: pool_copies(compiled.as_text(),
+                                 self._arg_shapes[key][1], self.shards)
+                for key, compiled in self.executables()
+                if key[0] == "decode"}
 
     @property
     def _dp(self) -> int:
@@ -458,6 +507,52 @@ class Runtime:
         in_s = (r.params_sharding(params), caches_sh, None, None, None, None)
         return in_s, (None, caches_sh)
 
+    # -- cache ownership -----------------------------------------------------
+    def _owns(self, caches) -> bool:
+        leaf = jax.tree_util.tree_leaves(caches)[0]
+        ref = self._owned.get(id(leaf))
+        return ref is not None and ref() is leaf
+
+    def _own(self, caches) -> None:
+        leaf = jax.tree_util.tree_leaves(caches)[0]
+        key, owned = id(leaf), self._owned
+
+        def forget(ref):
+            if owned.get(key) is ref:
+                del owned[key]
+        owned[key] = weakref.ref(leaf, forget)
+
+    def cache_update(self, fn: Callable) -> Callable:
+        """jit ``fn(caches, *args) -> caches``, an update of a cache tree
+        that donates it; the result stays this runtime's to donate when
+        its input was."""
+        jitted = jax.jit(fn, donate_argnums=(0,))
+
+        def update(caches, *args):
+            owned = self._owns(caches)
+            out = jitted(caches, *args)
+            if owned:
+                self._own(out)
+            return out
+        return update
+
+    def _decode_executable(self, params, caches) -> tuple:
+        """(cache key, jitted decode step) for these params and caches;
+        arrays or ``jax.ShapeDtypeStruct`` leaves (with a sharding, to
+        lower for another device)."""
+        if self.per_device and any(leaf.shape[1] % self._dp for leaf in
+                                   jax.tree_util.tree_leaves(caches)):
+            raise ValueError(
+                f"per-device decode needs the slots and the page pool to "
+                f"split evenly over the mesh's {self._dp} data shards")
+        key = ("decode", self._plan_key, self._decode_batch(caches),
+               T.kv_geometry(caches), _tree_sig(caches), _tree_sig(params))
+        return key, self._get(key, self._build_decode,
+                              shardings=None if self.rules is None else
+                              (lambda: self._decode_shardings(params,
+                                                              caches)),
+                              donate=(1,))
+
     def decode_fn(self, params, caches):
         """Resolve the decode executable for this (slot count, cache
         geometry, params structure) once — cached per batch-slot count +
@@ -466,26 +561,22 @@ class Runtime:
         can share one runtime without colliding. The returned callable is
         the per-tick hot path: no signature hashing per token; its
         ``pages`` operand is the scheduler's page table (None for dense
-        caches)."""
-        if self.per_device and any(leaf.shape[1] % self._dp for leaf in
-                                   jax.tree_util.tree_leaves(caches)):
-            raise ValueError(
-                f"per-device decode needs the slots and the page pool to "
-                f"split evenly over the mesh's {self._dp} data shards")
-        key = ("decode", self._plan_key, self._decode_batch(caches),
-               T.kv_geometry(caches), _tree_sig(caches), _tree_sig(params))
-        fn = self._get(key, self._build_decode,
-                       shardings=None if self.rules is None else
-                       (lambda: self._decode_shardings(params, caches)))
+        caches). It donates the caches it is given when this runtime
+        produced them, and a copy of them otherwise (module docstring)."""
+        key, fn = self._decode_executable(params, caches)
 
         def step(params, caches, tokens, pos, active, pages=None):
             with self.phases("samp.dec.dispatch"):
+                if not self._owns(caches):
+                    caches = jax.tree_util.tree_map(jnp.copy, caches)
                 args = (params, caches, jnp.asarray(tokens),
                         jnp.asarray(pos), jnp.asarray(active),
                         None if pages is None else jnp.asarray(pages))
                 if key not in self._arg_shapes:
                     self._note_args(key, args)
-                return fn(*args)
+                logits, caches = fn(*args)
+                self._own(caches)
+                return logits, caches
         return step
 
     @staticmethod

@@ -152,6 +152,77 @@ def test_kernel_inactive_slot_outputs_zero():
 
 
 # ---------------------------------------------------------------------------
+# in-place page write vs the XLA scatter
+# ---------------------------------------------------------------------------
+
+#: (positions, active slots, page-table holes (slot, entry)) per case, for 4
+#: slots over 4-token pages, 3 pages a slot: slot b owns pages 3b..3b+2
+WRITE_CASES = {
+    "first_and_last_row": ([0, 3, 4, 11], [1, 1, 1, 1], []),
+    "table_holes": ([1, 5, 6, 9], [1, 1, 1, 1], [(1, 1), (3, 2)]),
+    "inactive_slots": ([2, 7, 3, 10], [0, 1, 0, 1], []),
+    "two_slots": ([5, 0, 0, 8], [1, 0, 0, 1], []),
+}
+
+
+@pytest.mark.parametrize("lanes", [1, 128])
+@pytest.mark.parametrize("scheme", ["int8_per_token", "int8_per_head"])
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_page_write_matches_xla_scatter(scheme, case, lanes):
+    """The fused backend's page-write kernel (interpret mode) leaves the
+    pool bit for bit as the reference backend's XLA scatter does, on a
+    pool stacked over two layers with the second one written, unpadded
+    and padded to whole lanes: rows 0 and ps-1, -1 table holes and
+    inactive slots drop their writes, and two slots write two pages."""
+    from repro.kernels.backend import FusedBackend
+    from repro.models import layers as L
+    positions, active, holes = WRITE_CASES[case]
+    B, Hkv, hd, ps, pps, nl = 4, 2, 8, 4, 3, 2
+    rng = np.random.default_rng(7)
+    NP = B * pps
+    kv = (nl,) + L.page_leaf_shape(NP, ps, Hkv, hd, lanes=lanes)
+    pool = {"pages_k": rng.integers(-127, 128, kv),
+            "pages_v": rng.integers(-127, 128, kv),
+            "pages_pos": rng.integers(-1, 64, (nl, NP, ps))}
+    pool = {k: jnp.asarray(v, jnp.int8 if k != "pages_pos" else jnp.int32)
+            for k, v in pool.items()}
+    static = {}
+    if scheme == "int8_per_token":
+        for key in ("pages_ks", "pages_vs"):
+            pool[key] = jnp.asarray(rng.uniform(0.01, 0.05, (nl,) + (
+                L.page_leaf_shape(NP, ps, Hkv, lanes=lanes))), jnp.float32)
+    else:
+        static = {key: jnp.asarray(rng.uniform(0.01, 0.05, (Hkv,)),
+                                   jnp.float32) for key in ("k", "v")}
+    table = np.arange(NP, dtype=np.int32).reshape(B, pps)
+    for slot, entry in holes:
+        table[slot, entry] = -1
+    new = {key: jnp.asarray(rng.standard_normal((B, 1, Hkv, hd)),
+                            jnp.float32) for key in ("k", "v")}
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    live = jnp.asarray(active, bool)
+    cache = dict(pool, pos=jnp.zeros((B,), jnp.int32))
+    want = L._paged_cache_write(
+        {k: (v[1] if k in L.POOL_KEYS else v) for k, v in cache.items()},
+        new, pos, live, jnp.asarray(table), static)
+    got = L._paged_cache_write(dict(cache, **{L.POOL_LAYER: jnp.int32(1)}),
+                               new, pos, live, jnp.asarray(table), static,
+                               backend=FusedBackend())
+    assert set(got) - {L.POOL_LAYER} == set(want)
+    for key in want:
+        if key in L.POOL_KEYS:
+            np.testing.assert_array_equal(np.asarray(got[key][0]),
+                                          np.asarray(pool[key][0]))
+            np.testing.assert_array_equal(np.asarray(got[key][1]),
+                                          np.asarray(want[key]))
+        else:
+            np.testing.assert_array_equal(np.asarray(got[key]),
+                                          np.asarray(want[key]))
+    assert np.any(np.asarray(got["pages_k"][1])
+                  != np.asarray(pool["pages_k"][1]))
+
+
+# ---------------------------------------------------------------------------
 # engine parity
 # ---------------------------------------------------------------------------
 
@@ -380,6 +451,79 @@ def test_preemption_per_pool_shard_preserves_outputs(qwen_float):
     tight, eng = serve(8, 2)
     assert tight == roomy
     assert eng.stats["preemptions"] > 0
+
+
+def test_lane_padded_pool_matches_reference(qwen_float, monkeypatch):
+    """Where the kernels compile for a TPU the fused backend pads its int8
+    pages to whole lanes (so their compact layout is the row-major one the
+    kernels read); the padded pool serves token-for-token what the
+    reference backend's unpadded pool serves."""
+    from repro.kernels import ops
+    cfg, params, plan = qwen_float
+    ref, _ = _serve(cfg, params, plan, PROMPTS, page_size=8,
+                    kv_cache="int8_per_token", backend="reference")
+    monkeypatch.setattr(ops, "page_lanes", lambda: ops.LANES)
+    fused, eng = _serve(cfg, params, plan, PROMPTS, page_size=8,
+                        kv_cache="int8_per_token", backend="fused")
+    assert fused == ref
+    leaves = {str(p[-1].key): leaf for p, leaf in
+              jax.tree_util.tree_leaves_with_path(eng.caches)
+              if hasattr(p[-1], "key")}
+    assert leaves["pages_k"].shape[-1] == ops.LANES
+    assert leaves["pages_ks"].shape[-1] == ops.LANES
+
+
+def test_donated_steps_keep_engine_caches_valid(qwen_float):
+    """The decode step donates the engine's cache tree, so the pools are
+    updated in place: after its first tick (which copies the fresh tree)
+    every tick consumes the tree the one before returned, across admits,
+    retirements, page invalidation and preemption replay, and the engine
+    never holds a donated buffer. Outputs equal the reference backend's,
+    which donates the same way."""
+    cfg, params, plan = qwen_float
+
+    def serve(backend):
+        eng = ServeEngine(cfg, params, plan, batch_slots=2, max_len=64,
+                          page_size=4, pool_pages=4, backend=backend,
+                          kv_cache="int8_per_token")
+        inner, seen = eng._decode, []
+
+        def watched(params, caches, *args):
+            leaf = jax.tree_util.tree_leaves(caches)[0]
+            assert not leaf.is_deleted()
+            logits, new = inner(params, caches, *args)
+            seen.append(leaf)
+            return logits, new
+        eng._decode = watched
+        for i, p in enumerate(PROMPTS):
+            eng.submit(Request(uid=i, prompt=list(p), max_tokens=8))
+        out = {r.uid: r.output for r in eng.run()}
+        assert eng.stats["preemptions"] > 0
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(eng.caches))
+        # the first tick's tree came from init_caches and was copied; every
+        # later one was the runtime's own, and was donated
+        assert not seen[0].is_deleted()
+        assert all(leaf.is_deleted() for leaf in seen[1:])
+        return out
+
+    assert serve("fused") == serve("reference")
+
+
+def test_runtime_decode_leaves_the_callers_caches(qwen_float):
+    """``Runtime.decode`` on a cache tree its caller holds copies it before
+    the donating step, so the caller can step from the same tree twice."""
+    cfg, params, plan = qwen_float
+    eng = ServeEngine(cfg, params, plan, batch_slots=2, max_len=64,
+                      page_size=4, kv_cache="int8_per_token",
+                      backend="fused")
+    args = (np.array([[3], [4]], np.int32), np.zeros(2, np.int32),
+            np.ones(2, bool), eng.pool.table)
+    eng.pool.ensure(0, 1)
+    eng.pool.ensure(1, 1)
+    first, _ = eng.runtime.decode(params, eng.caches, *args)
+    again, _ = eng.runtime.decode(params, eng.caches, *args)
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(again))
 
 
 def test_single_oversized_request_raises(qwen_float):

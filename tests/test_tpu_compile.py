@@ -24,6 +24,7 @@ from repro.kernels import decode_attention as da
 from repro.kernels import dynamic_quant as dq
 from repro.kernels import flash_attention as fa
 from repro.kernels import fused_embed as fe
+from repro.kernels import page_write as pw
 from repro.kernels import quant_linear as ql
 
 # bert-base: d_model 768, 12 heads of 64, ffn 3072, vocab 21128, 512
@@ -32,10 +33,12 @@ BERT = dict(M=4096, D=768, F=3072, V=21128, P=512, B=8, H=12, S=512, hd=64)
 # qwen2-0.5b: d_model 896, ffn 4864, 14 query / 2 kv heads of 64; 4 decode
 # slots over 16-token pages, 512 tokens per slot
 QWEN = dict(D=896, F=4864, B=4, Hkv=2, g=7, hd=64, ps=16, pps=32)
+# the chat-decode cell's pool: 32 slots of 768 tokens over 16-token pages
+CHAT = dict(B=32, max_len=768, ps=16, NP=32 * 48)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -49,9 +52,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _assert_kernel(one_chip, fn, *shapes):
@@ -170,3 +178,117 @@ def test_decode_attention(one_chip, mode):
         ((B, Hkv, g, hd), F32), ((NP, Hkv, ps, hd), I8),
         ((NP, Hkv, ps, hd), I8), ((B, pps), I32), ((B,), I32),
         (scale_shape, F32), (scale_shape, F32), ((), F32))
+
+
+@pytest.mark.parametrize("lanes", [1, 128])
+def test_page_write(one_chip, lanes):
+    """The in-place page write over a whole 24-layer stack of the cell's
+    pool, K/V pages and their per-token scales, unpadded and padded to
+    whole lanes (as the runtime holds it)."""
+    B, Hkv, hd, ps, NP = CHAT["B"], QWEN["Hkv"], QWEN["hd"], CHAT["ps"], \
+        CHAT["NP"]
+    wide = lambda n: -(-n // lanes) * lanes          # noqa: E731
+    _assert_kernel(
+        one_chip,
+        lambda k, v, ks, vs, rk, rv, rks, rvs, ly, pg, rw: pw.page_write(
+            (k, v, ks, vs), (rk, rv, rks, rvs), ly, pg, rw,
+            interpret=False),
+        ((24, NP, Hkv, ps, wide(hd)), I8), ((24, NP, Hkv, ps, wide(hd)), I8),
+        ((24, NP, Hkv, wide(ps)), F32), ((24, NP, Hkv, wide(ps)), F32),
+        ((B, Hkv, hd), I8), ((B, Hkv, hd), I8), ((B, Hkv), F32),
+        ((B, Hkv), F32), ((), I32), ((B,), I32), ((B,), I32))
+
+
+def _decode_step_text(monkeypatch, scheme, on, rows, mesh=None):
+    """Compiled HLO text of the serving runtime's decode step at the
+    chat-decode cell's geometry (qwen2-0.5b widths, two layers, fused
+    backend, int8 pages as the runtime pads them for the chip), with the
+    caches; ``on`` / ``rows`` place the caches and the per-slot operands."""
+    from repro.configs import get_config
+    from repro.core.precision import EncoderPolicy
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.serve.runtime import Runtime
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("qwen2-0.5b").replace(num_layers=2)
+    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    plan = T.build_plan(cfg, policy)
+
+    def params_fn():
+        params = T.init_params(jax.random.PRNGKey(0), cfg, policy)
+        if scheme == "int8_per_head":       # calibrated per-head scales
+            for group in params["groups"]:
+                for lp in group["layers"]:
+                    for key in ("kc_scale", "vc_scale"):
+                        lp["attn"][key] = jnp.full(
+                            (cfg.num_layers, cfg.num_kv_heads), 0.05)
+        return params
+
+    def placed(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), tree)
+    B = CHAT["B"]
+    rt = Runtime(cfg, plan, backend="fused", compute_dtype=F32, mesh=mesh)
+    assert rt.backend.page_lanes() == 128   # compiled: lane-dense pages
+    params = placed(jax.eval_shape(params_fn), rows if mesh is None
+                    else jax.sharding.NamedSharding(
+                        mesh, jax.sharding.PartitionSpec()))
+    caches = placed(jax.eval_shape(lambda: T.init_caches(
+        cfg, plan, B, CHAT["max_len"], F32, page_size=CHAT["ps"],
+        kv_schemes=(scheme,) * cfg.num_layers,
+        lanes=rt.backend.page_lanes())), on)
+    _, step = rt._decode_executable(params, caches)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=rows) for s, d in
+            (((B, 1), I32), ((B,), I32), ((B,), jnp.bool_),
+             ((B, CHAT["max_len"] // CHAT["ps"]), I32))]
+    return step.lower(params, caches, *args).compile().as_text(), caches, rt
+
+
+@pytest.mark.parametrize("scheme", ["int8_per_token", "int8_per_head"])
+def test_decode_step_keeps_the_pool_in_place(one_chip, monkeypatch, scheme):
+    """The decode step holds no relayout copy of a page-pool leaf
+    (``pool_copies`` 0), and ``decode_attention`` is still the Pallas
+    kernel with its ``f32[slots, kv_heads, group, head_dim]`` result."""
+    from repro.serve.runtime import pool_copies
+    text, caches, _ = _decode_step_text(monkeypatch, scheme, one_chip,
+                                        one_chip)
+    assert pool_copies(text, caches) == 0
+    attn = [line for line in text.splitlines()
+            if "%decode_attention" in line and "custom-call(" in line]
+    assert attn and all("tpu_custom_call" in line for line in attn)
+    assert all(f"f32[{CHAT['B']},{QWEN['Hkv']},{QWEN['g']},{QWEN['hd']}]"
+               in line for line in attn)
+
+
+def test_decode_step_per_device_keeps_the_pool_in_place(topo, monkeypatch):
+    """On a data-parallel mesh of the described four chips each device
+    runs the step on its share of the pool, and no device relayouts it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.serve.runtime import pool_copies
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    text, caches, rt = _decode_step_text(
+        monkeypatch, "int8_per_token",
+        NamedSharding(mesh, PartitionSpec(None, "data")),
+        NamedSharding(mesh, PartitionSpec("data")), mesh=mesh)
+    assert rt.shards == 4 and "tpu_custom_call" in text
+    assert pool_copies(text, caches, rt.shards) == 0
+
+
+@pytest.mark.parametrize("per_head", [False, True])
+def test_decode_attention_on_a_lane_padded_stack(one_chip, per_head):
+    """``decode_attention`` reading one layer of the cell's whole 24-layer
+    pool, padded to whole lanes, with the layer as an operand."""
+    B, Hkv, g, hd, ps = CHAT["B"], QWEN["Hkv"], QWEN["g"], QWEN["hd"], \
+        CHAT["ps"]
+    NP, pps = CHAT["NP"], CHAT["max_len"] // CHAT["ps"]
+    scale_shape = (Hkv,) if per_head else (24, NP, Hkv, 128)
+    _assert_kernel(
+        one_chip,
+        lambda q, k, v, pt, ln, ks, vs, ly: da.decode_attention(
+            q, k, v, pt, ln, k_scale=ks, v_scale=vs, per_head=per_head,
+            layer=ly, interpret=False),
+        ((B, Hkv, g, hd), F32), ((24, NP, Hkv, ps, 128), I8),
+        ((24, NP, Hkv, ps, 128), I8), ((B, pps), I32), ((B,), I32),
+        (scale_shape, F32), (scale_shape, F32), ((), I32))
