@@ -1,7 +1,8 @@
 """Architecture config registry. ``load_all()`` imports every config module
 (side-effect registration); ``get_config(name)`` resolves one."""
 from repro.configs.base import (ArchConfig, BlockKind, MLAConfig, MoEConfig,
-                                all_configs, get_config, register)
+                                RopeScaling, all_configs, get_config,
+                                register)
 
 _LOADED = False
 
@@ -17,6 +18,7 @@ _MODULES = (
     "xlstm_125m",
     "hubert_xlarge",
     "recurrentgemma_9b",
+    "deepseek_v2_lite",
 )
 
 
@@ -42,7 +44,10 @@ ARCH_IDS = (
     "xlstm-125m",
     "hubert-xlarge",
     "recurrentgemma-9b",
+    "deepseek-v2-lite",
+    "deepseek-v2-lite-ep4",
 )
 
-__all__ = ["ArchConfig", "BlockKind", "MLAConfig", "MoEConfig", "register",
+__all__ = ["ArchConfig", "BlockKind", "MLAConfig", "MoEConfig", "RopeScaling",
+           "register",
            "get_config", "all_configs", "load_all", "ARCH_IDS"]

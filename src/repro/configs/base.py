@@ -14,12 +14,52 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """A routed-expert FFN. ``router``: ``topk_softmax`` takes the top-k
+    logits and a softmax over those k (Mixtral); ``softmax_topk`` takes a
+    softmax over all experts and keeps the greedy top-k of those
+    probabilities unrenormalized (DeepSeek-V2-Lite). The layer holds the
+    first ``held_count`` of ``num_experts`` (one chip's expert-parallel
+    share; None holds them all): the router keeps its ``num_experts``
+    outputs, and a pick of an expert not held adds nothing.
+    ``capacity_factor`` None is dropless: every pick of a held expert is
+    computed."""
     num_experts: int
     top_k: int
     d_ff_expert: int
     num_shared: int = 0          # shared (always-on) experts, deepseek-v2: 2
     first_dense: int = 0         # leading dense-FFN layers, deepseek-v2: 1
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25
+    router: str = "topk_softmax"     # | "softmax_topk"
+    held_count: Optional[int] = None
+
+    def __post_init__(self):
+        if self.router not in ("topk_softmax", "softmax_topk"):
+            raise ValueError(f"unknown MoE router {self.router!r}")
+        if not 0 < self.held <= self.num_experts:
+            raise ValueError(f"{self.held} held experts of "
+                             f"{self.num_experts}")
+
+    @property
+    def held(self) -> int:
+        """How many experts, from expert 0, this layer holds."""
+        return (self.num_experts if self.held_count is None
+                else self.held_count)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 computes it:
+    rotary frequency indices between the correction dims of ``beta_fast``
+    and ``beta_slow`` rotations over ``original_max_position`` blend from
+    the base frequencies to those divided by ``factor``, and the attention
+    softmax scale is multiplied by ``yarn_mscale(factor, mscale) ** 2``.
+    ``mscale`` stands for DeepSeek-V2's equal ``mscale`` and
+    ``mscale_all_dim``, whose ratio scales cos and sin: by 1."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +114,7 @@ class ArchConfig:
     norm_kind: str = "rmsnorm"   # rmsnorm|layernorm
     position: str = "rope"       # rope|learned|none
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None    # YaRN (deepseek-v2-lite)
     max_position: int = 524_288  # learned-position table size cap
     tie_embeddings: bool = True
     emb_scale_by_sqrt_dim: bool = False   # gemma family
@@ -142,13 +183,25 @@ class ArchConfig:
             num_prefix_embeds=4 if self.num_prefix_embeds else 0,
             frontend_dim=32 if self.frontend_dim else 0,
         )
-        if self.moe is not None:
+        if self.moe is not None and self.moe.router == "softmax_topk":
+            # keep the router's k and the held share's proportion: 8
+            # experts, of which a held share scaled as published
+            kw["moe"] = dataclasses.replace(
+                self.moe, num_experts=8, d_ff_expert=32,
+                top_k=min(self.moe.top_k, 8),
+                num_shared=min(self.moe.num_shared, 1),
+                first_dense=min(self.moe.first_dense, 1),
+                held_count=(None if self.moe.held_count is None else max(
+                    1, self.moe.held_count * 8 // self.moe.num_experts)))
+        elif self.moe is not None:
             kw["moe"] = dataclasses.replace(
                 self.moe, num_experts=4, top_k=2, d_ff_expert=32,
                 num_shared=min(self.moe.num_shared, 1),
                 first_dense=min(self.moe.first_dense, 1))
         if self.mla is not None:
-            kw["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=32,
+            kw["mla"] = MLAConfig(kv_lora_rank=32,
+                                  q_lora_rank=32 if self.mla.q_lora_rank
+                                  else 0,
                                   qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
         # keep the pattern length compatible with the reduced layer count
         n = kw["num_layers"]

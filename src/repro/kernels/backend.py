@@ -261,6 +261,8 @@ class FusedBackend(ComputeBackend):
             return None          # float block / expert stack: reference path
         K, N = w.values.shape
         from repro.kernels import ops
+        if not (ops.lane_tiled(K) and ops.lane_tiled(N)):
+            return None          # no whole-lane tiling: reference path
         if isinstance(x, QuantActivation):
             # already int8 — the fused addnorm quantized it with the static
             # scale this GEMM was calibrated on; no runtime quant needed
@@ -296,11 +298,11 @@ class FusedBackend(ComputeBackend):
 
     # -- routed expert GEMM stack --------------------------------------------
     def expert_gemm(self, xe, w, xs=None):
-        # Claims int8 expert stacks: each expert's routed token shard runs
-        # through the fused quant_linear kernel with its own per-expert
-        # scale operands (weights (E, 1, F); static acts (E, 1, 1) — a
-        # scalar xs, the pre-v4 ffn_in fallback, broadcasts to every
-        # expert). Declines float stacks.
+        # Claims int8 expert stacks: one grouped quant_expert_gemm kernel
+        # per GEMM, its grid walking the experts, each with its own
+        # per-expert scale operands (weights (E, 1, F); static acts
+        # (E, 1, 1) — a scalar xs, the pre-v4 ffn_in fallback, is every
+        # expert's). Declines float stacks.
         if (not self._enabled or not isinstance(w, QuantizedTensor)
                 or w.values.ndim != 3):
             return None

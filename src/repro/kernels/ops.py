@@ -19,6 +19,7 @@ them and the reference XLA ops per block via the ``BACKENDS`` registry.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Union
 
 import jax
@@ -49,6 +50,13 @@ def page_lanes() -> int:
     XLA's compact layout of the pool is the row-major one they read), 1 in
     interpret mode."""
     return 1 if _interpret() else LANES
+
+
+def lane_tiled(n: int) -> bool:
+    """Whether a GEMM dim of ``n`` splits into the whole 128-lane blocks
+    Mosaic tiles (or fits one block): always in interpret mode. A dense
+    FFN 10944 wide (deepseek-v2-lite) does not."""
+    return _interpret() or n <= LANES or n % LANES == 0
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -97,20 +105,18 @@ def dynamic_quant(x, *, bm: int = 256):
     return _dq.dynamic_quant(x, bm=bm, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "bm", "bn", "bk"))
-def quant_expert_gemm(xe, w_q, w_scale, xs=None, *, out_dtype=jnp.float32,
-                      bm: int = 128, bn: int = 128, bk: int = 128):
-    """Batched per-expert W8A8 GEMM: a routed capacity buffer
-    ``xe (..., E, C, D)`` against an int8 expert stack ``w_q (E, D, F)``
-    -> ``(..., E, C, F)``.
+@functools.partial(jax.jit, static_argnames=("out_dtype",))
+def quant_expert_gemm(xe, w_q, w_scale, xs=None, *, out_dtype=jnp.float32):
+    """Grouped per-expert W8A8 GEMM: a routed buffer ``xe (..., E, C, D)``
+    against an int8 expert stack ``w_q (E, D, F)`` -> ``(..., E, C, F)``,
+    in ONE Pallas kernel (``quant_linear.quant_expert_gemm``; the trace
+    names it ``quant_expert_gemm``) whose grid walks the experts.
 
     Per-expert scales are **operands**: ``w_scale`` broadcastable to
     (E, 1, F) (per-expert-per-channel, the v4 ``experts`` family layout) and
     ``xs`` broadcastable to (E, 1, 1) (per-expert static activation scales;
-    ``None`` selects per-token dynamic quantization via ``dynamic_quant``).
-    The expert axis is a static Python grid — expert count is model
-    structure, not data — so each expert's token shard runs through one
-    fused ``quant_linear`` with exactly its own scale operands.
+    a scalar is one scale for every expert; ``None`` selects per-token
+    dynamic quantization).
     """
     from repro.core.quantize import quantize, quantize_per_token
     E, D, F = w_q.shape
@@ -119,38 +125,31 @@ def quant_expert_gemm(xe, w_q, w_scale, xs=None, *, out_dtype=jnp.float32,
     ws = jnp.broadcast_to(ws.reshape((1, 1, -1) if ws.ndim < 3 else ws.shape),
                           (E, 1, F)).reshape(E, F)
     # Quantize the whole routed buffer in ONE op, exactly the subgraph the
-    # reference einsum path builds, then slice codes per expert. Quantizing
-    # per-expert slices separately lets XLA fuse the round differently
-    # (reciprocal-multiply vs divide), and a ±1 code flip at a rounding
-    # boundary is an O(scale) output step — which the MoE router then
-    # amplifies into a different top-k choice. Identical subgraph ->
-    # identical codes -> backend choice never moves the routing.
+    # reference einsum path builds: a different fusion of the round
+    # (reciprocal-multiply vs divide) can flip a code at a rounding
+    # boundary, an O(scale) output step the router then amplifies into a
+    # different top-k choice. Identical subgraph -> identical codes ->
+    # backend choice never moves the routing.
     if xs is not None:
         xs_b = jnp.asarray(xs, jnp.float32)
-        if xs_b.ndim == 0:                               # legacy scalar plan
-            codes = quantize(xe, xs_b)
-            x_scales = [xs_b] * E
-        else:
-            xs3 = jnp.broadcast_to(xs_b.reshape(-1, 1, 1), (E, 1, 1))
-            codes = quantize(xe, xs3)
-            x_scales = [xs3[e, 0, 0] for e in range(E)]
+        xs_b = xs_b if xs_b.ndim == 0 else xs_b.reshape(-1, 1, 1)
+        codes = quantize(xe, xs_b)
+        rows_scale = jnp.broadcast_to(xs_b, xe.shape[:-1] + (1,))
     else:
         xq = quantize_per_token(xe)                      # (..., E, C, 1)
-        codes = xq.values
-        sc4 = xq.scale.reshape((-1,) + xq.scale.shape[-3:])
-        x_scales = None
-    x4 = codes.reshape((-1,) + codes.shape[-3:])         # (G, E, C, D) int8
-    G, _, C, _ = x4.shape
-    outs = []
-    for e in range(E):
-        rows_q = x4[:, e].reshape(G * C, D)
-        x_scale = (x_scales[e] if x_scales is not None
-                   else sc4[:, e].reshape(G * C, 1))
-        y = quant_linear(rows_q, w_q[e], ws[e], x_scale, bias=None, act=None,
-                         out_scale=None, out_dtype=out_dtype,
-                         bm=bm, bn=bn, bk=bk)
-        outs.append(y.reshape(G, C, F))
-    return jnp.stack(outs, axis=1).reshape(lead + (E, C, F))
+        codes, rows_scale = xq.values, xq.scale
+    # (G, E, C, .) -> (E, G * C, .): each expert's rows of every group
+    G = math.prod(lead)
+    C = xe.shape[-2]
+
+    def by_expert(a):
+        a = a.reshape((G, E, C, a.shape[-1]))
+        return a.transpose(1, 0, 2, 3).reshape(E, G * C, a.shape[-1])
+    y = _ql.quant_expert_gemm(by_expert(codes), w_q, ws,
+                              by_expert(rows_scale), out_dtype=out_dtype,
+                              interpret=_interpret())
+    y = y.reshape(E, G, C, F).transpose(1, 0, 2, 3)
+    return y.reshape(lead + (E, C, F))
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -59,8 +59,8 @@ def fit_block(n: int, b: int) -> int:
 
 
 def _kernel(x_ref, w_ref, ws_ref, xs_ref, b_ref, os_ref, o_ref, acc_ref, *,
-            nk: int, act: Optional[str], requant: bool):
-    k = pl.program_id(2)
+            nk: int, act: Optional[str], requant: bool, k_axis: int = 2):
+    k = pl.program_id(k_axis)             # the grid's last axis walks K
 
     @pl.when(k == 0)
     def _init():
@@ -139,3 +139,60 @@ def quant_linear(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array,
     )(x_q, w_q, w_scale.reshape(1, N).astype(jnp.float32), xs,
       bias.reshape(1, N).astype(jnp.float32), os_op)
     return out
+
+
+def fit_lanes(n: int, b: int) -> int:
+    """A block of ``n`` that Mosaic tiles whole: ``n`` itself when it is at
+    most ``b``, else the largest multiple of 128 that divides ``n`` and is
+    at most ``b`` (``n`` itself when there is none)."""
+    if n <= b:
+        return n
+    for blk in range(b - b % 128, 0, -128):
+        if n % blk == 0:
+            return blk
+    return n
+
+
+def quant_expert_gemm(x_q: jax.Array, w_q: jax.Array, w_scale: jax.Array,
+                      x_scale: jax.Array, *, out_dtype=jnp.float32,
+                      bm: int = 256, bn: int = 2048, bk: int = 2048,
+                      interpret: bool = False) -> jax.Array:
+    """Grouped W8A8 GEMM over a stack of experts in one kernel:
+    ``y[e] = (x_q[e] @ w_q[e]) * x_scale[e] * w_scale[e]``.
+
+    x_q: (E, M, K) int8, each expert's buffer of routed rows; w_q: (E, K, N)
+    int8, the expert stack; w_scale: (E, N) f32 per-expert-per-channel;
+    x_scale: (E, M, 1) f32 per-row activation scales. The grid is
+    (experts, row blocks, column blocks, K blocks): the expert index picks
+    the weight block and its scales, and the body is :func:`quant_linear`'s
+    (the int32 accumulator in VMEM across the K axis, dequant in the
+    epilogue). Blocks are large by default — a decode step's buffers hold
+    few rows, so the kernel streams whole weight tiles of each expert."""
+    E, M, K = x_q.shape
+    E2, K2, N = w_q.shape
+    assert (E, K) == (E2, K2), (x_q.shape, w_q.shape)
+    bm, bn, bk = fit_block(M, bm), fit_lanes(N, bn), fit_lanes(K, bk)
+    nk = K // bk
+    kernel = functools.partial(_kernel, nk=nk, act=None, requant=False,
+                               k_axis=3)
+    return pl.pallas_call(
+        kernel,
+        grid=(E, M // bm, N // bn, nk),
+        in_specs=[
+            pl.BlockSpec((None, bm, bk), lambda e, i, j, k: (e, i, k)),
+            pl.BlockSpec((None, bk, bn), lambda e, i, j, k: (e, k, j)),
+            pl.BlockSpec((None, 1, bn), lambda e, i, j, k: (e, 0, j)),
+            pl.BlockSpec((None, bm, 1), lambda e, i, j, k: (e, i, 0)),
+            pl.BlockSpec((1, bn), lambda e, i, j, k: (0, j)),
+            pl.BlockSpec((1, 1), lambda e, i, j, k: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
+        out_shape=jax.ShapeDtypeStruct((E, M, N), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+    )(x_q, w_q, w_scale.reshape(E, 1, N).astype(jnp.float32),
+      x_scale.reshape(E, M, 1).astype(jnp.float32),
+      jnp.zeros((1, N), jnp.float32), jnp.ones((1, 1), jnp.float32))

@@ -230,19 +230,56 @@ def init_norm(kind: str, dim: int, dtype=jnp.float32) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
-    """Inverse frequencies for the even half of the head dim (f32)."""
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling=None) -> jax.Array:
+    """Inverse frequencies for the even half of the head dim (f32).
+    ``scaling`` (a :class:`~repro.configs.base.RopeScaling`) gives YaRN's:
+    frequency indices past the ``beta_fast`` correction dim blend, along a
+    linear ramp that ends at the ``beta_slow`` one, into the base
+    frequencies divided by ``factor`` (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return inv
+    low, high = yarn_ramp_bounds(head_dim, theta, scaling)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def yarn_ramp_bounds(head_dim: int, theta: float, scaling) -> tuple:
+    """(low, high) frequency indices of YaRN's ramp: the rotary dims at
+    which ``beta_fast`` and ``beta_slow`` full rotations fit in the
+    original context, floored and ceiled."""
+    def dim(rotations):
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(scaling.beta_fast)), 0),
+            min(math.ceil(dim(scaling.beta_slow)), head_dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_softmax_scale(scaling) -> float:
+    """The factor YaRN puts on the attention softmax scale:
+    ``yarn_mscale(factor, mscale) ** 2`` (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale) ** 2
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               heads_axis: bool = True) -> jax.Array:
+               heads_axis: bool = True, scaling=None) -> jax.Array:
     """x: (..., S, H, hd) when ``heads_axis`` else (..., S, hd);
     positions: (S,) int32 (uniform across batch — prefill/train) or (B, S)
-    (per-row — continuous-batching decode). Split-half convention."""
+    (per-row — continuous-batching decode). Split-half convention.
+    ``scaling``: YaRN frequencies (:func:`rope_frequencies`)."""
     head_dim = x.shape[-1]
-    inv = rope_frequencies(head_dim, theta)                  # (hd/2,)
+    inv = rope_frequencies(head_dim, theta, scaling)         # (hd/2,)
     ang = positions.astype(jnp.float32)[..., :, None] * inv  # (..., S, hd/2)
     if heads_axis:
         ang = ang[..., :, None, :]                           # (..., S, 1, hd/2)
@@ -779,14 +816,16 @@ def attention_block(x: jax.Array, p: dict, cfg, *, positions: jax.Array,
                   .reshape(B, S, cfg.num_kv_heads, cfg.head_dim),
                   "attn_heads")
     if cfg.position == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta,
+                       scaling=cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta,
+                       scaling=cfg.rope_scaling)
     observe_per_head(obs, "k_cache", k)
     observe_per_head(obs, "v_cache", v)
     new_cache = None
     k_pos = positions
     o = None
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = rope_softmax_scale(cfg.rope_scaling) / math.sqrt(cfg.head_dim)
     static_sc = {key: p[f"{key}c_scale"] for key in ("k", "v")
                  if f"{key}c_scale" in p}
     if is_paged(kv_cache):
@@ -904,15 +943,17 @@ def mla_block(x: jax.Array, p: dict, cfg, *, positions: jax.Array,
         q = dense(x, p["wq"])
     q = q.reshape(B, S, H, nope + rd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, heads_axis=True)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, heads_axis=True,
+                        scaling=cfg.rope_scaling)
     # --- latent kv ----------------------------------------------------------
     kv = dense(x, p["wkv_a"])
     ckv, k_rope = kv[..., :m.kv_lora_rank], kv[..., m.kv_lora_rank:]
     ckv = rms_norm(ckv, p["kv_norm"])
     observe(obs, "c_kv", ckv)
     k_rope = apply_rope(k_rope, positions, cfg.rope_theta,
-                        heads_axis=False)                    # (B,S,rd) shared
-    scale = 1.0 / math.sqrt(nope + rd)
+                        heads_axis=False,
+                        scaling=cfg.rope_scaling)            # (B,S,rd) shared
+    scale = rope_softmax_scale(cfg.rope_scaling) / math.sqrt(nope + rd)
     wkv_b = p["wkv_b"]["w"]
     if isinstance(wkv_b, QuantizedTensor):
         wkv_b_f = wkv_b.dequantize(x.dtype)
@@ -1009,12 +1050,15 @@ def ffn_block(x, p: dict, cfg, obs: Optional[dict] = None,
 
 
 def init_moe(key, cfg, dtype=jnp.float32) -> dict:
+    """The router over all ``num_experts`` and stacks of the held experts
+    only (``MoEConfig.held``)."""
     mo = cfg.moe
     ks = jax.random.split(key, 5)
-    E, D, F = mo.num_experts, cfg.d_model, mo.d_ff_expert
+    E, D, F = mo.held, cfg.d_model, mo.d_ff_expert
     std = 1.0 / math.sqrt(D)
     p = {
-        "router": {"w": jax.random.normal(ks[0], (D, E), jnp.float32) * std},
+        "router": {"w": jax.random.normal(ks[0], (D, mo.num_experts),
+                                          jnp.float32) * std},
         "wg": {"w": jax.random.normal(ks[1], (E, D, F), dtype) * std},
         "wu": {"w": jax.random.normal(ks[2], (E, D, F), dtype) * std},
         "wd": {"w": jax.random.normal(ks[3], (E, F, D), dtype)
@@ -1033,8 +1077,8 @@ def _expert_gemm(xe: jax.Array, w, xs: Optional[jax.Array],
     Quantized experts hold per-expert-per-channel weight scales (E, 1, N)
     (2-D blocks) or, under the v4 ``experts`` family, per-expert static
     activation scales ``xs`` shaped (E, 1, 1). ``backend`` may claim the
-    op via ``expert_gemm`` (the fused per-expert quant_linear path) or
-    decline, keeping this reference einsum."""
+    op via ``expert_gemm`` (the fused grouped ``quant_expert_gemm``
+    kernel) or decline, keeping this reference einsum."""
     eq = ("gecd,edf->gecf" if xe.ndim == 4 else "ecd,edf->ecf")
     observe(obs, site, xe)
     if backend is not None and isinstance(w, QuantizedTensor):
@@ -1052,24 +1096,54 @@ def _expert_gemm(xe: jax.Array, w, xs: Optional[jax.Array],
     return jnp.einsum(eq, xe, w.astype(xe.dtype))
 
 
-def _dispatch_one(xt, logits, E, K, C, obs_unused=None):
-    """Sort-based capacity dispatch for ONE token group.
-    xt: (Tl, D); logits: (Tl, E). Returns (xe (E, C, D), st, sg, keep, slot)
-    for the combine step."""
-    Tl = xt.shape[0]
-    gates, idx = jax.lax.top_k(logits, K)                        # (Tl, K)
-    gates = jax.nn.softmax(gates, axis=-1)
+def moe_capacity(mo, tokens: int) -> int:
+    """Rows of each held expert's buffer for ``tokens`` routed tokens: the
+    capacity bound, or ``tokens`` when dropless (a token picks an expert at
+    most once, so no pick is ever dropped)."""
+    if mo.capacity_factor is None:
+        return tokens
+    return max(1, int(math.ceil(mo.capacity_factor * tokens * mo.top_k
+                                / mo.num_experts)))
+
+
+def route(logits: jax.Array, mo) -> tuple:
+    """(gates, experts), each (..., top_k), of every token's picks from its
+    (..., num_experts) float32 router logits, by ``mo.router``."""
+    K = mo.top_k
+    if mo.router == "softmax_topk":
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    gates, idx = jax.lax.top_k(logits, K)
+    return jax.nn.softmax(gates, axis=-1), idx
+
+
+def _dispatch_picks(xt, gates, idx, E, C, held: bool = False, valid=None):
+    """Sort-based dispatch of ONE token group's picks into per-expert
+    buffers of ``C`` rows. xt: (Tl, D); gates/idx: (Tl, K). With
+    ``held``, only picks of experts ``[0, E)`` of tokens whose ``valid``
+    (Tl,) is set (all, when None) are routed; every other pick sorts
+    after the held experts and is dropped. Returns (xe (E, C, D),
+    st, sg, keep, slot) for the combine step; ``keep`` marks the picks
+    that were computed."""
+    Tl, K = idx.shape
     flat_expert = idx.reshape(-1)                                # (Tl*K,)
     flat_token = jnp.repeat(jnp.arange(Tl), K)
     flat_gate = gates.reshape(-1)
+    if held:
+        mine = flat_expert < E
+        if valid is not None:
+            mine = mine & valid[flat_token]
+        flat_expert = jnp.where(mine, flat_expert, E)
     order = jnp.argsort(flat_expert)                             # stable
     se, st, sg = flat_expert[order], flat_token[order], flat_gate[order]
     ones = jnp.ones_like(se)
     pos_in_expert = jax.lax.associative_scan(jnp.add, ones) - 1
-    seg_start = jnp.searchsorted(se, jnp.arange(E))              # (E,)
+    seg_start = jnp.searchsorted(se, jnp.arange(E + 1 if held else E))
     pos_in_expert = pos_in_expert - seg_start[se]
     keep = pos_in_expert < C
     slot = se * C + jnp.where(keep, pos_in_expert, 0)            # (Tl*K,)
+    if held:
+        keep = keep & (se < E)
+        slot = jnp.where(keep, slot, 0)
     src = jnp.where(keep[:, None], xt[st], 0)
     xe = jnp.zeros((E * C, xt.shape[1]), xt.dtype).at[slot].add(src)
     return xe.reshape(E, C, xt.shape[1]), st, sg, keep, slot
@@ -1084,16 +1158,27 @@ def _combine_one(ye, st, sg, keep, slot, Tl, D, dtype):
 
 def moe_block(x: jax.Array, p: dict, cfg, obs: Optional[dict] = None,
               constrain: Callable[[jax.Array, str], jax.Array] = lambda a, _: a,
-              backend=None) -> jax.Array:
-    """Top-k MoE with capacity-bounded sort-based dispatch.
+              backend=None, active: Optional[jax.Array] = None):
+    """Top-k MoE with sort-based dispatch; returns ``(y, rows)``, ``rows``
+    the picks the held experts computed (an int32 scalar).
 
-    Router (always float — it is tiny and precision-critical) picks top-k
-    experts per token; tokens are routed into per-expert capacity buffers via
-    an argsort over expert ids (the TPU-native alternative to the (T, E, C)
-    one-hot einsum, which does not fit memory at 160 experts), batched
-    expert GEMMs run over (E, C, D), and results scatter-add back with the
-    gate weights. Overflowing tokens are dropped (capacity factor bounds the
-    buffer — standard Switch/MaxText semantics).
+    Router (always float — it is tiny and precision-critical) scores every
+    expert and picks top-k per token (``MoEConfig.router``); tokens are
+    routed into per-expert buffers via an argsort over expert ids (the
+    TPU-native alternative to the (T, E, C) one-hot einsum, which does not
+    fit memory at 160 experts), batched expert GEMMs run over (E, C, D),
+    and results scatter-add back with the gate weights. Under a capacity
+    factor, overflowing tokens are dropped (standard Switch/MaxText
+    semantics); dropless configs give each held expert a buffer of every
+    token (:func:`moe_capacity`).
+
+    **Held share**: the layer holds the first ``MoEConfig.held`` of the
+    router's ``num_experts`` (one chip's expert-parallel share): only their
+    stacks exist, and a pick of any other expert adds nothing — the part
+    the absent experts would add is left out, and no exchange runs.
+    ``active`` (B,) marks the rows whose tokens route at all: an inactive
+    decode slot's token routes nowhere, so it neither takes capacity nor
+    counts in ``rows``.
 
     **Distribution**: sort/gather/scatter with data-dependent indices cannot
     cross a sharded axis without GSPMD replicating the (T*K, D) routed
@@ -1111,19 +1196,32 @@ def moe_block(x: jax.Array, p: dict, cfg, obs: Optional[dict] = None,
     mo = cfg.moe
     B, S, D = x.shape
     T = B * S
-    E, K = mo.num_experts, mo.top_k
+    E = mo.held
     groups = getattr(constrain, "dsize", 1)
     if T % max(groups, 1) or groups <= 1:
         groups = 1
     Tl = T // groups
-    C = max(1, int(math.ceil(mo.capacity_factor * Tl * K / E)))
+    C = moe_capacity(mo, Tl)
+    # picks outside the held share, or of inactive rows, are dropped
+    held = E != mo.num_experts or active is not None
     observe(obs, "ffn_in", x)
     xg = constrain(x.reshape(groups, Tl, D), "moe_tokens")
+    # f32 router at full precision: a pass in bf16 would move near-tied
+    # picks, and a changed pick changes the token's output wholesale
     logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                        p["router"]["w"])                        # f32 router
+                        p["router"]["w"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if active is None:
+        valid = jnp.ones((groups, Tl), bool)
+    else:
+        valid = jnp.broadcast_to(active[:, None], (B, S)).reshape(groups, Tl)
 
-    xe, st, sg, keep, slot = jax.vmap(
-        lambda xt, lg: _dispatch_one(xt, lg, E, K, C))(xg, logits)
+    def dispatch(xt, lg, v):
+        gates, idx = route(lg, mo)
+        return _dispatch_picks(xt, gates, idx, E, C, held,
+                               v if active is not None else None)
+
+    xe, st, sg, keep, slot = jax.vmap(dispatch)(xg, logits, valid)
     xe = constrain(xe, "moe_dispatch")                  # (G, E, C, D)
     observe_per_expert(obs, "expert_in", xe)
 
@@ -1146,7 +1244,7 @@ def moe_block(x: jax.Array, p: dict, cfg, obs: Optional[dict] = None,
     if "shared" in p:
         y = y + ffn_block(x, p["shared"], cfg, obs=obs,
                           prefix="shared_", backend=backend).reshape(T, D)
-    return y.reshape(B, S, D)
+    return y.reshape(B, S, D), jnp.sum(keep, dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------

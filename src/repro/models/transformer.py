@@ -228,12 +228,29 @@ def repack(params: dict, old_plan: tuple[Group, ...],
            new_plan: tuple[Group, ...],
            transform=None) -> dict:
     """Repack ``params`` from ``old_plan``'s grouping to ``new_plan``'s,
-    optionally applying ``transform(layer_idx, layer_params)`` per layer."""
-    layers = unpack_layers(params, old_plan)
-    if transform is not None:
-        layers = [transform(i, lp) for i, lp in enumerate(layers)]
+    optionally applying ``transform(layer_idx, layer_params)`` per layer.
+    The same as packing the transformed :func:`unpack_layers`, one stack
+    of the new plan at a time: only that stack's layers are sliced out at
+    once, so a model near the device's memory is not held twice over."""
+    where = {}                  # layer index -> (group, period slot, step)
+    for gi, g in enumerate(old_plan):
+        for s in range(g.steps):
+            for j in range(len(g.kinds)):
+                where[g.start + s * len(g.kinds) + j] = (gi, j, s)
+
+    def layer(i: int):
+        gi, j, s = where[i]
+        lp = jax.tree_util.tree_map(lambda a: a[s],
+                                    params["groups"][gi]["layers"][j])
+        return lp if transform is None else transform(i, lp)
+    groups = []
+    for g in new_plan:
+        groups.append({"layers": tuple(
+            _stack([layer(g.start + s * len(g.kinds) + j)
+                    for s in range(g.steps)])
+            for j in range(len(g.kinds)))})
     out = dict(params)
-    out["groups"] = pack_layers(layers, new_plan)
+    out["groups"] = groups
     return out
 
 
@@ -246,6 +263,8 @@ def layer_forward(x, lp, cfg: ArchConfig, kind: BlockKind, mode: LayerMode,
                   scheme: QuantScheme, *, positions, obs, cache, chunk,
                   constrain: Constrain, active=None, quant_bmm=None,
                   softmax=None, pages=None, backend=None):
+    """One layer; returns ``(x, new_cache, routed)``, ``routed`` the picks
+    its held experts computed (an int32 scalar; 0 outside MoE layers)."""
     quant = L.AttnQuant(enabled=(mode.quant_mha if quant_bmm is None
                                  else quant_bmm),
                         softmax_mode=scheme.softmax_mode,
@@ -256,6 +275,7 @@ def layer_forward(x, lp, cfg: ArchConfig, kind: BlockKind, mode: LayerMode,
         prefix_len=cfg.num_prefix_embeds if cfg.frontend == "vision" else 0)
     h = L.norm(x, lp["norm1"], cfg.norm_kind)
     new_cache = None
+    routed = 0
     if kind.body == "attn":
         if cfg.mla is not None:
             a, new_cache = L.mla_block(
@@ -273,8 +293,9 @@ def layer_forward(x, lp, cfg: ArchConfig, kind: BlockKind, mode: LayerMode,
                 a = a.dequantize()      # MoE residual keeps the float path
             x = constrain(x + a, "residual")
             h2 = L.norm(x, lp["norm2"], cfg.norm_kind)
-            f = L.moe_block(h2, lp["ffn"], cfg, obs=obs, constrain=constrain,
-                            backend=backend)
+            f, routed = L.moe_block(h2, lp["ffn"], cfg, obs=obs,
+                                    constrain=constrain, backend=backend,
+                                    active=active)
         else:
             # fused backends collapse add-residual + norm + requant into one
             # kernel when the ffn_in GEMM has a static int8 scale to feed
@@ -298,14 +319,16 @@ def layer_forward(x, lp, cfg: ArchConfig, kind: BlockKind, mode: LayerMode,
         a, new_cache = blk(h, lp["blk"], cfg, obs=obs, state=cache,
                            active=active)
         x = constrain(x + a, "residual")
-    return x, new_cache
+    return x, new_cache, routed
 
 
 def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
                scheme: QuantScheme, *, positions, obs=None, caches=None,
                chunk=DEFAULT_CHUNK, constrain: Constrain = _IDENTITY,
                remat: bool = False, active=None, pages=None, backend=None):
-    """Execute all layer groups. Returns (x, new_caches|None).
+    """Execute all layer groups. Returns (x, new_caches|None, routed):
+    ``routed`` sums the picks the layers' held experts computed (an int32
+    scalar, or 0 for a model without MoE layers).
 
     ``remat``: rematerialize each layer in the backward pass (activation
     checkpointing at layer-boundary granularity — the standard large-model
@@ -318,6 +341,7 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
     if obs is not None:
         backend = None
     new_caches = [] if caches is not None else None
+    routed = 0
     for gi, (g, gp) in enumerate(zip(plan, params["groups"])):
         gcache = caches[gi] if caches is not None else None
         unrolled = (obs is not None) or not g.scan
@@ -347,7 +371,8 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
                                 if obs.get("__values__") else {})
                     else:
                         lobs = None
-                    x, nc = make_lf(kind, g.mode, lobs)(x, lp, lcache)
+                    x, nc, r = make_lf(kind, g.mode, lobs)(x, lp, lcache)
+                    routed = routed + r
                     if obs is not None:
                         for site, v in lobs.pop("__raw__", {}).items():
                             obs.setdefault("__raw__", {})[
@@ -379,15 +404,18 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
                 {k: v for k, v in c.items() if k not in pl}
                 for c, pl in zip(gcache, pools))
 
-            def body(carry, xs, g=g, carried=carried, pools=pools):
+            moe = any(kind.moe for kind in g.kinds)
+
+            def body(carry, xs, g=g, carried=carried, pools=pools, moe=moe):
                 xc, pls = carry if carried else (carry, pools)
                 lps, lcs, layer = xs if carried else xs + (None,)
-                outs, new_pls = [], []
+                outs, new_pls, rs = [], [], 0
                 for j, kind in enumerate(g.kinds):
                     lc = None if lcs is None else lcs[j]
                     if pls[j] is not None:
                         lc = {**lc, **pls[j], L.POOL_LAYER: layer}
-                    xc, nc = make_lf(kind, g.mode, None)(xc, lps[j], lc)
+                    xc, nc, r = make_lf(kind, g.mode, None)(xc, lps[j], lc)
+                    rs = rs + r
                     if pls[j] is not None:
                         nc = {k: v for k, v in nc.items()
                               if k != L.POOL_LAYER}
@@ -396,6 +424,8 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
                         new_pls.append(None)
                     outs.append(nc)
                 ys = tuple(outs) if lcs is not None else None
+                if moe:             # each layer's routed picks, as ys
+                    ys = (ys, rs)
                 return ((xc, tuple(new_pls)) if carried else xc), ys
 
             if carried:
@@ -405,11 +435,14 @@ def run_groups(x, params, cfg: ArchConfig, plan: tuple[Group, ...],
             else:
                 # per-layer caches (or none: an empty xs subtree) as xs/ys
                 x, nc_stack = jax.lax.scan(body, x, (gp["layers"], rest))
+            if moe:
+                nc_stack, rs = nc_stack
+                routed = routed + jnp.sum(rs)
             if gcache is not None:
                 new_caches.append(tuple(
                     c if pl is None else {**c, **pl}
                     for c, pl in zip(nc_stack, pools)))
-    return x, new_caches
+    return x, new_caches, routed
 
 
 # ---------------------------------------------------------------------------
@@ -452,7 +485,7 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: tuple[Group, ...],
             chunk: Optional[int] = DEFAULT_CHUNK,
             constrain: Constrain = _IDENTITY, remat: bool = False,
             compute_dtype=jnp.bfloat16, return_hidden: bool = False,
-            pages=None, backend=None):
+            return_routed: bool = False, pages=None, backend=None):
     """Full-sequence (train/prefill) or incremental (decode) forward.
 
     decode: pass ``caches`` + ``pos``: an int scalar (synchronized batch) or
@@ -460,7 +493,8 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: tuple[Group, ...],
     ``active`` (B,) bool gating cache/state writes of idle slots).
     ``backend``: a ComputeBackend (repro.kernels.backend) selecting the
     reference XLA or fused Pallas execution per quantized block.
-    Returns (logits, new_caches).
+    Returns (logits, new_caches), and the picks the held experts computed
+    over all layers (an int32 scalar) third when ``return_routed``.
     """
     if cfg.frontend == "audio":
         S = batch["frames"].shape[1]
@@ -477,15 +511,16 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: tuple[Group, ...],
                      compute_dtype=compute_dtype,
                      backend=None if obs is not None else backend)
     x = constrain(x, "activation")
-    x, new_caches = run_groups(x, params, cfg, plan, scheme,
-                               positions=positions, obs=obs, caches=caches,
-                               chunk=chunk, constrain=constrain, remat=remat,
-                               active=active, pages=pages, backend=backend)
+    x, new_caches, routed = run_groups(
+        x, params, cfg, plan, scheme, positions=positions, obs=obs,
+        caches=caches, chunk=chunk, constrain=constrain, remat=remat,
+        active=active, pages=pages, backend=backend)
     x = L.norm(x, params["final_norm"], cfg.norm_kind)
+    extra = (jnp.asarray(routed, jnp.int32),) if return_routed else ()
     if return_hidden or "head" in params:
-        return x, new_caches
+        return (x, new_caches) + extra
     logits = constrain(unembed(x, params, cfg), "logits")
-    return logits, new_caches
+    return (logits, new_caches) + extra
 
 
 # ---------------------------------------------------------------------------
@@ -560,6 +595,13 @@ def _layer_cache(cfg: ArchConfig, kind: BlockKind, batch: int, max_len: int,
             # Local layers keep the dense ring: it is already W-bounded.
             ps, NP = page_size, num_pages
             if cfg.mla is not None:
+                if kv_scheme != "float":
+                    # no kernel reads quantized latent pages yet: refuse,
+                    # rather than serve float pages under an int8 name
+                    raise ValueError(
+                        f"{cfg.name}: MLA layers page their latent KV in "
+                        f"float only; kv_cache={kv_scheme!r} is not "
+                        f"supported (use kv_cache='float')")
                 m = cfg.mla
                 return {"pages_ckv": jnp.zeros((NP, ps, m.kv_lora_rank),
                                                dtype),
@@ -677,13 +719,17 @@ def kv_geometry(caches) -> tuple:
 def decode_step(params, tokens, caches, pos, cfg: ArchConfig, plan,
                 scheme: QuantScheme = QuantScheme(), *, active=None,
                 constrain: Constrain = _IDENTITY,
-                compute_dtype=jnp.bfloat16, pages=None, backend=None):
+                compute_dtype=jnp.bfloat16, pages=None, backend=None,
+                return_routed: bool = False):
     """One serving step: tokens (B, 1) at absolute position(s) ``pos``
     (scalar = synchronized batch; (B,) vector = continuous batching, with
-    ``active`` gating idle slots). ``pages`` is the scheduler's
-    (B, pages_per_slot) page table when the caches are paged.
-    Returns (logits (B, 1, V), new_caches)."""
+    ``active`` gating idle slots: their tokens route to no expert).
+    ``pages`` is the scheduler's (B, pages_per_slot) page table when the
+    caches are paged. Returns (logits (B, 1, V), new_caches), and the
+    active tokens' picks the held experts computed third when
+    ``return_routed``."""
     return forward(params, {"tokens": tokens}, cfg, plan, scheme,
                    caches=caches, pos=pos, active=active, chunk=None,
                    constrain=constrain, compute_dtype=compute_dtype,
-                   pages=pages, backend=backend)
+                   pages=pages, backend=backend,
+                   return_routed=return_routed)

@@ -277,10 +277,15 @@ class ServeEngine:
                 pages = (jnp.asarray(self.pool.table)
                          if self.pool is not None else None)
                 decode, step_params = self._executable()
-            logits, self.caches = decode(
+            # an MoE step returns its routed picks third: fetched with the
+            # logits, in the same copy
+            logits, self.caches, *routed = decode(
                 step_params, self.caches, tokens, pos, active, pages)
             with phase("samp.dec.fetch"):
-                logits = np.asarray(jax.device_get(logits), np.float32)
+                logits, *routed = jax.device_get((logits, *routed))
+                logits = np.asarray(logits, np.float32)
+            if routed:
+                self.runtime.count_routed(int(routed[0]), self.slots)
             self._stats["ticks"] += 1
             self._stats["tokens"] += len(live)
             with phase("samp.dec.sample"):

@@ -34,9 +34,18 @@ serving path's :class:`~repro.serve.metrics.Phases` table: its own
 ``samp.enc.pad`` / ``dispatch`` / ``fetch`` and ``samp.dec.dispatch``
 spans, and the engines' phases around them.
 
-MoE configs are the one exception to bucketing: expert capacity is derived
-from the token count, so padding would change routing for real rows. They
-run at natural shapes (still cached per shape, still counted).
+Capacity-bounded MoE configs are the one exception to bucketing: expert
+capacity is derived from the token count, so padding would change routing
+for real rows. They run at natural shapes (still cached per shape, still
+counted). Dropless MoE configs bucket like dense ones: every pick is
+computed, so a real row's output does not depend on the other rows.
+
+**MoE counters.** For an MoE config the decode step returns a third
+output, the picks the held experts computed for the tick's active tokens
+(summed over layers); the engine fetches it with the logits and hands it
+to :meth:`count_routed`, which adds it to ``stats["moe_routed_rows"]``
+and the rows the expert GEMMs ran (held experts x buffer rows x MoE
+layers) to ``stats["moe_expert_rows"]``.
 
 **Mesh-aware serving.** A Runtime bound to a ``mesh=`` (a ``jax.sharding``
 Mesh with ``data``/``model`` axes) places every executable over that mesh:
@@ -173,8 +182,9 @@ class Runtime:
         self.rules = Rules(cfg, mesh, fsdp=False) if mesh is not None \
             else None
         # MoE expert capacity scales with the token count: padded tokens
-        # would consume capacity and change routing for real rows.
-        self.bucketed = cfg.moe is None
+        # would consume capacity and change routing for real rows (not so
+        # when dropless: every pick is computed).
+        self.bucketed = cfg.moe is None or cfg.moe.capacity_factor is None
         # On a mesh that splits only the batch (model axis 1), every
         # executable runs its whole step once per device (shard_map): each
         # device runs the unmeshed program on its rows, so meshed results
@@ -211,6 +221,8 @@ class Runtime:
         # :meth:`executables` can hand back the compiled programs
         self._arg_shapes: dict[tuple, tuple] = {}
         self._stats = {"traces": 0, "real_tokens": 0, "padded_tokens": 0}
+        if cfg.moe is not None:
+            self._stats.update(moe_routed_rows=0, moe_expert_rows=0)
         self.phases = Phases()
         # cache trees this runtime may donate: id of the tree's first leaf
         # -> a weak reference to that leaf
@@ -358,9 +370,9 @@ class Runtime:
             x = T.embed_inputs(params, inputs, cfg,
                                positions=jnp.maximum(positions, 0),
                                compute_dtype=compute_dtype, backend=backend)
-            x, _ = T.run_groups(x, params, cfg, plan, scheme,
-                                positions=positions, chunk=chunk,
-                                backend=backend, **constrain_kw)
+            x, _, _ = T.run_groups(x, params, cfg, plan, scheme,
+                                   positions=positions, chunk=chunk,
+                                   backend=backend, **constrain_kw)
             x = L.norm(x, params["final_norm"], cfg.norm_kind)
             return head(params, x) if head is not None else x
         if not self.per_device:
@@ -453,12 +465,15 @@ class Runtime:
         constrain_kw = {} if self.rules is None or self.per_device else \
             {"constrain": self.rules}
 
+        moe = cfg.moe is not None
+
         def step(params, caches, tokens, pos, active, pages):
-            logits, caches = T.decode_step(
+            # an MoE step also returns the picks its held experts computed
+            logits, caches, *routed = T.decode_step(
                 params, tokens, caches, pos, cfg, plan, scheme,
                 active=active, compute_dtype=compute_dtype, pages=pages,
-                backend=backend, **constrain_kw)
-            return logits[:, -1, :], caches
+                backend=backend, return_routed=moe, **constrain_kw)
+            return (logits[:, -1, :], caches, *routed)
 
         def fn(params, caches, tokens, pos, active, pages):
             self._stats["traces"] += 1          # trace-time side effect
@@ -475,11 +490,13 @@ class Runtime:
                     n = T.kv_geometry(caches)[2]
                     base = jax.lax.axis_index(dp) * n
                     pages = jnp.where(pages >= 0, pages - base, -1)
-                return step(params, caches, tokens, pos, active, pages)
+                out = step(params, caches, tokens, pos, active, pages)
+                # every device's routed picks, summed
+                return out[:2] + tuple(jax.lax.psum(r, dp) for r in out[2:])
             return jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), P(None, dp)) + (P(dp),) * len(rows),
-                out_specs=(P(dp), P(None, dp)),
+                out_specs=(P(dp), P(None, dp)) + ((P(),) if moe else ()),
                 check_vma=False)(params, caches, *rows)
         return fn
 
@@ -493,6 +510,9 @@ class Runtime:
         axes instead."""
         from jax.sharding import PartitionSpec
         r = self.rules
+        # an MoE step's routed count is one replicated scalar
+        routed = ((self._sharding(PartitionSpec()),)
+                  if self.cfg.moe is not None else ())
         if self.per_device:
             rows = self._sharding(PartitionSpec(r.axes.dp))
             caches_sh = jax.tree_util.tree_map(
@@ -500,12 +520,12 @@ class Runtime:
                 caches)
             in_s = (r.params_sharding(params), caches_sh, rows, rows, rows,
                     rows)
-            return in_s, (rows, caches_sh)
+            return in_s, (rows, caches_sh) + routed
         caches_sh = jax.tree_util.tree_map(
             self._sharding, r.cache_spec(caches),
             is_leaf=lambda x: isinstance(x, PartitionSpec))
         in_s = (r.params_sharding(params), caches_sh, None, None, None, None)
-        return in_s, (None, caches_sh)
+        return in_s, (None, caches_sh) + routed
 
     # -- cache ownership -----------------------------------------------------
     def _owns(self, caches) -> bool:
@@ -562,7 +582,9 @@ class Runtime:
         the per-tick hot path: no signature hashing per token; its
         ``pages`` operand is the scheduler's page table (None for dense
         caches). It donates the caches it is given when this runtime
-        produced them, and a copy of them otherwise (module docstring)."""
+        produced them, and a copy of them otherwise (module docstring).
+        It returns (logits, caches), and an MoE step the tick's routed
+        picks third (module docstring)."""
         key, fn = self._decode_executable(params, caches)
 
         def step(params, caches, tokens, pos, active, pages=None):
@@ -574,10 +596,28 @@ class Runtime:
                         None if pages is None else jnp.asarray(pages))
                 if key not in self._arg_shapes:
                     self._note_args(key, args)
-                logits, caches = fn(*args)
+                logits, caches, *routed = fn(*args)
                 self._own(caches)
-                return logits, caches
+                return (logits, caches, *routed)
         return step
+
+    def count_routed(self, routed: int, slots: int) -> None:
+        """Add one MoE decode tick over ``slots`` slots to the counters:
+        ``routed`` picks its held experts computed (the step's third
+        output), and the rows its expert GEMMs ran: every held expert's
+        buffer, in every MoE layer, on every token group."""
+        mo = self.cfg.moe
+        if self.per_device:
+            groups = self.shards
+        else:
+            groups = getattr(self.rules, "dsize", 1) if self.rules else 1
+            if slots % groups:
+                groups = 1
+        layers = sum(kind.moe for kind in self.cfg.layer_kinds())
+        self._stats["moe_routed_rows"] += routed
+        self._stats["moe_expert_rows"] += (
+            groups * mo.held * L.moe_capacity(mo, slots // groups)
+            * layers)
 
     @staticmethod
     def _decode_batch(caches) -> int:

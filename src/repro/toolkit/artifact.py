@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import store
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
+from repro.configs.base import (ArchConfig, MLAConfig, MoEConfig,
+                                RopeScaling)
 from repro.core.plan import PrecisionPlan, as_plan, plan_from_policy
 from repro.core.precision import EncoderPolicy, LayerMode
 from repro.data.pipeline import TaskSpec
@@ -124,6 +125,8 @@ def _cfg_from_dict(d: dict) -> ArchConfig:
         d["moe"] = MoEConfig(**d["moe"])
     if d.get("mla"):
         d["mla"] = MLAConfig(**d["mla"])
+    if d.get("rope_scaling"):
+        d["rope_scaling"] = RopeScaling(**d["rope_scaling"])
     d["pattern"] = tuple(d["pattern"])
     return ArchConfig(**d)
 

@@ -98,8 +98,8 @@ class EncoderStage:
         self.backend = backend
 
     def __call__(self, params: dict, x: jax.Array, *, positions) -> jax.Array:
-        x, _ = T.run_groups(x, params, self.cfg, self.plan, self.scheme,
-                            positions=positions, backend=self.backend)
+        x, _, _ = T.run_groups(x, params, self.cfg, self.plan, self.scheme,
+                               positions=positions, backend=self.backend)
         return L.norm(x, params["final_norm"], self.cfg.norm_kind)
 
 

@@ -100,7 +100,7 @@ def test_calibrate_and_apply(arch):
             if hasattr(v, "dtype") and v.dtype == jnp.int8]
     assert int8, f"{arch}: no int8 leaves after apply_plan"
     if cfg.moe is not None:
-        E = cfg.moe.num_experts
+        E = cfg.moe.held         # stacks hold the layer's held experts
         expert_scales = [
             (p, v) for p, v in leaves
             if "ffn" in jax.tree_util.keystr(p)

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_config
+from repro.configs import MoEConfig, get_config
 from repro.core.calibration import synthetic_calibration_batches
 from repro.core.plan import LayerPlan, PrecisionPlan, QuantSpec
 from repro.core.samp import SAMPEngine, moe_family_variant
@@ -38,7 +38,11 @@ class FakeMesh:
 
 
 def _dispatch(xt, logits, E, K, C):
-    return L._dispatch_one(xt, logits, E, K, C)
+    """Capacity dispatch of one token group under the top-k-then-softmax
+    router."""
+    gates, idx = L.route(logits, MoEConfig(num_experts=E, top_k=K,
+                                           d_ff_expert=1))
+    return L._dispatch_picks(xt, gates, idx, E, C)
 
 
 # ---------------------------------------------------------------------------
@@ -75,6 +79,36 @@ def test_capacity_overflow_drops_tokens_gates_intact():
         np.testing.assert_allclose(y[t],
                                    kept_gates.sum() * np.asarray(xt[t]),
                                    rtol=1e-5, atol=1e-6)
+
+
+def test_inactive_slots_take_no_capacity_in_a_mixtral_decode():
+    """A capacity-bound Mixtral decode step at 64 slots, the last 4 live:
+    each live row gets every one of its picks, whatever the 60 inactive
+    slots hold, because an inactive slot's token routes nowhere. Without
+    ``active``, inactive rows that copy the live tokens sort ahead of them
+    into the same experts' C = 40 rows and drop the live rows' picks."""
+    cfg = get_config("mixtral-8x22b").reduced()
+    assert cfg.moe.capacity_factor is not None
+    B, D = 64, cfg.d_model
+    p = L.init_moe(KEY, cfg)
+    live = jax.random.normal(jax.random.PRNGKey(1), (4, 1, D))
+    active = jnp.arange(B) >= B - 4
+    dropless = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                                   capacity_factor=None))
+    want, _ = L.moe_block(live, p, dropless)         # every pick computed
+    copies = jnp.tile(live, (15, 1, 1))
+    for junk in (jnp.zeros((B - 4, 1, D)), copies,
+                 100.0 * jax.random.normal(jax.random.PRNGKey(2),
+                                           (B - 4, 1, D))):
+        x = jnp.concatenate([junk, live])
+        y, rows = L.moe_block(x, p, cfg, active=active)
+        np.testing.assert_allclose(np.asarray(y[B - 4:]), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+        assert int(rows) == 4 * cfg.moe.top_k
+    y, rows = L.moe_block(jnp.concatenate([copies, live]), p, cfg)
+    assert int(rows) < B * cfg.moe.top_k
+    assert not np.allclose(np.asarray(y[B - 4:]), np.asarray(want),
+                           rtol=1e-5, atol=1e-6)
 
 
 def test_zero_padding_in_capacity_buffer():
@@ -206,6 +240,30 @@ def test_quant_expert_gemm_matches_reference_einsum():
         assert got.shape == ref.shape
         err = float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
         assert err < 0.1          # int8 quantization error bound
+
+
+@pytest.mark.parametrize("lead", [(), (1,), (2,)])
+@pytest.mark.parametrize("scales", ["per_expert", "scalar", "dynamic"])
+def test_grouped_expert_kernel_matches_the_per_expert_einsum(lead, scales):
+    """The grouped ``quant_expert_gemm`` kernel (one call, its grid over the
+    experts) against the reference path's per-expert int8 einsum on the
+    same int8 codes: int32 accumulation is exact, so only the float
+    epilogue's rounding may differ."""
+    E, C, D, F = 3, 8, 48, 40
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    xe = jax.random.normal(k1, lead + (E, C, D))
+    wq = ptq.quantize_weight(jax.random.normal(k2, (E, D, F)),
+                             "int8_per_channel")
+    amax = jnp.abs(xe).max(axis=tuple(i for i in range(xe.ndim)
+                                      if i != xe.ndim - 3))
+    xs = {"per_expert": (amax / 127.0).reshape(E, 1, 1),
+          "scalar": jnp.abs(xe).max() / 127.0, "dynamic": None}[scales]
+    got = ops.quant_expert_gemm(xe, wq.values, wq.scale, xs)
+    want = L._expert_gemm(xe, wq, xs, None, "ffn_in_e")
+    assert got.shape == want.shape == lead + (E, C, F)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6 * float(
+                                   jnp.abs(want).max()))
 
 
 def test_expert_scale_leaves_shard_on_expert_axis():

@@ -35,6 +35,9 @@ BERT = dict(M=4096, D=768, F=3072, V=21128, P=512, B=8, H=12, S=512, hd=64)
 QWEN = dict(D=896, F=4864, B=4, Hkv=2, g=7, hd=64, ps=16, pps=32)
 # the chat-decode cell's pool: 32 slots of 768 tokens over 16-token pages
 CHAT = dict(B=32, max_len=768, ps=16, NP=32 * 48)
+# deepseek-v2-lite-ep4: 16 held experts of width 1408 over d_model 2048; the
+# moe-chat cell's 64 slots of 768 tokens
+DSV2 = dict(E=16, D=2048, F=1408, B=64, max_len=768, ps=16)
 
 
 @pytest.fixture(scope="module")
@@ -292,3 +295,70 @@ def test_decode_attention_on_a_lane_padded_stack(one_chip, per_head):
         ((B, Hkv, g, hd), F32), ((24, NP, Hkv, ps, 128), I8),
         ((24, NP, Hkv, ps, 128), I8), ((B, pps), I32), ((B,), I32),
         (scale_shape, F32), (scale_shape, F32), ((), I32))
+
+
+@pytest.mark.parametrize("gemm", ["gate", "down"])
+def test_quant_expert_gemm(one_chip, gemm):
+    """The grouped expert kernel over the 16 held experts' 64-row buffers,
+    in both GEMM orientations of a SwiGLU expert."""
+    E, M = DSV2["E"], DSV2["B"]
+    K, N = ((DSV2["D"], DSV2["F"]) if gemm == "gate"
+            else (DSV2["F"], DSV2["D"]))
+    _assert_kernel(
+        one_chip,
+        lambda x, w, ws, xs: ql.quant_expert_gemm(x, w, ws, xs,
+                                                  interpret=False),
+        ((E, M, K), I8), ((E, K, N), I8), ((E, N), F32), ((E, M, 1), F32))
+
+
+def test_deepseek_v2_lite_decode_step(one_chip, monkeypatch):
+    """The serving runtime's decode step of ``deepseek-v2-lite-ep4`` at its
+    published widths, cut to the dense layer and two MoE layers, on the
+    moe-chat cell's 64 slots (ffn plan, fused backend, float latent
+    pages): every expert GEMM is the grouped kernel, and the step returns
+    the routed-pick count beside the logits and the caches."""
+    from repro.configs import get_config
+    from repro.core.plan import plan_from_policy
+    from repro.core.precision import EncoderPolicy, make_policy
+    from repro.kernels import ops
+    from repro.launch.dryrun import abstract_stats
+    from repro.models import transformer as T
+    from repro.quant import ptq
+    from repro.serve.runtime import Runtime
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("deepseek-v2-lite-ep4").replace(num_layers=3)
+    precision = plan_from_policy(make_policy(cfg, "ffn"))
+
+    def params_fn():
+        params = T.init_params(jax.random.PRNGKey(0), cfg,
+                               EncoderPolicy.full_float(cfg.num_layers,
+                                                        "float32"))
+        return ptq.apply_plan(params, cfg, precision,
+                              abstract_stats(cfg))[0]
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+    plan = T.build_plan(cfg, precision)
+    rt = Runtime(cfg, plan, precision=precision, backend="fused",
+                 compute_dtype=F32)
+    B, max_len, ps = DSV2["B"], DSV2["max_len"], DSV2["ps"]
+    caches = placed(jax.eval_shape(lambda: T.init_caches(
+        cfg, plan, B, max_len, F32, page_size=ps,
+        kv_schemes=("float",) * cfg.num_layers,
+        lanes=rt.backend.page_lanes())))
+    _, step = rt._decode_executable(placed(jax.eval_shape(params_fn)),
+                                    caches)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (((B, 1), I32), ((B,), I32), ((B,), jnp.bool_),
+             ((B, max_len // ps), I32))]
+    lowered = step.lower(placed(jax.eval_shape(params_fn)), caches, *args)
+    logits, _, routed = lowered.out_info
+    assert logits.shape == (B, cfg.vocab_size) and routed.shape == ()
+    text = lowered.compile().as_text()
+    experts = [line for line in text.splitlines()
+               if "%quant_expert_gemm" in line and "custom-call(" in line]
+    # the gate, up and down GEMMs of the scanned MoE layer's body
+    assert len(experts) == 3
+    assert all("tpu_custom_call" in line for line in experts)
