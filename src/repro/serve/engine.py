@@ -161,7 +161,8 @@ class ServeEngine:
         self._decode_by_cluster: dict[int, object] = {}
         self.rng = np.random.default_rng(seed)
         self._stats = {"steps": 0, "ticks": 0, "tokens": 0, "retired": 0,
-                       "stalls": 0, "preemptions": 0, "requests": 0}
+                       "stalls": 0, "preemptions": 0, "requests": 0,
+                       "attn_pages_live": 0, "attn_pages_table": 0}
         # set when a deadlock preemption proves the pool cannot hold the
         # current working set: admission pauses until pages are freed, so
         # preempted requests don't thrash straight back into a slot
@@ -274,8 +275,10 @@ class ServeEngine:
                                     else req.output[c - len(req.prompt)])
                     pos[s] = c
                     active[s] = True
-                pages = (jnp.asarray(self.pool.table)
-                         if self.pool is not None else None)
+                pages = None
+                if self.pool is not None:
+                    pages = jnp.asarray(self.pool.table)
+                    self._count_attn_pages(pos, active)
                 decode, step_params = self._executable()
             # an MoE step returns its routed picks third: fetched with the
             # logits, in the same copy
@@ -324,6 +327,16 @@ class ServeEngine:
             live.remove(victim)
             stalled = [s for s in live if not self.pool.ensure(s, need(s))]
         return [s for s in live if s not in stalled]
+
+    def _count_attn_pages(self, pos: np.ndarray, active: np.ndarray) -> None:
+        """Add this tick's page-table entries the paged attention reads,
+        per layer — each active slot's allocated pages up to its length,
+        this tick's token included — and the table's size."""
+        table = self.pool.table
+        need = np.where(active, -(-(pos + 1) // self.pool.page_size), 0)
+        read = np.arange(table.shape[1]) < need[:, None]
+        self._stats["attn_pages_live"] += int((read & (table >= 0)).sum())
+        self._stats["attn_pages_table"] += table.size
 
     def _executable(self):
         """(decode step, params) of this tick."""

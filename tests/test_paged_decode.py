@@ -41,8 +41,12 @@ GOLDEN = "tests/data/golden_plan.json"
 
 
 def _reference_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                                k_scale, v_scale, per_head, scale, softcap):
-    """Dense numpy reference for the paged kernel's contract."""
+                                k_scale, v_scale, per_head, scale, softcap,
+                                p_scale=None):
+    """Dense numpy reference for the paged kernel's contract: token-major
+    ``(NP, ps, Hkv, hd)`` pages, a slot's tokens in table order, ``-1``
+    entries and tokens past the length left out, and under ``p_scale``
+    the final probabilities QDQ'd to unsigned-int8 codes."""
     B, Hkv, g, hd = q.shape
     NP, ps, _, _ = k_pages.shape
     out = np.zeros((B, Hkv, g, hd), np.float32)
@@ -76,79 +80,99 @@ def _reference_decode_attention(q, k_pages, v_pages, page_table, lengths,
                 s = np.tanh(s / softcap) * softcap
             p = np.exp(s - s.max(axis=1, keepdims=True))
             p /= p.sum(axis=1, keepdims=True)
+            if p_scale is not None:
+                p = np.clip(np.round(p / p_scale), 0, 255) * p_scale
             out[b, h] = p @ v[:, h]
     return out
 
 
-def _head_major(*arrays):
-    """Token-major (NP, ps, Hkv, ...) test pages -> the kernel's head-major
-    (NP, Hkv, ps, ...) layout."""
-    return [jnp.asarray(np.swapaxes(a, 1, 2)) for a in arrays]
+#: pool geometry per case: page size, table width, the slots' lengths and
+#: the (slot, entry) table holes inside a slot's live range, ``held`` pages
+#: a slot of length 0 still owns; ``stack`` pools
+#: the layers the kernel reads layer 1 of, ``lanes`` pads the minor dims as
+#: the chip's pools are. The walk's block is 3 pages for "small" (the whole
+#: row), 4 for "ragged" (6 pages: the last block half full) and 8 for
+#: "cell" (the chat-decode cell's 48-page rows of 16 tokens, 6 blocks).
+KERNEL_SHAPES = {
+    "small": dict(ps=4, pps=3, lengths=[5, 12, 1, 0], holes=[], held={3: 2},
+                  stack=1, lanes=1),
+    # lengths 0, 1, a page, a page + 1, the block edge -1/0/+1, full
+    "ragged": dict(ps=32, pps=6, lengths=[0, 1, 32, 33, 127, 128, 129, 192],
+                   holes=[(6, 1)], held={}, stack=1, lanes=1),
+    "cell": dict(ps=16, pps=48, lengths=[1, 0, 16, 17, 127, 128, 129, 768],
+                 holes=[(7, 40)], held={1: 5}, stack=2, lanes=128),
+}
+KERNEL_MODES = {
+    "per_token": dict(per_head=False),
+    "per_head": dict(per_head=True),
+    "uint8_softmax": dict(per_head=False, p_scale=1.0 / 255),
+    "softcap": dict(per_head=False, softcap=30.0),
+}
 
 
-def _make_paged_case(rng, *, B=3, Hkv=2, g=2, hd=8, ps=4, pps=3):
+@pytest.mark.parametrize("mode", sorted(KERNEL_MODES))
+@pytest.mark.parametrize("shape", sorted(KERNEL_SHAPES))
+def test_kernel_matches_reference(shape, mode):
+    """The paged kernel (interpret mode) against the numpy reference, per
+    scale scheme, softcap and uint8 softmax, on tables whose live range
+    ends inside a block and a page, is a whole block, or spans every
+    entry; a slot of length 0 reads zeros. Pages a slot does not own hold
+    random rows, lane padding of the scale pages holds NaN, and the other
+    layer of a stacked pool holds different rows: none of it may reach
+    the output."""
+    c, m = KERNEL_SHAPES[shape], KERNEL_MODES[mode]
+    ps, pps, lengths = c["ps"], c["pps"], np.asarray(c["lengths"], np.int32)
+    B, Hkv, g, hd = len(lengths), 2, 2, 8
     NP = B * pps
+    rng = np.random.default_rng(sorted(KERNEL_SHAPES).index(shape))
     q = rng.standard_normal((B, Hkv, g, hd)).astype(np.float32)
-    k = rng.integers(-127, 128, (NP, ps, Hkv, hd)).astype(np.int8)
-    v = rng.integers(-127, 128, (NP, ps, Hkv, hd)).astype(np.int8)
-    ks = rng.uniform(0.01, 0.05, (NP, ps, Hkv)).astype(np.float32)
-    vs = rng.uniform(0.01, 0.05, (NP, ps, Hkv)).astype(np.float32)
+    pool = lambda: rng.integers(                     # noqa: E731
+        -127, 128, (c["stack"], NP, ps, Hkv, hd)).astype(np.int8)
+    k, v = pool(), pool()
+    if m["per_head"]:
+        ks = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
+    else:
+        ks, vs = (rng.uniform(0.01, 0.05, (c["stack"], NP, ps, Hkv))
+                  .astype(np.float32) for _ in range(2))
     # slot b owns pages [b*pps ...), allocated as far as its length needs
-    lengths = np.array([5, ps * pps, 1][:B], np.int32)
     pt = -np.ones((B, pps), np.int32)
     for b in range(B):
-        for j in range(-(-int(lengths[b]) // ps)):
+        for j in range(c["held"].get(b, -(-int(lengths[b]) // ps))):
             pt[b, j] = b * pps + j
-    return q, k, v, ks, vs, pt, lengths
+    for b, j in c["holes"]:
+        pt[b, j] = -1
+    layer = c["stack"] - 1
 
-
-@pytest.mark.parametrize("softcap", [None, 30.0])
-def test_kernel_matches_reference_per_token(softcap):
-    rng = np.random.default_rng(0)
-    q, k, v, ks, vs, pt, lengths = _make_paged_case(rng)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    kh, vh, ksh, vsh = _head_major(k, v, ks, vs)
-    got = ops.decode_attention(
-        jnp.asarray(q), kh, vh, jnp.asarray(pt),
-        jnp.asarray(lengths), k_scale=ksh,
-        v_scale=vsh, per_head=False, scale=float(scale),
-        softcap=softcap)
-    want = _reference_decode_attention(q, k, v, pt, lengths, ks, vs,
-                                       per_head=False, scale=scale,
-                                       softcap=softcap)
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
-
-
-def test_kernel_matches_reference_per_head():
-    rng = np.random.default_rng(1)
-    q, k, v, _, _, pt, lengths = _make_paged_case(rng)
-    Hkv = q.shape[1]
-    ks = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
-    vs = rng.uniform(0.01, 0.05, (Hkv,)).astype(np.float32)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    kh, vh = _head_major(k, v)
-    got = ops.decode_attention(
-        jnp.asarray(q), kh, vh, jnp.asarray(pt),
-        jnp.asarray(lengths), k_scale=jnp.asarray(ks),
-        v_scale=jnp.asarray(vs), per_head=True, scale=float(scale))
-    want = _reference_decode_attention(q, k, v, pt, lengths, ks, vs,
-                                       per_head=True, scale=scale,
-                                       softcap=None)
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
-
-
-def test_kernel_inactive_slot_outputs_zero():
-    rng = np.random.default_rng(2)
-    q, k, v, ks, vs, pt, lengths = _make_paged_case(rng)
-    lengths = lengths.copy()
-    lengths[1] = 0                     # masked slot, pages still allocated
-    kh, vh, ksh, vsh = _head_major(k, v, ks, vs)
-    got = ops.decode_attention(
-        jnp.asarray(q), kh, vh, jnp.asarray(pt),
-        jnp.asarray(lengths), k_scale=ksh,
-        v_scale=vsh, per_head=False, scale=0.25)
-    assert np.all(np.asarray(got)[1] == 0.0)
-    assert np.any(np.asarray(got)[0] != 0.0)
+    def padded(a, lanes):                # head-major, minor dim padded
+        a = np.swapaxes(a, 2, 3)
+        pad = -(-a.shape[-1] // lanes) * lanes - a.shape[-1]
+        return np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)],
+                      constant_values=np.nan if a.dtype == np.float32
+                      else 0)
+    kh, vh = (jnp.asarray(padded(a, c["lanes"])) for a in (k, v))
+    if m["per_head"]:
+        ksh, vsh = jnp.asarray(ks), jnp.asarray(vs)
+    else:
+        ksh, vsh = (jnp.asarray(padded(a, c["lanes"])) for a in (ks, vs))
+    if c["stack"] == 1:                  # one layer's pool, no layer operand
+        kh, vh = kh[0], vh[0]
+        if not m["per_head"]:
+            ksh, vsh = ksh[0], vsh[0]
+    scale = 1.0 / np.sqrt(hd)
+    got = np.asarray(ops.decode_attention(
+        jnp.asarray(q), kh, vh, jnp.asarray(pt), jnp.asarray(lengths),
+        k_scale=ksh, v_scale=vsh, per_head=m["per_head"], scale=float(scale),
+        softcap=m.get("softcap"), p_scale=m.get("p_scale"),
+        layer=layer if c["stack"] > 1 else None))
+    want = _reference_decode_attention(
+        q, k[layer], v[layer], pt, lengths,
+        ks if m["per_head"] else ks[layer], vs if m["per_head"] else vs[layer],
+        per_head=m["per_head"], scale=scale, softcap=m.get("softcap"),
+        p_scale=m.get("p_scale"))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert np.all(got[lengths == 0] == 0.0)
+    assert np.all(np.any(got[lengths > 0] != 0.0, axis=(1, 2, 3)))
 
 
 # ---------------------------------------------------------------------------
@@ -524,6 +548,29 @@ def test_runtime_decode_leaves_the_callers_caches(qwen_float):
     first, _ = eng.runtime.decode(params, eng.caches, *args)
     again, _ = eng.runtime.decode(params, eng.caches, *args)
     np.testing.assert_array_equal(np.asarray(first), np.asarray(again))
+
+
+def test_attention_page_counters_match_a_hand_count(qwen_float):
+    """``attn_pages_live`` sums, over ticks, the table entries each active
+    slot reads (its pages up to its length, this tick's token included);
+    ``attn_pages_table`` the table's size. Two slots of four 4-token pages:
+    A (3 + 4 tokens) runs ticks 1-6 at lengths 1-6, B (2 + 2) ticks 1-3
+    at 1-3, and C (5 + 1), queued behind them, takes B's slot and pages
+    for ticks 4-8 at lengths 1-5."""
+    cfg, params, plan = qwen_float
+    eng = ServeEngine(cfg, params, plan, batch_slots=2, max_len=16,
+                      page_size=4)
+    for uid, (n, out) in enumerate([(3, 4), (2, 2), (5, 1)]):
+        eng.submit(Request(uid=uid, prompt=list(range(1, n + 1)),
+                           max_tokens=out))
+    done = eng.run()
+    assert sorted(len(r.output) for r in done) == [1, 2, 4]
+    pages = lambda *lens: sum(-(-n // 4) for n in lens)   # noqa: E731
+    live = (pages(1, 1) + pages(2, 2) + pages(3, 3) + pages(4, 1)
+            + pages(5, 2) + pages(6, 3) + pages(4) + pages(5))
+    assert eng.stats["ticks"] == 8
+    assert eng.stats["attn_pages_live"] == live == 17
+    assert eng.stats["attn_pages_table"] == 8 * 2 * 4
 
 
 def test_single_oversized_request_raises(qwen_float):

@@ -30,9 +30,8 @@ from repro.kernels import quant_linear as ql
 # bert-base: d_model 768, 12 heads of 64, ffn 3072, vocab 21128, 512
 # positions, 2 segments; a batch of 8 x 512 tokens
 BERT = dict(M=4096, D=768, F=3072, V=21128, P=512, B=8, H=12, S=512, hd=64)
-# qwen2-0.5b: d_model 896, ffn 4864, 14 query / 2 kv heads of 64; 4 decode
-# slots over 16-token pages, 512 tokens per slot
-QWEN = dict(D=896, F=4864, B=4, Hkv=2, g=7, hd=64, ps=16, pps=32)
+# qwen2-0.5b: d_model 896, ffn 4864, 14 query / 2 kv heads of 64
+QWEN = dict(D=896, F=4864, Hkv=2, g=7, hd=64)
 # the chat-decode cell's pool: 32 slots of 768 tokens over 16-token pages
 CHAT = dict(B=32, max_len=768, ps=16, NP=32 * 48)
 # deepseek-v2-lite-ep4: 16 held experts of width 1408 over d_model 2048; the
@@ -165,22 +164,34 @@ def test_quant_flash_attention_batched(one_chip, requant):
         (shape, I8), (shape, I8), (shape, I8), ((B, S), I32), ((), F32))
 
 
-@pytest.mark.parametrize("mode", ["per_head", "per_token", "per_head_p_scale"])
-def test_decode_attention(one_chip, mode):
-    B, Hkv, g, hd = QWEN["B"], QWEN["Hkv"], QWEN["g"], QWEN["hd"]
-    ps, pps = QWEN["ps"], QWEN["pps"]
-    NP = B * pps
-    per_head = mode != "per_token"
-    scale_shape = (Hkv,) if per_head else (NP, Hkv, ps)
-    quant_p = mode == "per_head_p_scale"
+DECODE_MODES = ["per_head", "per_token", "per_head_p_scale"]
+
+
+def _assert_decode_attention(one_chip, mode, layers):
+    """``decode_attention`` at the chat-decode cell's geometry (32 slots of
+    48 pages of 16 tokens, qwen2-0.5b's heads), its int8 pool padded to
+    whole lanes as the runtime holds it on the chip: one layer's pool, or a
+    stack of ``layers`` read at the layer an operand names."""
+    B, Hkv, g, hd, ps = CHAT["B"], QWEN["Hkv"], QWEN["g"], QWEN["hd"], \
+        CHAT["ps"]
+    NP, pps = CHAT["NP"], CHAT["max_len"] // CHAT["ps"]
+    per_head, quant_p = mode != "per_token", mode == "per_head_p_scale"
+    stack = (layers,) if layers else ()
+    scale_shape = (Hkv,) if per_head else stack + (NP, Hkv, 128)
     _assert_kernel(
         one_chip,
-        lambda q, k, v, pt, ln, ks, vs, p: da.decode_attention(
+        lambda q, k, v, pt, ln, ks, vs, p, ly: da.decode_attention(
             q, k, v, pt, ln, k_scale=ks, v_scale=vs, per_head=per_head,
-            p_scale=p if quant_p else None, interpret=False),
-        ((B, Hkv, g, hd), F32), ((NP, Hkv, ps, hd), I8),
-        ((NP, Hkv, ps, hd), I8), ((B, pps), I32), ((B,), I32),
-        (scale_shape, F32), (scale_shape, F32), ((), F32))
+            p_scale=p if quant_p else None, layer=ly if layers else None,
+            interpret=False),
+        ((B, Hkv, g, hd), F32), (stack + (NP, Hkv, ps, 128), I8),
+        (stack + (NP, Hkv, ps, 128), I8), ((B, pps), I32), ((B,), I32),
+        (scale_shape, F32), (scale_shape, F32), ((), F32), ((), I32))
+
+
+@pytest.mark.parametrize("mode", DECODE_MODES)
+def test_decode_attention(one_chip, mode):
+    _assert_decode_attention(one_chip, mode, layers=0)
 
 
 @pytest.mark.parametrize("lanes", [1, 128])
@@ -251,15 +262,16 @@ def _decode_step_text(monkeypatch, scheme, on, rows, mesh=None):
 @pytest.mark.parametrize("scheme", ["int8_per_token", "int8_per_head"])
 def test_decode_step_keeps_the_pool_in_place(one_chip, monkeypatch, scheme):
     """The decode step holds no relayout copy of a page-pool leaf
-    (``pool_copies`` 0), and ``decode_attention`` is still the Pallas
-    kernel with its ``f32[slots, kv_heads, group, head_dim]`` result."""
+    (``pool_copies`` 0), and ``decode_attention`` is still one Pallas
+    call in the layer scan's body, with its ``f32[slots, kv_heads, group,
+    head_dim]`` result."""
     from repro.serve.runtime import pool_copies
     text, caches, _ = _decode_step_text(monkeypatch, scheme, one_chip,
                                         one_chip)
     assert pool_copies(text, caches) == 0
     attn = [line for line in text.splitlines()
             if "%decode_attention" in line and "custom-call(" in line]
-    assert attn and all("tpu_custom_call" in line for line in attn)
+    assert len(attn) == 1 and "tpu_custom_call" in attn[0]
     assert all(f"f32[{CHAT['B']},{QWEN['Hkv']},{QWEN['g']},{QWEN['hd']}]"
                in line for line in attn)
 
@@ -279,22 +291,11 @@ def test_decode_step_per_device_keeps_the_pool_in_place(topo, monkeypatch):
     assert pool_copies(text, caches, rt.shards) == 0
 
 
-@pytest.mark.parametrize("per_head", [False, True])
-def test_decode_attention_on_a_lane_padded_stack(one_chip, per_head):
+@pytest.mark.parametrize("mode", DECODE_MODES)
+def test_decode_attention_on_a_lane_padded_stack(one_chip, mode):
     """``decode_attention`` reading one layer of the cell's whole 24-layer
     pool, padded to whole lanes, with the layer as an operand."""
-    B, Hkv, g, hd, ps = CHAT["B"], QWEN["Hkv"], QWEN["g"], QWEN["hd"], \
-        CHAT["ps"]
-    NP, pps = CHAT["NP"], CHAT["max_len"] // CHAT["ps"]
-    scale_shape = (Hkv,) if per_head else (24, NP, Hkv, 128)
-    _assert_kernel(
-        one_chip,
-        lambda q, k, v, pt, ln, ks, vs, ly: da.decode_attention(
-            q, k, v, pt, ln, k_scale=ks, v_scale=vs, per_head=per_head,
-            layer=ly, interpret=False),
-        ((B, Hkv, g, hd), F32), ((24, NP, Hkv, ps, 128), I8),
-        ((24, NP, Hkv, ps, 128), I8), ((B, pps), I32), ((B,), I32),
-        (scale_shape, F32), (scale_shape, F32), ((), I32))
+    _assert_decode_attention(one_chip, mode, layers=24)
 
 
 @pytest.mark.parametrize("gemm", ["gate", "down"])
