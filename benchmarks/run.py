@@ -1,9 +1,11 @@
-"""Benchmark aggregator: one entry per paper table/figure + the roofline.
+"""Paper-reproduction aggregator: the Table-2 accuracy grid and the
+Figure-4 / Appendix-B softmax code-range count.
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
-Prints a `name,seconds,derived` CSV summary line per benchmark after each
+Prints a `name,seconds,derived` CSV summary line per section after each
 section's own table. ``--quick`` shrinks the Table-2 fine-tuning budget.
+Speed is measured on the chip by ``bench/run.py``, not here.
 """
 from __future__ import annotations
 
@@ -30,16 +32,11 @@ def main():
         dt = time.time() - t0
         summary.append((name, dt, derived))
 
-    from benchmarks import (figure3_speedup, fusion_ablation, roofline,
-                            serve_throughput, softmax_range, table2_clue)
+    from benchmarks import softmax_range, table2_clue
 
     def _table2():
         rows = table2_clue.main(steps=steps, stride=stride)
         return f"{len(rows)} grid points"
-
-    def _fig3():
-        figure3_speedup.main()
-        return "modeled+measured grids"
 
     def _softmax():
         r = softmax_range.collect()
@@ -47,33 +44,8 @@ def main():
                 f"mha unused {r['mha_unused']}/256; "
                 f"unsigned fix {r['softmax_unsigned_unused']}/256")
 
-    def _fusion():
-        rows = fusion_ablation.main()
-        return f"{len(rows)} fusions"
-
-    def _serve():
-        r = serve_throughput.main(quick=args.quick)
-        return (f"decode {r['decode']['requests_per_s']:.1f} req/s / "
-                f"encoder {r['encoder']['requests_per_s']:.1f} req/s; "
-                f"{r['decode']['retraces'] + r['encoder']['retraces']} "
-                f"retraces")
-
-    def _roofline():
-        md, analyses = roofline.table()
-        print(md)
-        if not analyses:
-            return "no dry-run records (run repro.launch.dryrun first)"
-        worst = min(analyses, key=lambda a: a["roofline_frac"])
-        return (f"{len(analyses)} cells; worst roofline "
-                f"{worst['arch']}/{worst['shape']}="
-                f"{worst['roofline_frac']:.2f}")
-
     run("table2_clue (paper Table 2)", _table2)
-    run("figure3_speedup (paper Figure 3)", _fig3)
     run("softmax_range (paper Figure 4 / Appx B)", _softmax)
-    run("fusion_ablation (paper §2.2/§3.2)", _fusion)
-    run("serve_throughput (serving stack)", _serve)
-    run("roofline (deliverable g)", _roofline)
 
     print("\n=== summary csv " + "=" * 44)
     print("name,seconds,derived")

@@ -9,9 +9,8 @@ bounded-concurrency open-loop client, records client-side latency into
 the same histogram buckets the server exports at ``/metrics``
 (``repro.serve.metrics.LATENCY_BUCKETS``), and counts 429/503 rejections
 so the admission controller's behaviour shows up as a *rate*, not an
-error log. ``benchmarks/serve_throughput.py`` imports :func:`run_load`
-to produce the ``frontend`` section of BENCH_serve.json; the tests reuse
-the client helpers to talk to in-process front-ends.
+error log. The tests reuse the client helpers to talk to in-process
+front-ends.
 """
 from __future__ import annotations
 
@@ -161,7 +160,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None,
                     help="merge the result into this JSON file under "
-                         "'http_load' (e.g. BENCH_serve.json)")
+                         "'http_load'")
     args = ap.parse_args()
     result = asyncio.run(run_load(
         args.host, args.port, mode=args.mode, n_requests=args.requests,

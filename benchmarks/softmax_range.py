@@ -28,7 +28,7 @@ def collect(arch="bert-base", n_batches=4, batch=16, seq=32, layers=12,
             emit=print):
     cfg = get_config(arch).reduced().replace(num_layers=layers)
     eng = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(jax.random.PRNGKey(0), cfg, eng.float_policy)
+    params = T.init_params(jax.random.PRNGKey(0), cfg, eng.float_precision)
     task = make_task("tnews", vocab_size=cfg.vocab_size, seq_len=seq)
     softmax_vals, mha_vals = [], []
     for i in range(n_batches):

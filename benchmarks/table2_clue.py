@@ -7,7 +7,7 @@ so the reproduction keeps the full experimental *structure* at calibration
 scale: a width-reduced 12-LAYER BERT (layer count preserved — the k axis is
 the paper's object of study) fine-tuned on synthetic stand-ins of the three
 tasks; accuracy is genuinely measured on a held-out dev stream, speedup is the
-analytic TPU roofline latency model (benchmarks/latency_model — the same
+analytic TPU roofline latency model (repro.toolkit.latency — the same
 interface wall-clock numbers flow through on hardware).
 
 Emits the Table-2-shaped grid per task with the allocator's underlined
@@ -20,11 +20,12 @@ import time
 import jax
 import jax.numpy as jnp
 
-from benchmarks.latency_model import encoder_latency
 from repro.configs import get_config
+from repro.core.plan import PrecisionPlan
 from repro.core.samp import SAMPEngine
 from repro.data import eval_accuracy, get_batch, make_task
 from repro.models import transformer as T
+from repro.toolkit.latency import encoder_latency
 from repro.train import AdamW, TrainConfig, Trainer
 from repro.train.trainer import TrainState
 
@@ -33,13 +34,12 @@ TASKS = (("afqmc", "afqmc", 2), ("iflytek", "iflytek", 119),
 
 
 def finetune(cfg, task, n_classes, steps=150, seed=0):
-    policy_cls = ("cls", n_classes)
-    from repro.core.precision import EncoderPolicy
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
-    tr = Trainer(cfg, policy, optimizer=AdamW(lr=2e-3),
+    head = ("cls", n_classes)
+    precision = PrecisionPlan.full_float(cfg.num_layers, "float32")
+    tr = Trainer(cfg, precision, optimizer=AdamW(lr=2e-3),
                  tcfg=TrainConfig(steps=steps, log_every=10_000,
                                   compute_dtype="float32", remat=False),
-                 head=policy_cls)
+                 head=head)
     state = tr.init_state(jax.random.PRNGKey(seed))
     step = tr.make_step()
     for i in range(steps):
@@ -79,12 +79,12 @@ def run_task(name, task_key, n_classes, *, steps=150, stride=2,
              for b in (get_batch(task, 1000 + i, 16) for i in range(4))]
     stats = eng.calibrate(params, calib)
 
-    def eval_fn(qp, plan, policy):
+    def eval_fn(qp, plan, precision):
         return eval_accuracy(predictor(cfg, plan, qp), task, batches=8,
                              batch_size=64)
 
-    def latency_fn(qp, plan, policy):
-        return encoder_latency(cfg, policy, batch=32, seq=seq_len)
+    def latency_fn(qp, plan, precision):
+        return encoder_latency(cfg, precision, batch=32, seq=seq_len)
 
     pts = eng.sweep(params, stats, eval_fn, latency_fn, stride=stride)
     base = pts[0]

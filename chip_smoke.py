@@ -140,7 +140,6 @@ def build_encoder(cfg, seed: int):
     by the full plan upgraded to the whole-layer int8 dataflow (handed to
     build_model as a plan file). Returns ((params, plan) float,
     (params, plan) int8)."""
-    from repro.core.plan import plan_from_policy
     from repro.core.precision import make_policy
     from repro.core.samp import int8_dataflow_variant
     from repro.data.pipeline import make_task
@@ -148,7 +147,7 @@ def build_encoder(cfg, seed: int):
     task = make_task("tnews", vocab_size=cfg.vocab_size,
                      seq_len=cfg.max_position)
     head = ("cls", task.n_classes)
-    plan = int8_dataflow_variant(plan_from_policy(make_policy(cfg, "full")))
+    plan = int8_dataflow_variant(make_policy(cfg, "full"))
     OUT.mkdir(exist_ok=True)
     plan_file = OUT / f"{cfg.name}-full-int8flow.json"
     plan.save(str(plan_file))

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.data import get_batch, make_task
 from repro.launch.mesh import make_host_mesh
 from repro.train import AdamW, TrainConfig, Trainer, cosine_schedule
@@ -36,7 +36,7 @@ args = ap.parse_args()
 cfg = get_config(args.arch)
 if not args.full:
     cfg = cfg.reduced()
-policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
 mesh = make_host_mesh() if len(jax.devices()) > 1 else None
 trainer = Trainer(
     cfg, policy, mesh=mesh,

@@ -2,5 +2,5 @@
 lattice, the accuracy-decay-aware allocator, and the engine tying them
 together (the paper's primary contribution)."""
 from repro.core import allocator, calibration, plan, precision, quantize  # noqa: F401
-from repro.core.plan import (LayerPlan, PrecisionPlan,  # noqa: F401
-                             QuantSpec, as_plan, plan_from_policy)
+from repro.core.plan import (LayerMode, LayerPlan,  # noqa: F401
+                             PrecisionPlan, QuantSpec)

@@ -2,11 +2,12 @@
 
 The paper's "self-adaptive mixed-precision" decision is, in full generality,
 a choice *per layer, per GEMM block*: which weights go int8, how their
-activations are scaled, and which calibrator produced the scales. The
-:class:`EncoderPolicy` lattice in :mod:`repro.core.precision` only spans the
-paper's three per-layer modes; a :class:`PrecisionPlan` is the superset that
-every consumer (PTQ, the search strategies, the artifact bundles, the
-serving runtime's executable cache) now speaks:
+activations are scaled, and which calibrator produced the scales. A
+:class:`PrecisionPlan` is the one precision description every consumer
+(PTQ, the search strategies, the artifact bundles, the serving runtime's
+executable cache) speaks; the paper's three per-layer modes
+(:class:`LayerMode`) are points on it (:meth:`LayerPlan.for_mode`,
+:meth:`PrecisionPlan.prefix`):
 
 * a plan is an immutable tree ``PrecisionPlan -> LayerPlan -> QuantSpec``;
 * each layer exposes four *blocks* — ``qkv`` (the MHA input projections and
@@ -22,20 +23,14 @@ serving runtime's executable cache) now speaks:
 * ``fingerprint()`` is a stable content hash of the canonical JSON form —
   the one identity used for executable-cache keys, artifact metadata, and
   save → load equality checks.
-
-``EncoderPolicy`` remains as a thin view for the paper's mode lattice;
-:func:`plan_from_policy` converts (and :meth:`PrecisionPlan.from_policy`
-does the same with a deprecation warning for external callers).
 """
 from __future__ import annotations
 
 import dataclasses
+import enum
 import hashlib
 import json
-import warnings
 from typing import Mapping, Optional, Sequence, Union
-
-from repro.core.precision import EncoderPolicy, LayerMode
 
 SCHEMA_VERSION = 4
 
@@ -59,6 +54,26 @@ FAMILY_ALIASES = {
     "conv_stem": "ffn_in",          # audio/vision conv front-end GEMMs
 }
 FLOAT_DTYPES = ("float32", "bfloat16", "float16")
+
+
+class LayerMode(enum.Enum):
+    """The paper's per-layer modes (§3.2, Figure 2): ``FLOAT`` (no
+    quantization), ``QUANT_FFN_ONLY`` (FFN GEMMs int8, MHA float — the
+    paper's preferred mode) and ``FULLY_QUANT`` (MHA and FFN GEMMs int8).
+    Every :class:`LayerPlan` derives one (:attr:`LayerPlan.mode`), which
+    names the execution mode of its scan group."""
+
+    FLOAT = "float"
+    QUANT_FFN_ONLY = "quant_ffn_only"
+    FULLY_QUANT = "fully_quant"
+
+    @property
+    def quant_ffn(self) -> bool:
+        return self is not LayerMode.FLOAT
+
+    @property
+    def quant_mha(self) -> bool:
+        return self is LayerMode.FULLY_QUANT
 
 
 def _known_calibrators() -> tuple:
@@ -364,7 +379,7 @@ class PrecisionPlan:
             raise ValueError(f"float_dtype {self.float_dtype!r} not in "
                              f"{FLOAT_DTYPES}")
 
-    # -- EncoderPolicy-compatible surface (duck-typed by build_plan) --------
+    # -- the paper's mode-lattice view --------------------------------------
     @property
     def num_layers(self) -> int:
         return len(self.layers)
@@ -389,10 +404,8 @@ class PrecisionPlan:
         return self.layers[layer_idx].qkv.quantized
 
     def softmax_scheme(self, layer_idx: int) -> str:
-        """The softmax dataflow scheme of layer ``layer_idx`` (schema v3).
-        Duck-typed by ``build_plan`` the same way as :meth:`bmm_quantized`;
-        EncoderPolicy has no such method, so policy-driven plans keep the
-        legacy global ``QuantScheme.softmax_mode`` behavior."""
+        """The softmax dataflow scheme of layer ``layer_idx`` (schema v3);
+        'float' layers follow the global ``QuantScheme.softmax_mode``."""
         return self.layers[layer_idx].softmax
 
     def group_boundaries(self) -> list[tuple[int, int, LayerMode]]:
@@ -491,26 +504,6 @@ class PrecisionPlan:
         return PrecisionPlan(
             tuple(layer if i in layer_set else FLOAT_LAYER
                   for i in range(num_layers)), float_dtype)
-
-    @staticmethod
-    def from_policy(policy: EncoderPolicy, *, dynamic_acts: bool = False,
-                    calibrator: str = "minmax") -> "PrecisionPlan":
-        """EncoderPolicy -> PrecisionPlan shim.
-
-        Deprecated entry point: the mode lattice is a strict subset of what
-        plans express — build plans directly (or via the search strategies).
-        """
-        warnings.warn(
-            "EncoderPolicy is deprecated as a precision description; "
-            "use PrecisionPlan (this shim converts losslessly)",
-            DeprecationWarning, stacklevel=2)
-        return plan_from_policy(policy, dynamic_acts=dynamic_acts,
-                                calibrator=calibrator)
-
-    def to_policy(self) -> EncoderPolicy:
-        """Project onto the paper's mode lattice (lossy for per-block or
-        per-tensor-weight plans; exact for plans built from policies)."""
-        return EncoderPolicy(self.modes, self.float_dtype)
 
     # -- serialization ------------------------------------------------------
     def to_dict(self) -> dict:
@@ -755,26 +748,6 @@ def load_plan_or_planset(path: str) -> Union[PrecisionPlan, "PlanSet"]:
     return PrecisionPlan.from_dict(d)
 
 
-def plan_from_policy(policy: EncoderPolicy, *, dynamic_acts: bool = False,
-                     calibrator: str = "minmax") -> PrecisionPlan:
-    """Lossless EncoderPolicy -> PrecisionPlan conversion (no warning —
-    the internal compatibility path; external callers should migrate via
-    :meth:`PrecisionPlan.from_policy`)."""
-    return PrecisionPlan(
-        tuple(LayerPlan.for_mode(m, dynamic_acts=dynamic_acts,
-                                 calibrator=calibrator)
-              for m in policy.modes),
-        policy.float_dtype)
-
-
-def as_plan(precision: Union[PrecisionPlan, EncoderPolicy], *,
-            dynamic_acts: bool = False,
-            calibrator: str = "minmax") -> PrecisionPlan:
-    """Coerce either precision description to a PrecisionPlan."""
-    if isinstance(precision, PrecisionPlan):
-        return precision
-    if isinstance(precision, EncoderPolicy):
-        return plan_from_policy(precision, dynamic_acts=dynamic_acts,
-                                calibrator=calibrator)
-    raise TypeError(f"expected PrecisionPlan or EncoderPolicy, got "
-                    f"{type(precision).__name__}")
+# bench/system.py's name for the identity; ROADMAP debt "precision residue"
+def plan_from_policy(plan: PrecisionPlan) -> PrecisionPlan:
+    return plan

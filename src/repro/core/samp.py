@@ -25,13 +25,12 @@ Ties the substrate together:
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Sequence, Union
+from typing import Callable, Optional, Sequence
 
 from repro.configs.base import ArchConfig
 from repro.core import allocator
-from repro.core.plan import (LayerPlan, PrecisionPlan, QuantSpec, as_plan,
-                             plan_from_policy)
-from repro.core.precision import EncoderPolicy, LayerMode, paper_grid
+from repro.core.plan import LayerMode, LayerPlan, PrecisionPlan, QuantSpec
+from repro.core.precision import paper_grid
 from repro.models.transformer import QuantScheme, build_plan
 from repro.quant import ptq
 
@@ -58,17 +57,6 @@ class SweepPoint:
     plan: PrecisionPlan       # the candidate's precision description
     accuracy: float
     latency: float
-
-    @property
-    def policy(self) -> PrecisionPlan:
-        """Deprecated EncoderPolicy-era name for :attr:`plan` (the object
-        has been a PrecisionPlan since the plan API redesign — there is no
-        ``.modes`` lattice here). Use ``point.plan``."""
-        import warnings
-        warnings.warn("SweepPoint.policy is deprecated; the field holds a "
-                      "PrecisionPlan — use SweepPoint.plan",
-                      DeprecationWarning, stacklevel=2)
-        return self.plan
 
     @property
     def speedup_key(self):
@@ -172,13 +160,11 @@ def _grid_candidates(engine: "SAMPEngine", stride: int,
     ``dataflow`` doubles each eligible candidate with its whole-layer
     int8-dataflow variant (family ``<mode>+int8flow``); ``moe_families``
     (MoE configs only) adds the per-expert variant (``<mode>+experts``)."""
-    for name, k, policy in paper_grid(engine.cfg.num_layers,
-                                      engine.float_dtype, stride):
+    for name, k, precision in paper_grid(
+            engine.cfg.num_layers, engine.float_dtype, stride,
+            dynamic_acts=engine.scheme.dynamic_acts, calibrator=calibrator):
         if name != "float" and not any(m.value == name for m in modes):
             continue
-        precision = plan_from_policy(
-            policy, dynamic_acts=engine.scheme.dynamic_acts,
-            calibrator=calibrator)
         yield name, k, precision
         if dataflow:
             flow = int8_dataflow_variant(precision)
@@ -297,8 +283,6 @@ class SAMPEngine:
         self.cfg = cfg
         self.scheme = scheme
         self.float_dtype = float_dtype
-        self.float_policy = EncoderPolicy.full_float(cfg.num_layers,
-                                                     float_dtype)
         self.float_precision = PrecisionPlan.full_float(cfg.num_layers,
                                                         float_dtype)
         self.float_plan = build_plan(cfg, self.float_precision)
@@ -367,11 +351,9 @@ class SAMPEngine:
         return [cand[r.index] for r in recs]
 
     # -- step 4: apply -------------------------------------------------------
-    def apply(self, params: dict, stats: dict,
-              precision: Union[PrecisionPlan, EncoderPolicy]):
+    def apply(self, params: dict, stats: dict, precision: PrecisionPlan):
         """Produce the production-ready (params, plan) for a chosen
-        PrecisionPlan (EncoderPolicies convert via the shim)."""
-        precision = as_plan(precision, dynamic_acts=self.scheme.dynamic_acts)
+        PrecisionPlan."""
         return ptq.apply_plan(params, self.cfg, precision, stats,
                               scheme=self.scheme,
                               float_plan=self.float_plan)

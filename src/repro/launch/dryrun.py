@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core.precision import EncoderPolicy, LayerMode, make_policy
+from repro.core.plan import PrecisionPlan
+from repro.core.precision import make_policy
 from repro.distributed.sharding import Rules
 from repro.launch.hlo_cost import analyze_hlo
 from repro.launch import shapes as SH
@@ -93,9 +94,9 @@ def quantized_param_specs(cfg, policy, param_dtype=jnp.bfloat16):
     """Abstract quantized params: eval_shape the PTQ transform itself."""
     def build():
         params = T.init_params(jax.random.PRNGKey(0), cfg,
-                               EncoderPolicy.full_float(cfg.num_layers),
+                               PrecisionPlan.full_float(cfg.num_layers),
                                dtype=param_dtype)
-        qp, _ = ptq.apply_policy(params, cfg, policy, abstract_stats(cfg))
+        qp, _ = ptq.apply_plan(params, cfg, policy, abstract_stats(cfg))
         return qp
     return jax.eval_shape(build)
 

@@ -42,8 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.calibration import synthetic_calibration_batches
-from repro.core.plan import (PlanSet, PrecisionPlan, load_plan_or_planset,
-                             plan_from_policy)
+from repro.core.plan import PlanSet, PrecisionPlan, load_plan_or_planset
 from repro.core.precision import make_policy
 from repro.core.samp import SAMPEngine
 from repro.data.pipeline import make_task
@@ -101,8 +100,7 @@ def search_plan(cfg, eng: SAMPEngine, params, stats, strategy: str, *,
 def build_model(cfg, policy_name: str = "float", *, seed: int = 0,
                 head=None, log=print, plan_file=None, strategy=None,
                 max_latency=None):
-    """Float init + optional SAMP PTQ (shared with
-    benchmarks/serve_throughput.py — one build flow for everything that
+    """Float init + optional SAMP PTQ (one build flow for everything that
     serves a synthetic-calibrated model). Precision comes from, in
     precedence order: a saved plan file, a search strategy, or the named
     mode policy. Returns ``(params, execution_plan, precision)`` — the
@@ -110,13 +108,13 @@ def build_model(cfg, policy_name: str = "float", *, seed: int = 0,
     schemes (``precision.kv_schemes``)."""
     eng = SAMPEngine(cfg, float_dtype="float32")
     params = T.init_params(jax.random.PRNGKey(seed), cfg,
-                           eng.float_policy, head=head)
+                           eng.float_precision, head=head)
     precision = None
     if plan_file is not None:
         precision = PrecisionPlan.load(plan_file)
         log(f"[serve] loaded plan {plan_file}: {precision.describe()}")
     elif strategy is None:
-        precision = plan_from_policy(make_policy(cfg, policy_name))
+        precision = make_policy(cfg, policy_name)
     if precision is not None and not (precision.num_quant_ffn
                                       or precision.num_quant_mha
                                       or precision.num_quant_kv):
@@ -151,7 +149,7 @@ def build_routed_model(cfg, policy_name: str, cluster_model, *,
 
     eng = SAMPEngine(cfg, float_dtype="float32")
     params = T.init_params(jax.random.PRNGKey(seed), cfg,
-                           eng.float_policy, head=head)
+                           eng.float_precision, head=head)
     batches, classes = adaptive.clustered_synthetic_batches(
         cfg, cluster_model, seed=seed, max_len=max_len)
     adaptive.fit_cluster_model(cluster_model, params, batches, cfg)
@@ -167,7 +165,7 @@ def build_routed_model(cfg, policy_name: str, cluster_model, *,
         log(f"[serve] loaded {plan_file}: {planset.describe()}")
     else:
         planset = PlanSet.uniform(
-            plan_from_policy(make_policy(cfg, policy_name)), cids)
+            make_policy(cfg, policy_name), cids)
     router = adaptive.build_router(cfg, params, planset, stats,
                                    cluster_model=cluster_model,
                                    scheme=eng.scheme,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.models import transformer as T
 
 
@@ -87,7 +87,7 @@ def cache_specs(cfg: ArchConfig, plan, cell: ShapeCell,
                               cell.seq_len, cache_dtype))
 
 
-def params_specs(cfg: ArchConfig, policy: EncoderPolicy,
+def params_specs(cfg: ArchConfig, policy: PrecisionPlan,
                  param_dtype=jnp.bfloat16, head=None):
     return jax.eval_shape(
         lambda: T.init_params(jax.random.PRNGKey(0), cfg, policy,

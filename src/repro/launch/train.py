@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.data import get_batch, make_task
 from repro.launch.mesh import make_host_mesh
 from repro.train import AdamW, TrainConfig, Trainer, cosine_schedule
@@ -41,7 +41,7 @@ def main():
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "bfloat16")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "bfloat16")
     mesh = make_host_mesh(model=args.mesh_model) \
         if len(jax.devices()) > 1 else None
     tcfg = TrainConfig(steps=args.steps, checkpoint_dir=args.ckpt,

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, BlockKind
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerMode, PrecisionPlan
 from repro.kernels.backend import ffn_input_scale
 from repro.models import layers as L
 from repro.models import rglru as R
@@ -52,16 +52,15 @@ class QuantScheme:
 class Group:
     """One execution group: layers [start, stop), all in ``mode``, whose
     kind-sequence is ``kinds`` repeated ``steps`` times. ``quant_bmm``
-    gates the attention score/value int8 matmuls: per-block PrecisionPlans
-    tie them to the qkv block's spec, which can differ from the derived
-    mode's ``quant_mha`` (None = follow the mode, the policy-lattice
-    behavior)."""
+    gates the attention score/value int8 matmuls: they follow the qkv
+    block's spec, which can differ from the derived mode's
+    ``quant_mha``."""
     start: int
     stop: int
     mode: LayerMode
     kinds: tuple[BlockKind, ...]
     steps: int
-    quant_bmm: Optional[bool] = None
+    quant_bmm: bool
     #: schema-v3 per-layer softmax dataflow scheme ('uint8' quantizes the
     #: softmax output between the score and value matmuls; None = follow
     #: the global QuantScheme.softmax_mode policy). Uniform within a group:
@@ -73,28 +72,21 @@ class Group:
         return self.steps >= 2
 
 
-def build_plan(cfg: ArchConfig, policy) -> tuple[Group, ...]:
-    """Execution plan for a precision description: an ``EncoderPolicy`` or a
-    :class:`~repro.core.plan.PrecisionPlan` (both expose ``num_layers`` and
-    ``group_boundaries()``; a PrecisionPlan splits runs on full per-block
-    LayerPlan equality so scan groups stay structurally homogeneous)."""
-    if policy.num_layers != cfg.num_layers:
-        raise ValueError(
-            f"policy has {policy.num_layers} layers, arch {cfg.num_layers}")
+def build_plan(cfg: ArchConfig, precision: PrecisionPlan
+               ) -> tuple[Group, ...]:
+    """Execution plan for a :class:`~repro.core.plan.PrecisionPlan`: runs
+    split on full per-block LayerPlan equality, so scan groups stay
+    structurally homogeneous."""
+    if precision.num_layers != cfg.num_layers:
+        raise ValueError(f"plan has {precision.num_layers} layers, arch "
+                         f"{cfg.num_layers}")
     kinds = cfg.layer_kinds()
     p = len(cfg.pattern)
     groups: list[Group] = []
-    # per-block plans quantize the attention bmms iff the qkv block is
-    # quantized; the mode lattice ties them to quant_mha
-    bmm_fn = getattr(policy, "bmm_quantized", None)
-    # schema-v3 plans carry a per-layer softmax scheme; EncoderPolicy (and
-    # v1/v2 plans, whose layers default to 'float') fall back to the global
-    # QuantScheme policy via None
-    sm_fn = getattr(policy, "softmax_scheme", None)
-
-    for (s, e, mode) in policy.group_boundaries():
-        quant_bmm = bmm_fn(s) if bmm_fn is not None else mode.quant_mha
-        sm = sm_fn(s) if sm_fn is not None else None
+    for (s, e, mode) in precision.group_boundaries():
+        quant_bmm = precision.bmm_quantized(s)
+        # 'float' layers follow the global QuantScheme softmax policy
+        sm = precision.softmax_scheme(s)
         sm = None if sm == "float" else sm
         # Greedy maximal runs: prefer a homogeneous run; else a run that is
         # periodic with the arch's block pattern (possibly rotated); else a
@@ -158,19 +150,18 @@ def _stack(trees: Sequence[Any]):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
-def init_params(key, cfg: ArchConfig, policy: Optional[EncoderPolicy] = None,
+def init_params(key, cfg: ArchConfig, plan: Optional[PrecisionPlan] = None,
                 *, head: Optional[tuple[str, int]] = None,
                 dtype=jnp.float32) -> dict:
     """Float parameter init, packed per execution group. Quantized params are
-    produced from these by repro.quant.ptq.apply_policy (PTQ: no re-training).
+    produced from these by repro.quant.ptq.apply_plan (PTQ: no re-training).
     """
-    policy = policy or EncoderPolicy.full_float(cfg.num_layers)
-    plan = build_plan(cfg, policy)
     kemb, khead, klayers = jax.random.split(key, 3)
     params: dict = {"embed": L.init_embeddings(kemb, cfg, dtype)}
     lkeys = jax.random.split(klayers, cfg.num_layers)
     groups = []
-    for g in plan:
+    for g in build_plan(cfg,
+                        plan or PrecisionPlan.full_float(cfg.num_layers)):
         period = []
         for j in range(len(g.kinds)):
             stack = [init_layer(lkeys[g.start + s * len(g.kinds) + j], cfg,
@@ -261,12 +252,11 @@ def repack(params: dict, old_plan: tuple[Group, ...],
 
 def layer_forward(x, lp, cfg: ArchConfig, kind: BlockKind, mode: LayerMode,
                   scheme: QuantScheme, *, positions, obs, cache, chunk,
-                  constrain: Constrain, active=None, quant_bmm=None,
+                  constrain: Constrain, quant_bmm: bool, active=None,
                   softmax=None, pages=None, backend=None):
     """One layer; returns ``(x, new_cache, routed)``, ``routed`` the picks
     its held experts computed (an int32 scalar; 0 outside MoE layers)."""
-    quant = L.AttnQuant(enabled=(mode.quant_mha if quant_bmm is None
-                                 else quant_bmm),
+    quant = L.AttnQuant(enabled=quant_bmm,
                         softmax_mode=scheme.softmax_mode,
                         plan_scheme=softmax)
     spec = L.MaskSpec(
@@ -691,7 +681,7 @@ def init_caches(cfg: ArchConfig, plan: tuple[Group, ...],
 
 def cache_bytes(caches) -> int:
     """Total KV/state cache footprint in bytes (the serving-side
-    ``samp_kv_cache_bytes`` gauge and BENCH_serve's ``kv_cache_bytes``)."""
+    ``samp_kv_cache_bytes`` gauge)."""
     return int(sum(leaf.size * leaf.dtype.itemsize
                    for leaf in jax.tree_util.tree_leaves(caches)))
 

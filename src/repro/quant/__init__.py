@@ -1,6 +1,4 @@
 from repro.quant import ptq  # noqa: F401
-from repro.quant.ptq import (apply_plan, apply_policy, capture_stats,
-                             quantize_weight)
+from repro.quant.ptq import apply_plan, capture_stats, quantize_weight
 
-__all__ = ["ptq", "apply_plan", "apply_policy", "capture_stats",
-           "quantize_weight"]
+__all__ = ["ptq", "apply_plan", "capture_stats", "quantize_weight"]

@@ -21,14 +21,11 @@ matmuls (q·k^T, p·v) additionally get ``{q,k,p,v}_scale`` scalars when the
 layer's qkv block is statically quantized (the paper's Figure-2(a) path,
 including the softmax quantization that Appendix B shows is the accuracy
 killer).
-
-:func:`apply_policy` remains as the :class:`EncoderPolicy` compatibility
-wrapper (policies convert losslessly via ``plan_from_policy``).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +35,7 @@ import inspect
 
 from repro.configs.base import ArchConfig, BlockKind
 from repro.core.calibration import CALIBRATORS, Calibrator, make_calibrator
-from repro.core.plan import (LayerPlan, PrecisionPlan, as_plan,
-                             plan_from_policy)
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerPlan, PrecisionPlan
 from repro.core.quantize import (QuantizedTensor, compute_scale_symmetric,
                                  quantize, UINT8_MAX)
 from repro.models import transformer as T
@@ -198,15 +193,11 @@ def _set_path(d: dict, path: tuple[str, ...], value) -> None:
 
 
 def quantize_layer(lp: dict, cfg: ArchConfig, kind: BlockKind,
-                   layer: Union[LayerPlan, LayerMode],
-                   amax: dict[str, float],
+                   layer: LayerPlan, amax: dict[str, float],
                    scheme: T.QuantScheme) -> dict:
-    """Return a quantized copy of one layer's params under ``layer`` (a
-    per-block :class:`LayerPlan`; a bare :class:`LayerMode` is expanded via
-    :meth:`LayerPlan.for_mode`). ``amax`` maps site name -> calibrated amax
-    for THIS layer."""
-    if isinstance(layer, LayerMode):
-        layer = LayerPlan.for_mode(layer, dynamic_acts=scheme.dynamic_acts)
+    """Return a quantized copy of one layer's params under its per-block
+    :class:`LayerPlan`. ``amax`` maps site name -> calibrated amax for THIS
+    layer."""
     if not (layer.quant_mha or layer.quant_ffn
             or layer.kv_cache != "float"):
         return lp
@@ -472,7 +463,7 @@ def _capture_stats_clustered(params, batches, cfg, plan, scheme, clusters,
 
 
 def apply_plan(params: dict, cfg: ArchConfig,
-               precision: Union[PrecisionPlan, EncoderPolicy],
+               precision: PrecisionPlan,
                stats: dict[str, dict[str, float]], *,
                scheme: T.QuantScheme = T.QuantScheme(),
                float_plan=None, backend=None):
@@ -486,7 +477,6 @@ def apply_plan(params: dict, cfg: ArchConfig,
     built-in backends execute everything (reference ops are the universal
     fallback), so this is the fail-fast hook for custom registered
     backends with a narrower op set."""
-    precision = as_plan(precision, dynamic_acts=scheme.dynamic_acts)
     if backend is not None:
         from repro.kernels.backend import get_backend
         get_backend(backend).validate_plan(precision)
@@ -505,14 +495,3 @@ def apply_plan(params: dict, cfg: ArchConfig,
     qparams = T.repack(params, float_plan, new_plan, transform)
     return qparams, new_plan
 
-
-def apply_policy(params: dict, cfg: ArchConfig, policy: EncoderPolicy,
-                 stats: dict[str, dict[str, float]], *,
-                 scheme: T.QuantScheme = T.QuantScheme(),
-                 float_plan=None):
-    """:class:`EncoderPolicy` compatibility wrapper over
-    :func:`apply_plan` (policies convert losslessly; ``scheme.dynamic_acts``
-    selects per-token activation quantization, as before)."""
-    precision = plan_from_policy(policy, dynamic_acts=scheme.dynamic_acts)
-    return apply_plan(params, cfg, precision, stats, scheme=scheme,
-                      float_plan=float_plan)

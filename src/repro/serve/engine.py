@@ -111,7 +111,7 @@ class ServeEngine:
         self.compute_dtype = compute_dtype
         self.cache_dtype = cache_dtype
         if page_size is None and kv_cache is None and precision is not None \
-                and getattr(precision, "num_quant_kv", 0):
+                and precision.num_quant_kv:
             # the plan itself asks for quantized KV: paging is implied
             page_size = DEFAULT_PAGE_SIZE
         self.page_size = page_size

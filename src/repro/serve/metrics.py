@@ -37,8 +37,8 @@ from typing import Callable, Optional, Sequence
 from jax.profiler import TraceAnnotation
 
 # Request-latency bucket upper bounds (seconds). Shared by the /metrics
-# histogram and the benchmark artifacts (BENCH_serve.json), so client- and
-# server-side histograms line up bucket for bucket.
+# histogram and the HTTP load client's summary (latency_summary), so
+# client- and server-side histograms line up bucket for bucket.
 LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                    0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 
@@ -156,8 +156,9 @@ def engine_counters(engine) -> dict:
 def latency_summary(latencies: Sequence[float], *,
                     buckets: Sequence[float] = LATENCY_BUCKETS) -> dict:
     """Quantiles + cumulative histogram for a latency sample set — the
-    shape BENCH_serve.json records (and the shape the /metrics histogram
-    exports, so benchmark and dashboard numbers are comparable)."""
+    shape ``benchmarks/serve_http_load.py`` reports (and the shape the
+    /metrics histogram exports, so client and dashboard numbers are
+    comparable)."""
     xs = sorted(float(x) for x in latencies)
     n = len(xs)
 

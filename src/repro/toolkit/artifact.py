@@ -19,8 +19,9 @@ leaves into it. Outputs are bit-identical to the pipeline that was saved,
 the reloaded plan's ``fingerprint()`` is byte-identical to the recorded
 one, and no calibration batches are needed at deployment time.
 
-Version history: v1 bundles stored an ``EncoderPolicy`` (``policy`` key);
-they still load, through the lossless policy -> plan shim. v3 bundles are
+Version history: v1 bundles stored the paper's per-layer modes
+(``policy`` key); they still load, each mode expanded through
+:meth:`~repro.core.plan.LayerPlan.for_mode`. v3 bundles are
 *adaptive*: they persist the FLOAT parameters plus a
 :class:`~repro.core.plan.PlanSet`, a serialized cluster model, and
 per-cluster calibration stats — loading rebuilds the K quantized trees
@@ -34,7 +35,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Optional, Union
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +43,7 @@ import jax.numpy as jnp
 from repro.checkpoint import store
 from repro.configs.base import (ArchConfig, MLAConfig, MoEConfig,
                                 RopeScaling)
-from repro.core.plan import PrecisionPlan, as_plan, plan_from_policy
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerMode, LayerPlan, PrecisionPlan
 from repro.data.pipeline import TaskSpec
 from repro.models import transformer as T
 from repro.quant import ptq
@@ -139,24 +139,21 @@ def _param_dtype(params: dict) -> str:
 
 
 def save_artifact(directory: str, *, cfg: ArchConfig,
-                  policy: Union[PrecisionPlan, EncoderPolicy],
-                  stats: dict, params: dict,
+                  policy: PrecisionPlan, stats: dict, params: dict,
                   scheme: T.QuantScheme = T.QuantScheme(),
                   task: Optional[TaskSpec] = None,
                   target: str = "lm", n_out: int = 0,
                   compute_dtype: str = "float32",
                   tokenizer=None) -> str:
     """Write a deployable bundle. ``params`` must be the PTQ output for
-    ``policy`` (a PrecisionPlan, or an EncoderPolicy coerced through the
-    shim) packed under its execution plan; ``stats`` the calibration stats
-    the plan was applied with."""
-    precision = as_plan(policy, dynamic_acts=scheme.dynamic_acts)
+    the plan ``policy`` packed under its execution plan; ``stats`` the
+    calibration stats the plan was applied with."""
     os.makedirs(directory, exist_ok=True)
     meta = {
         "version": SINGLE_PLAN_VERSION,
         "arch": _cfg_to_dict(cfg),
-        "plan": precision.to_dict(),
-        "plan_fingerprint": precision.fingerprint(),
+        "plan": policy.to_dict(),
+        "plan_fingerprint": policy.fingerprint(),
         "scheme": dataclasses.asdict(scheme),
         "stats": stats,
         "task": dataclasses.asdict(task) if task is not None else None,
@@ -235,12 +232,12 @@ def _precision_from_meta(meta: dict) -> PrecisionPlan:
                 f"reloaded plan hashes to {precision.fingerprint()} — "
                 f"the bundle's artifact.json was edited or corrupted")
         return precision
-    # v1: an EncoderPolicy (modes + float_dtype) through the lossless shim
-    policy = EncoderPolicy(
-        tuple(LayerMode(m) for m in meta["policy"]["modes"]),
+    # v1: the paper's per-layer modes + float_dtype
+    dynamic_acts = T.QuantScheme(**meta["scheme"]).dynamic_acts
+    return PrecisionPlan(
+        tuple(LayerPlan.for_mode(LayerMode(m), dynamic_acts=dynamic_acts)
+              for m in meta["policy"]["modes"]),
         meta["policy"]["float_dtype"])
-    scheme = T.QuantScheme(**meta["scheme"])
-    return plan_from_policy(policy, dynamic_acts=scheme.dynamic_acts)
 
 
 def load_artifact(directory: str) -> Artifact:

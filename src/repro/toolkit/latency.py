@@ -10,36 +10,32 @@ swappable backend interface in the ``LATENCY_BACKENDS`` registry:
 
   and summed over the layer inventory given the per-layer SAMP mode. The
   only latency source available on this CPU-only container.
-* ``wallclock`` — measured: jits the real forward for each candidate policy
+* ``wallclock`` — measured: jits the real forward for each candidate plan
   and times it (median of ``reps``). The paper's setting on real hardware.
 
-Both produce the same ``(qparams, plan, policy) -> seconds`` callable the
+Both produce the same ``(qparams, plan, precision) -> seconds`` callable the
 sweep consumes, so the allocator is agnostic to the source (DESIGN.md §2).
 
 Hardware constants (TPU v5e): 197 TFLOP/s bf16, 394 TOP/s int8 (2x),
 ~49 TFLOP/s fp32 (no MXU fp32 path — priced at bf16/4), 819 GB/s HBM.
 The model reproduces the paper's qualitative shape: each Quant-FFN-Only
 layer buys a few percent end-to-end (the paper measures 2-3% on T4).
-
-(Moved here from ``benchmarks/latency_model.py``, which remains as a
-deprecated re-export shim for the bench scripts.)
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Optional
+from typing import Optional
 
 from repro.configs.base import ArchConfig
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerMode, PrecisionPlan
+from repro.core.samp import LatencyFn
 from repro.toolkit.registry import register_latency_backend
 
 PEAK = {"float32": 49.25e12, "bfloat16": 197e12, "float16": 197e12,
         "int8": 394e12}
 HBM_BW = 819e9
 BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
-
-LatencyFn = Callable[[dict, tuple, EncoderPolicy], float]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,16 +105,13 @@ def layer_ops(cfg: ArchConfig, mode: LayerMode, batch: int, seq: int,
     return ops
 
 
-def encoder_latency(cfg: ArchConfig, policy, *, batch: int,
-                    seq: int, chips: int = 1) -> float:
-    """Modeled seconds for one forward pass of the whole encoder stack.
-    ``policy`` is any precision description exposing ``.modes`` and
-    ``.float_dtype`` — an ``EncoderPolicy`` or a
-    :class:`~repro.core.plan.PrecisionPlan` (priced via its per-layer
-    derived modes)."""
+def encoder_latency(cfg: ArchConfig, precision: PrecisionPlan, *,
+                    batch: int, seq: int, chips: int = 1) -> float:
+    """Modeled seconds for one forward pass of the whole encoder stack,
+    priced via the plan's per-layer derived modes."""
     total = 0.0
-    for mode in policy.modes:
-        for op in layer_ops(cfg, mode, batch, seq, policy.float_dtype):
+    for mode in precision.modes:
+        for op in layer_ops(cfg, mode, batch, seq, precision.float_dtype):
             total += op.seconds
     return total / chips
 
@@ -137,7 +130,7 @@ def layer_latency(cfg: ArchConfig, mode: LayerMode, *, batch: int, seq: int,
 class LatencyBackend:
     """A latency source. ``bind`` closes over the measurement point (model
     config, batch geometry, an example batch for measured backends) and
-    returns the ``(qparams, plan, policy) -> seconds`` callable that
+    returns the ``(qparams, plan, precision) -> seconds`` callable that
     :meth:`repro.core.samp.SAMPEngine.sweep` consumes."""
 
     name = "?"
@@ -159,15 +152,15 @@ class RooflineBackend(LatencyBackend):
 
     def bind(self, cfg, *, batch, seq, example_batch=None, scheme=None,
              compute_dtype=None) -> LatencyFn:
-        def fn(qparams, plan, policy: EncoderPolicy) -> float:
-            return encoder_latency(cfg, policy, batch=batch, seq=seq,
+        def fn(qparams, plan, precision: PrecisionPlan) -> float:
+            return encoder_latency(cfg, precision, batch=batch, seq=seq,
                                    chips=self.chips)
         return fn
 
 
 @register_latency_backend("wallclock")
 class WallclockBackend(LatencyBackend):
-    """Measured wall-clock of the jitted forward, per candidate policy.
+    """Measured wall-clock of the jitted forward, per candidate plan.
     Each (mode, k) candidate is its own compiled executable (the paper's
     "configure the result to the toolkit" semantics), so compile time is
     excluded via warmup and the median of ``reps`` timed runs is reported."""
@@ -193,7 +186,7 @@ class WallclockBackend(LatencyBackend):
                 example_batch["segments"] = jnp.zeros((batch, seq), jnp.int32)
         example_batch = {k: jnp.asarray(v) for k, v in example_batch.items()}
 
-        def fn(qparams, plan, policy: EncoderPolicy) -> float:
+        def fn(qparams, plan, precision: PrecisionPlan) -> float:
             @jax.jit
             def fwd(p, b):
                 h, _ = T.forward(p, b, cfg, plan, scheme,

@@ -23,8 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.plan import PrecisionPlan, as_plan
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.data.pipeline import TaskSpec, eval_accuracy, get_batch, make_task
 from repro.data.tokenizer import WordPieceTokenizer
 from repro.kernels.backend import get_backend
@@ -129,8 +128,7 @@ class Pipeline:
 
     def __init__(self, cfg: ArchConfig, task: TaskSpec, target: TargetSpec,
                  *, n_out: Optional[int] = None,
-                 policy: Optional[Union[PrecisionPlan,
-                                        EncoderPolicy]] = None,
+                 policy: Optional[PrecisionPlan] = None,
                  plan=None, scheme: T.QuantScheme = T.QuantScheme(),
                  params: Optional[dict] = None,
                  tokenizer: Optional[WordPieceTokenizer] = None,
@@ -142,12 +140,7 @@ class Pipeline:
         # serving mesh the runtime places executables over (None = single
         # device); quantized siblings and serving engines inherit it
         self.mesh = mesh
-        # the precision description is always a PrecisionPlan internally;
-        # EncoderPolicies coerce through the lossless shim
-        self.policy = (PrecisionPlan.full_float(cfg.num_layers)
-                       if policy is None
-                       else as_plan(policy,
-                                    dynamic_acts=scheme.dynamic_acts))
+        self.policy = policy or PrecisionPlan.full_float(cfg.num_layers)
         self.scheme = scheme
         self.compute_dtype = compute_dtype
         self.params = params
@@ -227,8 +220,7 @@ class Pipeline:
         return params
 
     def with_policy(self, params: dict, plan,
-                    policy: Union[PrecisionPlan, EncoderPolicy]
-                    ) -> "Pipeline":
+                    policy: PrecisionPlan) -> "Pipeline":
         """Same stages, new precision: bind PTQ output (params packed under
         ``plan``) into a sibling Pipeline. The sibling shares this
         pipeline's runtime — its executables land in the same cache under

@@ -21,8 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
-from repro.core.plan import PrecisionPlan, as_plan
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.core.samp import SAMPEngine, SAMPResult, SweepPoint
 from repro.data.pipeline import get_batch
 from repro.kernels.backend import get_backend
@@ -65,8 +64,8 @@ class AutotuneReport:
 
     def summary(self) -> str:
         """One line per recommended candidate family. Each line names the
-        candidate's PrecisionPlan (``rec.plan`` — ``rec.point.policy`` is
-        the deprecated spelling) via its ``describe()`` string."""
+        candidate's PrecisionPlan (``rec.plan``) via its ``describe()``
+        string."""
         lines = []
         for rec in self.recommendations:
             r = rec.recommendation
@@ -183,7 +182,7 @@ class SAMP:
                            compute_dtype=str(jnp.dtype(
                                self.pipeline.compute_dtype)),
                            remat=False)
-        trainer = Trainer(self.cfg, self.engine.float_policy,
+        trainer = Trainer(self.cfg, self.engine.float_precision,
                           optimizer=AdamW(lr=lr), tcfg=tcfg,
                           scheme=self.pipeline.scheme,
                           loss_fn=self.pipeline.loss_fn())
@@ -325,14 +324,12 @@ class SAMP:
                                      min_accuracy=min_accuracy)
 
     # -- step 4: apply ---------------------------------------------------------
-    def apply(self, policy: Union[PrecisionPlan, EncoderPolicy]) -> Pipeline:
-        """Quantize under a PrecisionPlan (or an EncoderPolicy, converted
-        through the shim) and bind the deployable pipeline."""
+    def apply(self, precision: PrecisionPlan) -> Pipeline:
+        """Quantize under a PrecisionPlan and bind the deployable
+        pipeline."""
         params = self._require_params()
         if self.stats is None:
             self.calibrate()
-        precision = as_plan(policy,
-                            dynamic_acts=self.pipeline.scheme.dynamic_acts)
         # fail now, not at serve time, if the deployment's compute backend
         # cannot execute a scheme the plan names
         self.pipeline.backend.validate_plan(precision)

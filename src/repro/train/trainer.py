@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store
 from repro.configs.base import ArchConfig
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.distributed.sharding import Rules
 from repro.distributed import compression
 from repro.models import transformer as T
@@ -61,7 +61,7 @@ class TrainState:
 
 
 class Trainer:
-    def __init__(self, cfg: ArchConfig, policy: EncoderPolicy, *,
+    def __init__(self, cfg: ArchConfig, policy: PrecisionPlan, *,
                  mesh: Optional[Mesh] = None, optimizer: AdamW = AdamW(),
                  tcfg: TrainConfig = TrainConfig(),
                  scheme: T.QuantScheme = T.QuantScheme(),

@@ -22,7 +22,7 @@ from repro.adaptive import (EmbeddingKMeans, LengthBuckets, PlanSet,
                             clustered_synthetic_batches, fit_cluster_model,
                             load_plan_or_planset, pooled_embeddings)
 from repro.configs import get_config
-from repro.core.plan import PrecisionPlan, plan_from_policy
+from repro.core.plan import PrecisionPlan
 from repro.core.precision import make_policy
 from repro.core.samp import SAMPEngine
 from repro.models import transformer as T
@@ -40,11 +40,11 @@ def tiny_cfg(num_layers=2):
 
 
 def _ffn_plan(cfg):
-    return plan_from_policy(make_policy(cfg, "ffn"))
+    return make_policy(cfg, "ffn")
 
 
 def _mha_plan(cfg):
-    return plan_from_policy(make_policy(cfg, "full"))
+    return make_policy(cfg, "full")
 
 
 # ---------------------------------------------------------------------------
@@ -78,8 +78,8 @@ def test_planset_validation_errors():
     with pytest.raises(ValueError, match="default"):
         PlanSet(((0, p), (1, p)), default=7)
     with pytest.raises(ValueError):
-        PlanSet(((0, p), (1, plan_from_policy(
-            make_policy(tiny_cfg(num_layers=3), "ffn")))), default=0)
+        PlanSet(((0, p), (1, make_policy(tiny_cfg(num_layers=3), "ffn"))),
+                default=0)
     # strict from_dict: unknown top-level and member keys rejected
     d = PlanSet(((0, p),), default=0).to_dict()
     d["extra"] = 1
@@ -216,7 +216,7 @@ def test_capture_stats_clusters_partitions_rows_exactly():
     cluster's rows alone — partitioning is exact, not approximate."""
     cfg = tiny_cfg()
     eng = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng.float_policy)
+    params = T.init_params(KEY, cfg, eng.float_precision)
 
     def mk(seed, rows, width):
         return {"tokens": jax.random.randint(jax.random.PRNGKey(seed),
@@ -517,7 +517,7 @@ def test_embedding_kmeans_routes_end_to_end():
     embedding table, and routes at admission."""
     cfg = tiny_cfg()
     eng = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng.float_policy, head=("cls", 3))
+    params = T.init_params(KEY, cfg, eng.float_precision, head=("cls", 3))
     model = EmbeddingKMeans(2, seed=0)
     batches, classes = clustered_synthetic_batches(cfg, model, max_len=16)
     fit_cluster_model(model, params, batches, cfg)

@@ -23,7 +23,6 @@ import pytest
 
 from repro.configs import all_configs, get_config
 from repro.core.calibration import synthetic_calibration_batches
-from repro.core.plan import plan_from_policy
 from repro.core.precision import make_policy
 from repro.core.samp import SAMPEngine, moe_family_variant
 from repro.kernels.backend import get_backend
@@ -62,8 +61,7 @@ def built(arch):
         params = T.init_params(KEY, cfg, eng.float_precision)
         batches = synthetic_calibration_batches(cfg, num_batches=2,
                                                 seq_len=16)
-        precision = plan_from_policy(make_policy(cfg, "ffn",
-                                                 float_dtype="float32"))
+        precision = make_policy(cfg, "ffn", float_dtype="float32")
         if cfg.moe is not None:
             precision = moe_family_variant(precision)
         stats = eng.calibrate(params, batches, precision=precision)

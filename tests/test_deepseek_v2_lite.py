@@ -14,8 +14,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.calibration import synthetic_calibration_batches
-from repro.core.plan import plan_from_policy
-from repro.core.precision import EncoderPolicy, make_policy
+from repro.core.plan import PrecisionPlan
+from repro.core.precision import make_policy
 from repro.core.samp import SAMPEngine
 from repro.models import layers as L
 from repro.models import transformer as T
@@ -45,8 +45,7 @@ def _served(cfg, plan_name: str, backend: str):
     every tick of every slot recorded as the engine samples them."""
     eng = SAMPEngine(cfg, float_dtype="float32")
     params = T.init_params(KEY, cfg, eng.float_precision)
-    precision = plan_from_policy(make_policy(cfg, plan_name,
-                                             float_dtype="float32"))
+    precision = make_policy(cfg, plan_name, float_dtype="float32")
     stats = eng.calibrate(params, synthetic_calibration_batches(cfg),
                           precision=precision)
     qparams, plan = eng.apply(params, stats, precision)
@@ -213,7 +212,7 @@ def test_a_real_slot_does_not_see_the_other_slots():
     """One decode step, slot 0 active: its logits are the same whatever
     the inactive slots hold, and only its picks count."""
     cfg = get_config("deepseek-v2-lite-ep4").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     active = jnp.array([True, False, False, False])
@@ -284,7 +283,7 @@ def test_the_held_share_counts_only_its_picks():
 @pytest.mark.parametrize("scheme", ["int8_per_token", "int8_per_head"])
 def test_mla_refuses_a_quantized_kv_cache(scheme):
     cfg = get_config("deepseek-v2-lite-ep4").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     with pytest.raises(ValueError, match="MLA.*kv_cache='float'"):

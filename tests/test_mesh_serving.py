@@ -207,7 +207,7 @@ def test_host_mesh_serve_matches_unmeshed_golden_plan(tmp_path):
         cfg = get_config("bert-base").reduced().replace(num_layers=4)
         eng = SAMPEngine(cfg, float_dtype="float32")
         params = T.init_params(jax.random.PRNGKey(0), cfg,
-                               eng.float_policy, head=("cls", 5))
+                               eng.float_precision, head=("cls", 5))
         golden = PrecisionPlan.load("tests/data/golden_plan.json")
         batches = synthetic_calibration_batches(cfg, num_batches=2, seed=0)
         stats = eng.calibrate(params, batches, precision=golden)
@@ -300,13 +300,13 @@ def test_host_mesh_decode_matches_per_device_decode(backend):
         import jax
         import numpy as np
         from repro.configs import get_config
-        from repro.core.precision import EncoderPolicy
+        from repro.core.plan import PrecisionPlan
         from repro.launch.mesh import make_serving_mesh
         from repro.models import transformer as T
         from repro.serve import Request, ServeEngine
 
         cfg = get_config("qwen2-0.5b").reduced().replace(num_layers=2)
-        policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+        policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
         plan = T.build_plan(cfg, policy)
         params = T.init_params(jax.random.PRNGKey(0), cfg, policy)
         prompts = [[2, 17, 9], [5, 40, 3, 3, 8], [11, 3, 7, 1], [23, 8]]

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.models import transformer as T
 
 KEY = jax.random.PRNGKey(0)
@@ -33,7 +33,7 @@ def make_batch(cfg, B=2, S=16):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_forward(arch):
     cfg = get_config(arch).reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     head = ("cls", 5) if cfg.family == "bert" else None
     params = T.init_params(KEY, cfg, policy, head=head)
@@ -50,7 +50,7 @@ def test_smoke_forward(arch):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_train_step(arch):
     cfg = get_config(arch).reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     head = ("cls", 5) if cfg.family == "bert" else None
     params = T.init_params(KEY, cfg, policy, head=head)
@@ -72,7 +72,7 @@ def test_decode_matches_prefill(arch):
     if cfg.moe is not None:     # avoid capacity-drop divergence
         cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
                                                   capacity_factor=16.0))
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     B, S = 2, 10
@@ -93,7 +93,7 @@ def test_decode_matches_prefill(arch):
 def test_prefill_then_decode_continues():
     """Bulk prefill writes caches decode can continue from."""
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     B, S = 2, 8
@@ -118,7 +118,7 @@ def test_sliding_window_ring_buffer_decode():
     cfg = get_config("mixtral-8x22b").reduced()
     cfg = cfg.replace(sliding_window=4,
                       moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     B, S = 1, 12
@@ -141,7 +141,7 @@ def test_sliding_window_ring_buffer_decode():
 
 def test_chunked_attention_matches_unchunked():
     cfg = get_config("gemma2-2b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     toks = jax.random.randint(KEY, (2, 32), 0, cfg.vocab_size)
@@ -154,12 +154,12 @@ def test_chunked_attention_matches_unchunked():
 
 
 def test_repack_roundtrip():
-    from repro.core.precision import LayerMode
+    from repro.core.plan import LayerMode
     cfg = get_config("gemma2-2b").reduced()
-    fp = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    fp = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan_f = T.build_plan(cfg, fp)
     params = T.init_params(KEY, cfg, fp)
-    qp_policy = EncoderPolicy.prefix(cfg.num_layers, 2,
+    qp_policy = PrecisionPlan.prefix(cfg.num_layers, 2,
                                      LayerMode.QUANT_FFN_ONLY, "float32")
     plan_q = T.build_plan(cfg, qp_policy)
     repacked = T.repack(params, plan_f, plan_q)

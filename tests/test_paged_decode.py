@@ -24,7 +24,6 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.plan import LayerMode, LayerPlan, PrecisionPlan
-from repro.core.precision import EncoderPolicy
 from repro.kernels import ops
 from repro.models import transformer as T
 from repro.serve import Request, ServeEngine
@@ -254,7 +253,7 @@ def test_page_write_matches_xla_scatter(scheme, case, lanes):
 @pytest.fixture(scope="module")
 def qwen_float():
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     return cfg, params, plan
@@ -316,7 +315,7 @@ def test_golden_plan_int8_fused_matches_float_reference():
                            page_size=8, kv_cache="int8_per_token")
     assert int8_fused == float_ref
     # logit-level closeness on a fresh decode step (covers every prompt)
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     fplan = T.build_plan(cfg, policy)
     fparams = T.init_params(KEY, cfg, policy)
     dense = T.init_caches(cfg, fplan, 1, 32, jnp.float32)

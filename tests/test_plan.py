@@ -1,10 +1,12 @@
-"""PrecisionPlan: schema validation, serialization round-trips, the policy
-shim, search strategies, per-block PTQ, and the plan-keyed runtime cache."""
+"""PrecisionPlan: schema validation, serialization round-trips, the named
+prefix plans, search strategies, per-block PTQ, and the plan-keyed runtime
+cache."""
 import json
 import os
+import pathlib
 import subprocess
 import sys
-import warnings
+import types
 
 from _hypothesis_shim import hypothesis, st
 import jax
@@ -13,10 +15,10 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.plan import (ACT_SCHEMES, BLOCKS, FLOAT_LAYER, LayerPlan,
-                             PrecisionPlan, QuantSpec, WEIGHT_SCHEMES,
-                             as_plan, plan_from_policy)
-from repro.core.precision import EncoderPolicy, LayerMode, paper_grid
+from repro.core.plan import (ACT_SCHEMES, BLOCKS, FLOAT_LAYER, LayerMode,
+                             LayerPlan, PrecisionPlan, QuantSpec,
+                             WEIGHT_SCHEMES)
+from repro.core.precision import make_policy, paper_grid
 from repro.core.quantize import QuantizedTensor
 from repro.core.samp import SAMPEngine, SEARCH_STRATEGIES, get_strategy
 from repro.models import transformer as T
@@ -25,6 +27,8 @@ from repro.toolkit import SAMP, Pipeline
 from repro.toolkit.plan_lint import lint
 
 KEY = jax.random.PRNGKey(0)
+REPO = pathlib.Path(__file__).resolve().parents[1]
+BENCH = REPO / "bench"
 DATA = os.path.join(os.path.dirname(__file__), "data")
 GOLDEN = os.path.join(DATA, "golden_plan.json")
 GOLDEN_FINGERPRINT = \
@@ -130,28 +134,46 @@ def test_json_round_trip_preserves_fingerprint(plan):
                   st.sampled_from((LayerMode.QUANT_FFN_ONLY,
                                    LayerMode.FULLY_QUANT)))
 def test_policy_shim_equivalence(n, k, mode):
-    """from_policy -> to_policy is the identity on the mode lattice, and
-    the derived per-layer modes match the policy's."""
-    policy = EncoderPolicy.prefix(n, min(k, n), mode, "float32")
-    plan = plan_from_policy(policy)
-    assert plan.modes == policy.modes
-    assert plan.to_policy() == policy
-    assert plan.num_quant_ffn == policy.num_quant_ffn
-    assert plan.num_quant_mha == policy.num_quant_mha
-    # identical policies -> identical fingerprints; and the shimmed plan
-    # groups exactly like the policy
-    assert plan.fingerprint() == plan_from_policy(policy).fingerprint()
-    assert [(s, e) for s, e, _ in plan.group_boundaries()] == \
-        [(s, e) for s, e, _ in policy.group_boundaries()]
+    """A named policy ('ffnK'/'fullK') is the prefix plan: the first k
+    layers in ``mode``, the rest float, with matching counts, a fingerprint
+    that only depends on the content, and one scan group per run."""
+    k = min(k, n)
+    plan = PrecisionPlan.prefix(n, k, mode, "float32")
+    name = ("ffn" if mode is LayerMode.QUANT_FFN_ONLY else "full") + str(k)
+    assert make_policy(types.SimpleNamespace(num_layers=n), name,
+                       "float32") == plan
+    assert plan.modes == (mode,) * k + (LayerMode.FLOAT,) * (n - k)
+    assert plan.num_quant_ffn == k
+    assert plan.num_quant_mha == (k if mode.quant_mha else 0)
+    assert plan.fingerprint() == \
+        PrecisionPlan.prefix(n, k, mode, "float32").fingerprint()
+    assert plan.group_boundaries() == [
+        run for run in ((0, k, mode), (k, n, LayerMode.FLOAT))
+        if run[0] < run[1]]
 
 
-def test_from_policy_shim_warns():
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        plan = PrecisionPlan.from_policy(EncoderPolicy.full_float(3))
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
-    assert plan == PrecisionPlan.full_float(3)
-    assert as_plan(plan) is plan
+# each benchmark cell's plan as bench/system.precision_plan builds it, by
+# fingerprint: the serving runtime keys the cell's executables on it, so a
+# refactor of the precision API must leave these unchanged
+CELL_FINGERPRINTS = {
+    "bert-base-samp":
+        "000fbb9e3eb83a78909090cb063685a43a4e98eb46b9cf336b7e002bb0038400",
+    "qwen2-0.5b-samp":
+        "3a6273acea8007155b1eaf9125726db7806bb5ad68f5c025490056a8e625c634",
+    "deepseek-v2-lite-ep4-samp":
+        "6e7389919d67572646c426f01a6848563024d9a4c609baf6a6cf74ac49ce8167",
+}
+
+
+@pytest.mark.parametrize(
+    "name", sorted(p.stem for p in (BENCH / "configs").glob("*.json")))
+def test_cell_plans_keep_their_fingerprints(name):
+    if str(BENCH) not in sys.path:
+        sys.path.insert(0, str(BENCH))
+    import system
+    config = json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    plan = system.precision_plan(config, system.arch_config(config))
+    assert plan.fingerprint() == CELL_FINGERPRINTS[name]
 
 
 def test_file_round_trip(tmp_path):

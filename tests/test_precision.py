@@ -3,8 +3,8 @@ from _hypothesis_shim import hypothesis, st
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import (EncoderPolicy, LayerMode, make_policy,
-                                  paper_grid)
+from repro.core.plan import LayerMode, PrecisionPlan
+from repro.core.precision import make_policy, paper_grid
 from repro.models.transformer import build_plan
 
 settings = hypothesis.settings(max_examples=30, deadline=None)
@@ -18,12 +18,12 @@ def test_modes():
 
 
 def test_prefix_policy_counts():
-    p = EncoderPolicy.prefix(12, 5, LayerMode.FULLY_QUANT)
+    p = PrecisionPlan.prefix(12, 5, LayerMode.FULLY_QUANT)
     assert p.num_quant_mha == 5 and p.num_quant_ffn == 5
-    p2 = EncoderPolicy.prefix(12, 7, LayerMode.QUANT_FFN_ONLY)
+    p2 = PrecisionPlan.prefix(12, 7, LayerMode.QUANT_FFN_ONLY)
     assert p2.num_quant_mha == 0 and p2.num_quant_ffn == 7
     with pytest.raises(ValueError):
-        EncoderPolicy.prefix(12, 13, LayerMode.FLOAT)
+        PrecisionPlan.prefix(12, 13, LayerMode.FLOAT)
 
 
 def test_paper_grid_size():
@@ -35,7 +35,7 @@ def test_paper_grid_size():
 
 
 def test_group_boundaries_partition():
-    p = EncoderPolicy.prefix(10, 4, LayerMode.FULLY_QUANT)
+    p = PrecisionPlan.prefix(10, 4, LayerMode.FULLY_QUANT)
     runs = p.group_boundaries()
     assert runs[0] == (0, 4, LayerMode.FULLY_QUANT)
     assert runs[1] == (4, 10, LayerMode.FLOAT)
@@ -48,7 +48,7 @@ def test_plan_covers_all_layers_every_arch(n_unused, k):
                  "xlstm-125m", "deepseek-v2-236b"):
         cfg = get_config(arch)
         k_eff = min(k, cfg.num_layers)
-        policy = EncoderPolicy.prefix(cfg.num_layers, k_eff,
+        policy = PrecisionPlan.prefix(cfg.num_layers, k_eff,
                                       LayerMode.QUANT_FFN_ONLY)
         plan = build_plan(cfg, policy)
         covered = []
@@ -60,7 +60,7 @@ def test_plan_covers_all_layers_every_arch(n_unused, k):
 
 def test_plan_scans_homogeneous_archs():
     cfg = get_config("deepseek-coder-33b")
-    policy = EncoderPolicy.prefix(cfg.num_layers, 10,
+    policy = PrecisionPlan.prefix(cfg.num_layers, 10,
                                   LayerMode.QUANT_FFN_ONLY)
     plan = build_plan(cfg, policy)
     assert len(plan) == 2                     # quantized prefix + float rest
@@ -69,7 +69,7 @@ def test_plan_scans_homogeneous_archs():
 
 def test_plan_period_scan_gemma2():
     cfg = get_config("gemma2-2b")             # alternating local/global
-    policy = EncoderPolicy.full_float(cfg.num_layers)
+    policy = PrecisionPlan.full_float(cfg.num_layers)
     plan = build_plan(cfg, policy)
     assert len(plan) == 1
     assert len(plan[0].kinds) == 2            # one period = 2 layers
@@ -78,7 +78,7 @@ def test_plan_period_scan_gemma2():
 
 def test_plan_dsv2_dense_first_layer():
     cfg = get_config("deepseek-v2-236b")
-    plan = build_plan(cfg, EncoderPolicy.full_float(cfg.num_layers))
+    plan = build_plan(cfg, PrecisionPlan.full_float(cfg.num_layers))
     assert len(plan) == 2
     assert plan[0].stop - plan[0].start == 1  # the dense-FFN layer 0
     assert not plan[0].kinds[0].moe

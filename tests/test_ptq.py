@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerMode, PrecisionPlan
 from repro.core.quantize import QuantizedTensor
 from repro.core.samp import SAMPEngine
 from repro.models import transformer as T
@@ -17,7 +17,7 @@ KEY = jax.random.PRNGKey(0)
 def setup(arch, head=None):
     cfg = get_config(arch).reduced()
     eng = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng.float_policy, head=head)
+    params = T.init_params(KEY, cfg, eng.float_precision, head=head)
     batches = [{"tokens": jax.random.randint(jax.random.PRNGKey(i), (2, 16),
                                              0, cfg.vocab_size)}
                for i in range(3)]
@@ -55,7 +55,7 @@ def test_apply_policy_quantizes_right_weights(mode):
     cfg, eng, params, batches = setup("qwen2-0.5b")
     stats = eng.calibrate(params, batches)
     k = cfg.num_layers // 2
-    policy = EncoderPolicy.prefix(cfg.num_layers, k, mode, "float32")
+    policy = PrecisionPlan.prefix(cfg.num_layers, k, mode, "float32")
     qp, plan = eng.apply(params, stats, policy)
     layers = T.unpack_layers(qp, plan)
     for i, lp in enumerate(layers):
@@ -77,7 +77,7 @@ def test_quantized_outputs_close_to_float():
                        compute_dtype=jnp.float32)
     errs = {}
     for mode in (LayerMode.QUANT_FFN_ONLY, LayerMode.FULLY_QUANT):
-        policy = EncoderPolicy.prefix(cfg.num_layers, cfg.num_layers, mode,
+        policy = PrecisionPlan.prefix(cfg.num_layers, cfg.num_layers, mode,
                                       "float32")
         qp, plan = eng.apply(params, stats, policy)
         out, _ = T.forward(qp, batches[0], cfg, plan,
@@ -96,14 +96,14 @@ def test_unsigned_softmax_fix_reduces_error():
     for sm in ("symmetric", "unsigned"):
         scheme = T.QuantScheme(softmax_mode=sm)
         eng = SAMPEngine(cfg, scheme=scheme, float_dtype="float32")
-        params = T.init_params(KEY, cfg, eng.float_policy)
+        params = T.init_params(KEY, cfg, eng.float_precision)
         batches = [{"tokens": jax.random.randint(jax.random.PRNGKey(i),
                                                  (2, 16), 0, cfg.vocab_size)}
                    for i in range(3)]
         stats = eng.calibrate(params, batches)
         ref, _ = T.forward(params, batches[0], cfg, eng.float_plan,
                            compute_dtype=jnp.float32)
-        policy = EncoderPolicy.prefix(cfg.num_layers, cfg.num_layers,
+        policy = PrecisionPlan.prefix(cfg.num_layers, cfg.num_layers,
                                       LayerMode.FULLY_QUANT, "float32")
         qp, plan = eng.apply(params, stats, policy)
         out, _ = T.forward(qp, batches[0], cfg, plan, scheme,
@@ -116,11 +116,12 @@ def test_dynamic_acts_need_no_stats():
     cfg = get_config("qwen2-0.5b").reduced()
     scheme = T.QuantScheme(dynamic_acts=True)
     eng = SAMPEngine(cfg, scheme=scheme, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng.float_policy)
-    policy = EncoderPolicy.prefix(cfg.num_layers, cfg.num_layers,
-                                  LayerMode.QUANT_FFN_ONLY, "float32")
-    qp, plan = ptq.apply_policy(params, cfg, policy, {}, scheme=scheme,
-                                float_plan=eng.float_plan)
+    params = T.init_params(KEY, cfg, eng.float_precision)
+    policy = PrecisionPlan.prefix(cfg.num_layers, cfg.num_layers,
+                                  LayerMode.QUANT_FFN_ONLY, "float32",
+                                  dynamic_acts=True)
+    qp, plan = ptq.apply_plan(params, cfg, policy, {}, scheme=scheme,
+                              float_plan=eng.float_plan)
     batch = {"tokens": jax.random.randint(KEY, (2, 16), 0, cfg.vocab_size)}
     out, _ = T.forward(qp, batch, cfg, plan, scheme,
                        compute_dtype=jnp.float32)
@@ -132,11 +133,11 @@ def test_dynamic_acts_need_no_stats():
 def test_expert_weight_quantization_shape():
     cfg = get_config("mixtral-8x22b").reduced()
     eng = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng.float_policy)
+    params = T.init_params(KEY, cfg, eng.float_precision)
     batches = [{"tokens": jax.random.randint(KEY, (2, 16), 0,
                                              cfg.vocab_size)}]
     stats = eng.calibrate(params, batches)
-    policy = EncoderPolicy.prefix(cfg.num_layers, cfg.num_layers,
+    policy = PrecisionPlan.prefix(cfg.num_layers, cfg.num_layers,
                                   LayerMode.QUANT_FFN_ONLY, "float32")
     qp, plan = eng.apply(params, stats, policy)
     layers = T.unpack_layers(qp, plan)

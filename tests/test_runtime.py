@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy, make_policy
+from repro.core.plan import PrecisionPlan
+from repro.core.precision import make_policy
 from repro.data import get_batch
 from repro.models import transformer as T
 from repro.serve import (EncoderRequest, EncoderServeEngine, MicroBatcher,
@@ -31,7 +32,7 @@ def bert_pipe():
 @pytest.fixture(scope="module")
 def qwen_setup():
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     return cfg, params, plan

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import LayerMode, PrecisionPlan
 from repro.core.samp import SAMPEngine
 from repro.models import transformer as T
 from repro.serve import Request, ServeEngine
@@ -29,7 +29,7 @@ def greedy_reference(cfg, params, plan, prompt, n, max_len=64):
 @pytest.fixture(scope="module")
 def qwen_setup():
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     return cfg, params, plan
@@ -95,12 +95,12 @@ def test_serving_quantized_model():
     """SAMP-quantized weights serve through the same engine."""
     cfg = get_config("qwen2-0.5b").reduced()
     eng_s = SAMPEngine(cfg, float_dtype="float32")
-    params = T.init_params(KEY, cfg, eng_s.float_policy)
+    params = T.init_params(KEY, cfg, eng_s.float_precision)
     batches = [{"tokens": jax.random.randint(jax.random.PRNGKey(i), (2, 16),
                                              0, cfg.vocab_size)}
                for i in range(2)]
     stats = eng_s.calibrate(params, batches)
-    policy = EncoderPolicy.prefix(cfg.num_layers, cfg.num_layers,
+    policy = PrecisionPlan.prefix(cfg.num_layers, cfg.num_layers,
                                   LayerMode.QUANT_FFN_ONLY, "float32")
     qp, plan = eng_s.apply(params, stats, policy)
     srv = ServeEngine(cfg, qp, plan, batch_slots=2, max_len=32)
@@ -112,7 +112,7 @@ def test_serving_quantized_model():
 def test_recurrent_arch_serving():
     """Continuous batching over SSM state (xlstm) — state gating path."""
     cfg = get_config("xlstm-125m").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
     params = T.init_params(KEY, cfg, policy)
     eng = ServeEngine(cfg, params, plan, batch_slots=2, max_len=32)

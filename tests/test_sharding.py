@@ -10,7 +10,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core.precision import EncoderPolicy, LayerMode, make_policy
+from repro.core.plan import PrecisionPlan
+from repro.core.precision import make_policy
 from repro.distributed.sharding import Rules
 from repro.models import transformer as T
 
@@ -117,12 +118,11 @@ def test_pjit_train_step_8dev_subprocess(tmp_path):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from repro.configs import get_config
-        from repro.core.precision import EncoderPolicy
         from repro.train import Trainer, TrainConfig, AdamW
         from repro.data import make_task, get_batch
 
         cfg = get_config("qwen2-0.5b").reduced()
-        policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+        policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
         mesh = jax.make_mesh((4, 2), ("data", "model"))
         tr = Trainer(cfg, policy, mesh=mesh, optimizer=AdamW(lr=1e-3),
                      tcfg=TrainConfig(steps=2, compute_dtype="float32"))

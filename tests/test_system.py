@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy, LayerMode
+from repro.core.plan import PrecisionPlan
 from repro.core.samp import SAMPEngine
 from repro.data import eval_accuracy, get_batch, make_task
 from repro.models import transformer as T
@@ -28,7 +28,7 @@ N_CLASSES = 5
 @pytest.fixture(scope="module")
 def finetuned():
     cfg = get_config("bert-base").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     task = make_task("tnews", vocab_size=cfg.vocab_size, seq_len=24)
     task = task.__class__(**{**task.__dict__, "n_classes": N_CLASSES})
     tcfg = TrainConfig(steps=120, log_every=1000, compute_dtype="float32",
@@ -85,7 +85,7 @@ def test_samp_sweep_and_allocator(finetuned):
 
     def latency_fn(qp, plan, policy):
         # analytic roofline latency model (per-layer GEMM precision)
-        from benchmarks.latency_model import encoder_latency
+        from repro.toolkit.latency import encoder_latency
         return encoder_latency(cfg, policy, batch=32, seq=24)
 
     pts = eng.sweep(params, stats, eval_fn, latency_fn, stride=4)

@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy, LayerMode, make_policy
+from repro.core.plan import PrecisionPlan
+from repro.core.precision import make_policy
 from repro.data import eval_accuracy, get_batch
 from repro.models import transformer as T
 from repro.toolkit import (LATENCY_BACKENDS, SAMP, TARGETS, Pipeline,
@@ -173,7 +174,7 @@ def test_roofline_backend_matches_function():
 
 def test_roofline_int8_is_faster():
     cfg = get_config("bert-base")
-    t_f = encoder_latency(cfg, EncoderPolicy.full_float(cfg.num_layers),
+    t_f = encoder_latency(cfg, PrecisionPlan.full_float(cfg.num_layers),
                           batch=8, seq=128)
     t_q = encoder_latency(cfg, make_policy(cfg, "full"), batch=8, seq=128)
     assert t_q < t_f
@@ -185,14 +186,6 @@ def test_wallclock_backend_runs(trained):
         pipe.cfg, batch=2, seq=8, compute_dtype=jnp.float32)
     t = fn(pipe.params, pipe.plan, pipe.policy)
     assert t > 0
-
-
-def test_benchmarks_shim_still_exports():
-    from benchmarks.latency_model import encoder_latency as shim_fn
-    cfg = get_config("bert-base")
-    pol = EncoderPolicy.full_float(cfg.num_layers)
-    assert shim_fn(cfg, pol, batch=1, seq=32) == encoder_latency(
-        cfg, pol, batch=1, seq=32)
 
 
 # ---------------------------------------------------------------------------

@@ -219,13 +219,13 @@ def _decode_step_text(monkeypatch, scheme, on, rows, mesh=None):
     backend, int8 pages as the runtime pads them for the chip), with the
     caches; ``on`` / ``rows`` place the caches and the per-slot operands."""
     from repro.configs import get_config
-    from repro.core.precision import EncoderPolicy
+    from repro.core.plan import PrecisionPlan
     from repro.kernels import ops
     from repro.models import transformer as T
     from repro.serve.runtime import Runtime
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = get_config("qwen2-0.5b").replace(num_layers=2)
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     plan = T.build_plan(cfg, policy)
 
     def params_fn():
@@ -319,8 +319,8 @@ def test_deepseek_v2_lite_decode_step(one_chip, monkeypatch):
     pages): every expert GEMM is the grouped kernel, and the step returns
     the routed-pick count beside the logits and the caches."""
     from repro.configs import get_config
-    from repro.core.plan import plan_from_policy
-    from repro.core.precision import EncoderPolicy, make_policy
+    from repro.core.plan import PrecisionPlan
+    from repro.core.precision import make_policy
     from repro.kernels import ops
     from repro.launch.dryrun import abstract_stats
     from repro.models import transformer as T
@@ -328,11 +328,11 @@ def test_deepseek_v2_lite_decode_step(one_chip, monkeypatch):
     from repro.serve.runtime import Runtime
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = get_config("deepseek-v2-lite-ep4").replace(num_layers=3)
-    precision = plan_from_policy(make_policy(cfg, "ffn"))
+    precision = make_policy(cfg, "ffn")
 
     def params_fn():
         params = T.init_params(jax.random.PRNGKey(0), cfg,
-                               EncoderPolicy.full_float(cfg.num_layers,
+                               PrecisionPlan.full_float(cfg.num_layers,
                                                         "float32"))
         return ptq.apply_plan(params, cfg, precision,
                               abstract_stats(cfg))[0]

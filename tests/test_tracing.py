@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.models import transformer as T
 from repro.serve import (EncoderRequest, EncoderServeEngine, MicroBatcher,
                          Request, ServeEngine, SlotScheduler)
@@ -31,7 +31,7 @@ def bert_pipe():
 @pytest.fixture(scope="module")
 def qwen_setup():
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     return cfg, T.init_params(KEY, cfg, policy), T.build_plan(cfg, policy)
 
 

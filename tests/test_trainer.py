@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.precision import EncoderPolicy
+from repro.core.plan import PrecisionPlan
 from repro.data import get_batch, make_task
 from repro.train import AdamW, TrainConfig, Trainer, cosine_schedule
 from repro.train.optimizer import global_norm
@@ -18,7 +18,7 @@ KEY = jax.random.PRNGKey(0)
 
 def mk_trainer(tmp_path=None, steps=20, grad_accum=1):
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     tcfg = TrainConfig(steps=steps, log_every=100, checkpoint_every=5,
                        checkpoint_dir=str(tmp_path) if tmp_path else None,
                        grad_accum=grad_accum, remat=True,
@@ -64,7 +64,7 @@ def test_kill_and_resume_bitwise(tmp_path):
 
 def test_grad_accum_matches_big_batch():
     cfg = get_config("qwen2-0.5b").reduced()
-    policy = EncoderPolicy.full_float(cfg.num_layers, "float32")
+    policy = PrecisionPlan.full_float(cfg.num_layers, "float32")
     task = make_task("lm", vocab_size=cfg.vocab_size, seq_len=16)
     batch = {k: jnp.asarray(v) for k, v in get_batch(task, 0, 8).items()}
 
