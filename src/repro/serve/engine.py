@@ -35,6 +35,11 @@ from repro.serve.scheduler import PagePool, SlotScheduler
 # page geometry when a plan implies paging but the caller picked no size
 DEFAULT_PAGE_SIZE = 16
 
+# each slot's greedy pick, first maximum as numpy's argmax, taken on the
+# device from whatever logits the step returned: a tick copies (slots,)
+# ids to the host instead of the (slots, vocab) logits
+_greedy_ids = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
+
 
 @dataclasses.dataclass
 class Request:
@@ -160,9 +165,12 @@ class ServeEngine:
                         else self.runtime.decode_fn(params, self.caches))
         self._decode_by_cluster: dict[int, object] = {}
         self.rng = np.random.default_rng(seed)
+        # ``logits_fetches``: ticks that copied the logits to the host, for
+        # a slot sampling at temperature > 0 (greedy slots take device ids)
         self._stats = {"steps": 0, "ticks": 0, "tokens": 0, "retired": 0,
                        "stalls": 0, "preemptions": 0, "requests": 0,
-                       "attn_pages_live": 0, "attn_pages_table": 0}
+                       "attn_pages_live": 0, "attn_pages_table": 0,
+                       "logits_fetches": 0}
         # set when a deadlock preemption proves the pool cannot hold the
         # current working set: admission pauses until pages are freed, so
         # preempted requests don't thrash straight back into a slot
@@ -265,6 +273,7 @@ class ServeEngine:
                 tokens = np.zeros((self.slots, 1), np.int32)
                 pos = np.zeros(self.slots, np.int32)
                 active = np.zeros(self.slots, bool)
+                sampled = False
                 for s in live:
                     req = self.sched.active[s]
                     c = int(self.sched.cursor[s])
@@ -275,24 +284,31 @@ class ServeEngine:
                                     else req.output[c - len(req.prompt)])
                     pos[s] = c
                     active[s] = True
+                    # a slot at its text frontier draws from this tick's
+                    # logits: at temperature > 0 they go to the host
+                    sampled |= req.temperature > 0 and c + 1 >= req.text_len
                 pages = None
                 if self.pool is not None:
                     pages = jnp.asarray(self.pool.table)
                     self._count_attn_pages(pos, active)
                 decode, step_params = self._executable()
             # an MoE step returns its routed picks third: fetched with the
-            # logits, in the same copy
+            # ids, in the same copy
             logits, self.caches, *routed = decode(
                 step_params, self.caches, tokens, pos, active, pages)
+            ids = _greedy_ids(logits)
             with phase("samp.dec.fetch"):
-                logits, *routed = jax.device_get((logits, *routed))
+                ids, logits, *routed = jax.device_get(
+                    (ids, logits if sampled else None, *routed))
+            if sampled:
                 logits = np.asarray(logits, np.float32)
+                self._stats["logits_fetches"] += 1
             if routed:
                 self.runtime.count_routed(int(routed[0]), self.slots)
             self._stats["ticks"] += 1
             self._stats["tokens"] += len(live)
             with phase("samp.dec.sample"):
-                return self._sample(live, logits)
+                return self._sample(live, ids, logits)
 
     def _ensure_pages(self, live: list[int]) -> list[int]:
         """Grow each live slot's page allocation to cover this tick's
@@ -351,9 +367,12 @@ class ServeEngine:
             self._decode_by_cluster[entry.cluster] = decode
         return decode, entry.params
 
-    def _sample(self, live: list[int], logits: np.ndarray) -> list[Request]:
-        """Advance the live slots' cursors and pick each next token from
-        this tick's logits; returns the requests that retired."""
+    def _sample(self, live: list[int], ids: np.ndarray,
+                logits: Optional[np.ndarray]) -> list[Request]:
+        """Advance the live slots' cursors and pick each next token: a
+        greedy slot's is its device pick in ``ids``, a sampled slot draws
+        from its row of this tick's host ``logits`` (None on a tick where
+        no slot samples); returns the requests that retired."""
         retired: list[Request] = []
         for s in live:
             req = self.sched.active[s]
@@ -363,13 +382,13 @@ class ServeEngine:
             if self.sched.cursor[s] < req.text_len:
                 continue
             # this tick's logits predict the next token
-            row = logits[s]
             if req.temperature > 0:
+                row = logits[s]
                 p = np.exp((row - row.max()) / req.temperature)
                 p /= p.sum()
                 nxt = int(self.rng.choice(len(p), p=p))
             else:
-                nxt = int(row.argmax())
+                nxt = int(ids[s])
             req.output.append(nxt)
             hit_eos = req.eos_id is not None and nxt == req.eos_id
             if hit_eos or len(req.output) >= req.max_tokens \

@@ -109,6 +109,12 @@ class HTTPFrontend:
                   "KV pages currently allocated out of the decode page "
                   "pool (0 for dense caches)",
                   fn=lambda: float(dec.kv_pages_in_use) if dec else 0.0)
+        reg.counter("samp_logits_fetches_total",
+                    "decode ticks that copied the logits to the host, for "
+                    "a slot sampling at temperature > 0 (greedy ticks "
+                    "copy only the token ids)",
+                    fn=lambda: float(dec.stats["logits_fetches"])
+                    if dec else 0.0)
 
         for name, engine in (("encoder", self.encoder),
                              ("decode", self.decode)):

@@ -58,6 +58,7 @@ CORE_METRICS = (
     "samp_request_latency_seconds",
     "samp_kv_cache_bytes",
     "samp_kv_pages_in_use",
+    "samp_logits_fetches_total",
     "samp_cluster_requests_total",
     "samp_active_plans",
     "samp_phase_seconds_total",

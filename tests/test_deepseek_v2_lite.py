@@ -42,7 +42,7 @@ def _reference():
 def _served(cfg, plan_name: str, backend: str):
     """(float params, the served engine's (prompt+output, logits) per
     request): three requests through a paged ServeEngine, the logits of
-    every tick of every slot recorded as the engine samples them."""
+    every tick of every slot recorded as each decode step returns them."""
     eng = SAMPEngine(cfg, float_dtype="float32")
     params = T.init_params(KEY, cfg, eng.float_precision)
     precision = make_policy(cfg, plan_name, float_dtype="float32")
@@ -50,17 +50,20 @@ def _served(cfg, plan_name: str, backend: str):
                           precision=precision)
     qparams, plan = eng.apply(params, stats, precision)
     seen: dict = {}
+    engine = ServeEngine(cfg, qparams, plan, batch_slots=2, max_len=32,
+                         page_size=4, kv_cache="float", backend=backend,
+                         precision=precision)
+    inner = engine._decode
 
-    class Recording(ServeEngine):
-        def _sample(self, live, logits):
-            for s in live:
-                req = self.sched.active[s]
-                seen.setdefault(req.uid, {})[int(self.sched.cursor[s])] = \
-                    np.array(logits[s])
-            return super()._sample(live, logits)
-    engine = Recording(cfg, qparams, plan, batch_slots=2, max_len=32,
-                       page_size=4, kv_cache="float", backend=backend,
-                       precision=precision)
+    def recording(params, caches, tokens, pos, active, pages=None):
+        logits, *rest = inner(params, caches, tokens, pos, active, pages)
+        got = np.asarray(logits)
+        for s, req in enumerate(engine.sched.active):
+            if req is not None and active[s]:
+                seen.setdefault(req.uid, {})[
+                    int(engine.sched.cursor[s])] = got[s]
+        return (logits, *rest)
+    engine._decode = recording
     for uid, prompt in enumerate(PROMPTS):
         engine.submit(Request(uid=uid, prompt=list(prompt), max_tokens=5))
     done = engine.run()

@@ -299,11 +299,14 @@ def test_concurrent_sse_decode_matches_direct_engine(qwen_golden):
                       port=0, log=SILENT)
 
     async def scenario(port):
-        return await asyncio.gather(
+        streams = await asyncio.gather(
             *(http_sse("127.0.0.1", port, "/v1/generate",
                        {"prompt": p, "max_tokens": 5}) for p in prompts))
+        return streams, await scrape_metrics("127.0.0.1", port)
 
-    results = run_session(fe, scenario)
+    results, metrics = run_session(fe, scenario)
+    # greedy requests take the device's picks: no tick copied logits
+    assert "\nsamp_logits_fetches_total 0\n" in metrics
     for p, (status, _, events) in zip(prompts, results):
         assert status == 200
         streamed = [d["token"] for e, d in events if e == "token"]
